@@ -372,7 +372,11 @@ class PromApiHandler(BaseHTTPRequestHandler):
 
                 return self._send(200, J.success({"version": __version__, "application": "filodb-tpu"}))
             if path == "/admin/health":
-                return self._send(200, {"status": "healthy", "shards": len(self.engine.memstore.shards(self.engine.dataset))})
+                return self._send(200, {
+                    "status": "healthy",
+                    "shards": len(self.engine.memstore.shards(self.engine.dataset)),
+                    **device_facts(),
+                })
             if path == "/__members":
                 # cluster membership contract (reference akka-bootstrapper's
                 # /__members endpoint; coordinator/bootstrap.py). POST with
@@ -1389,6 +1393,19 @@ def make_server(engine: QueryEngine, host: str = "127.0.0.1", port: int = 9090,
         attrs["ARROW_EDGE"] = result_plane.get("peer_exchange", "arrow") == "arrow"
     handler = type("BoundHandler", (PromApiHandler,), attrs)
     return ThreadingHTTPServer((host, port), handler)
+
+
+def device_facts() -> dict:
+    """Where this process's kernels run, as jax reports it (the server's
+    start-up log line and GET /admin/health)."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+    }
 
 
 def serve_background(engine: QueryEngine, host: str = "127.0.0.1", port: int = 0,

@@ -32,9 +32,9 @@ DEFAULTS: dict = {
     "store_root": None,  # None = memory-only (NullColumnStore)
     # persistent XLA compile cache (ops/compile_cache.py): compiled kernel
     # programs survive process restarts, so a rolling deploy skips the
-    # multi-second cold compile. "auto" = <store_root>/jax-compile-cache
-    # (or ~/.cache/filodb-tpu/... when memory-only); a path uses it as-is;
-    # null disables.
+    # multi-second cold compile. "auto" = on, at $JAX_COMPILATION_CACHE_DIR
+    # when the environment sets it, else <checkout>/.jax-compile-cache;
+    # null = off. The knob takes no path: the variable places the cache.
     "compile_cache_dir": "auto",
     # query limits (reference filodb.query circuit breaker / limits)
     "query": {
@@ -262,13 +262,10 @@ DEFAULTS: dict = {
     # the samples as real time series into the "_system" dataset, queryable
     # through the standard query API via ?dataset=_system (so dashboards
     # over the server's own kernel/cache/tenant metrics run through the
-    # fused query path). null disables. tpu_watch_log: path of the
-    # tools/tpu_watch.py log to surface as filodb_tpu_* gauges ("auto" =
-    # <repo>/TPU_WATCH_LOG.txt when present; null disables).
+    # fused query path). null disables.
     "telemetry": {
         "self_scrape_interval_s": None,
         "self_scrape_spread": 1,
-        "tpu_watch_log": "auto",
     },
     # SLO burn-rate recording rules over the query observatory (obs/slo.py,
     # doc/observability.md "SLO burn-rate rules"): a second standing-query
@@ -328,21 +325,6 @@ def force_virtual_devices(n: int) -> None:
         os.environ["XLA_FLAGS"] = (
             flags + f" --xla_force_host_platform_device_count={n}"
         ).strip()
-
-
-def apply_platform_env() -> None:
-    """Honor ``FILODB_PLATFORM`` (e.g. "cpu", "tpu"): force the JAX platform
-    BEFORE first backend init. Deployment images may preload an accelerator
-    plugin via sitecustomize that reads env vars too late and whose backend
-    init can wedge indefinitely when the device link is down — the live jax
-    config override is the only reliable defense (same as tests/conftest.py
-    and __graft_entry__.dryrun_multichip)."""
-    plat = os.environ.get("FILODB_PLATFORM")
-    if plat:
-        os.environ["JAX_PLATFORMS"] = plat
-        import jax
-
-        jax.config.update("jax_platforms", plat)
 
 
 def load_config(path: str | None = None, overrides: dict | None = None) -> dict:

@@ -256,8 +256,10 @@ def batch_downsample(store, memstore, dataset: str, shard_nums, target_memstore,
         import multiprocessing
         from concurrent.futures import ProcessPoolExecutor, as_completed
 
-        # spawn, not fork: a forked child inherits the parent's initialized
-        # JAX/TPU backend state and can wedge on first device touch
+        # spawn, not fork: a forked child would inherit the parent's
+        # initialized JAX backend, and a chip belongs to ONE process. The
+        # workers never need it: this module imports no jax (they decode
+        # and reduce in numpy; tests/test_chip_smoke.py pins that).
         ctx = multiprocessing.get_context("spawn")
         with ProcessPoolExecutor(max_workers=min(max(processes, 1), len(shard_nums) or 1),
                                  mp_context=ctx) as pool:

@@ -12,87 +12,64 @@ tantivy's term dictionaries for the same purpose).
 from __future__ import annotations
 
 import ctypes
-import os
 import re
-import subprocess
-import threading
 from typing import Iterable, Mapping, Sequence
 
 import numpy as np
 
 from ..core.filters import ColumnFilter
+from ..native import NativeLib
 from .index import _LITERAL_ALT, PartKeyIndex, regex_literal_prefix
 
-_HERE = os.path.join(os.path.dirname(__file__), "..", "native")
-_SO = os.path.abspath(os.path.join(_HERE, "libfilodbindex.so"))
-_SRC = os.path.abspath(os.path.join(_HERE, "index.cpp"))
-_lock = threading.Lock()
-_lib = None
-_tried = False
+
+def _bind(L) -> None:
+    c_charpp = ctypes.POINTER(ctypes.c_char_p)
+    c_longp = ctypes.POINTER(ctypes.c_long)
+    c_i32p = ctypes.POINTER(ctypes.c_int32)
+    L.fdb_idx_new.restype = ctypes.c_void_p
+    L.fdb_idx_free.argtypes = [ctypes.c_void_p]
+    L.fdb_idx_add.argtypes = [
+        ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32,
+        c_charpp, c_longp, c_charpp, c_longp, ctypes.c_int64, ctypes.c_int64,
+    ]
+    L.fdb_idx_update_end.argtypes = [ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64]
+    L.fdb_idx_remove.argtypes = [
+        ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32, c_charpp, c_longp, c_charpp, c_longp,
+    ]
+    L.fdb_idx_query.restype = ctypes.c_long
+    L.fdb_idx_query.argtypes = [
+        ctypes.c_void_p, ctypes.c_int32, c_charpp, c_longp, c_charpp, c_longp,
+        ctypes.c_int64, ctypes.c_int64, c_i32p, ctypes.c_long,
+    ]
+    L.fdb_idx_all.restype = ctypes.c_long
+    L.fdb_idx_all.argtypes = [ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, c_i32p, ctypes.c_long]
+    L.fdb_idx_size.restype = ctypes.c_long
+    L.fdb_idx_size.argtypes = [ctypes.c_void_p]
+    L.fdb_idx_values_prefix.restype = ctypes.c_long
+    L.fdb_idx_values_prefix.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_long,
+        ctypes.c_char_p, ctypes.c_long,
+        ctypes.c_char_p, ctypes.c_long, c_longp,
+    ]
+    L.fdb_idx_union.restype = ctypes.c_long
+    L.fdb_idx_union.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_long,
+        ctypes.c_int32, c_charpp, c_longp,
+        ctypes.c_int64, ctypes.c_int64, c_i32p, ctypes.c_long,
+    ]
+    L.fdb_idx_union_prefix.restype = ctypes.c_long
+    L.fdb_idx_union_prefix.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_long,
+        ctypes.c_char_p, ctypes.c_long,
+        ctypes.c_int64, ctypes.c_int64, c_i32p, ctypes.c_long,
+    ]
+
+
+_INDEX = NativeLib("filodbindex", "index.cpp", ("-O3",), _bind)
 
 
 def _load():
-    global _lib, _tried
-    if _lib is not None or _tried:
-        return _lib
-    with _lock:
-        if _lib is not None or _tried:
-            return _lib
-        _tried = True
-        if not os.path.exists(_SO) or os.path.getmtime(_SO) < os.path.getmtime(_SRC):
-            try:
-                subprocess.run(
-                    ["g++", "-O3", "-shared", "-fPIC", _SRC, "-o", _SO],
-                    check=True, capture_output=True, timeout=120,
-                )
-            except Exception:
-                return None
-        try:
-            L = ctypes.CDLL(_SO)
-        except OSError:
-            return None
-        c_charpp = ctypes.POINTER(ctypes.c_char_p)
-        c_longp = ctypes.POINTER(ctypes.c_long)
-        c_i32p = ctypes.POINTER(ctypes.c_int32)
-        L.fdb_idx_new.restype = ctypes.c_void_p
-        L.fdb_idx_free.argtypes = [ctypes.c_void_p]
-        L.fdb_idx_add.argtypes = [
-            ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32,
-            c_charpp, c_longp, c_charpp, c_longp, ctypes.c_int64, ctypes.c_int64,
-        ]
-        L.fdb_idx_update_end.argtypes = [ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64]
-        L.fdb_idx_remove.argtypes = [
-            ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32, c_charpp, c_longp, c_charpp, c_longp,
-        ]
-        L.fdb_idx_query.restype = ctypes.c_long
-        L.fdb_idx_query.argtypes = [
-            ctypes.c_void_p, ctypes.c_int32, c_charpp, c_longp, c_charpp, c_longp,
-            ctypes.c_int64, ctypes.c_int64, c_i32p, ctypes.c_long,
-        ]
-        L.fdb_idx_all.restype = ctypes.c_long
-        L.fdb_idx_all.argtypes = [ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, c_i32p, ctypes.c_long]
-        L.fdb_idx_size.restype = ctypes.c_long
-        L.fdb_idx_size.argtypes = [ctypes.c_void_p]
-        L.fdb_idx_values_prefix.restype = ctypes.c_long
-        L.fdb_idx_values_prefix.argtypes = [
-            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_long,
-            ctypes.c_char_p, ctypes.c_long,
-            ctypes.c_char_p, ctypes.c_long, c_longp,
-        ]
-        L.fdb_idx_union.restype = ctypes.c_long
-        L.fdb_idx_union.argtypes = [
-            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_long,
-            ctypes.c_int32, c_charpp, c_longp,
-            ctypes.c_int64, ctypes.c_int64, c_i32p, ctypes.c_long,
-        ]
-        L.fdb_idx_union_prefix.restype = ctypes.c_long
-        L.fdb_idx_union_prefix.argtypes = [
-            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_long,
-            ctypes.c_char_p, ctypes.c_long,
-            ctypes.c_int64, ctypes.c_int64, c_i32p, ctypes.c_long,
-        ]
-        _lib = L
-        return _lib
+    return _INDEX.load()
 
 
 # regex_literal_prefix moved to memstore/index.py (the bitmap index's

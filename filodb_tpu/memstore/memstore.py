@@ -51,6 +51,11 @@ class TimeSeriesMemStore:
     def shards(self, dataset: str) -> list[TimeSeriesShard]:
         return list(self._datasets.get(dataset, {}).values())
 
+    def local_shard_count(self) -> int:
+        """Shards of EVERY dataset held here — they all stage onto the same
+        device, so its stage-cache share is divided among them."""
+        return sum(len(shards) for shards in self._datasets.values())
+
     def shard_nums(self, dataset: str) -> list[int]:
         return sorted(self._datasets.get(dataset, {}).keys())
 

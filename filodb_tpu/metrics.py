@@ -150,11 +150,6 @@ HELP_TEXTS: dict[str, str] = {
     "filodb_standing_pushes": "Per-subscriber payload deliveries (sent) and stall drops (dropped).",
     "filodb_standing_promotions": "Standing-query lifecycle events (register|promote|demote).",
     "filodb_standing_rule_samples": "Samples written back into the memstore by recording rules.",
-    "filodb_tpu_probe_healthy": "Last tpu-watch probe outcome (1 healthy, 0 not).",
-    "filodb_tpu_probe_age_seconds": "Seconds since the last tpu-watch probe.",
-    "filodb_tpu_probes": "tpu-watch probes attempted (from the watch log).",
-    "filodb_tpu_probes_ok": "tpu-watch probes that found a healthy device.",
-    "filodb_tpu_bench_attested": "tpu-watch attested benchmark measurements.",
     "filodb_query_phase_seconds": "Per-phase query latency decomposition (parse_plan|admission|stage|dispatch|transfer|render|other).",
     "filodb_query_path": "Queries by execution path (fused|fallback|tree|standing:delta|standing:full|standing:serve) per dataset.",
     "filodb_tenant_phase_seconds": "Per-phase query wall seconds attributed to the tenant (ws/ns).",

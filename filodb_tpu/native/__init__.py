@@ -1,72 +1,148 @@
-"""ctypes bindings for the native codec library (reference analog: the Rust
+"""ctypes bindings for the native libraries (reference analog: the Rust
 JNI shims, SimdNativeMethods.scala:15 / TantivyNativeMethods).
 
-Builds libfilodbcodecs.so from codecs.cpp with g++ on first use if missing;
-all callers fall back to the numpy implementations when no compiler exists.
+Each ``lib<stem>.so`` is built with g++ from its committed ``.cpp`` on the
+machine that loads it: the build is stamped with the source's hash, the
+flags and this machine's boot id, and a library whose stamp does not match
+(another host's ``-march=native`` build riding a copied tree, an edited
+source) is rebuilt, never reused. Without a working compiler every caller
+takes its numpy / pure-Python tier, and ``tiers()`` says so.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
 
 import numpy as np
 
-_HERE = os.path.dirname(__file__)
-_SO = os.path.join(_HERE, "libfilodbcodecs.so")
-_SRC = os.path.join(_HERE, "codecs.cpp")
-_lock = threading.Lock()
-_lib = None
-_tried = False
+log = logging.getLogger("filodb_tpu.native")
+
+_HERE = os.path.dirname(os.path.abspath(__file__))
 
 
-def _build() -> bool:
+def _machine_id() -> str:
     try:
-        subprocess.run(
-            ["g++", "-O3", "-march=native", "-shared", "-fPIC", _SRC, "-o", _SO],
-            check=True,
-            capture_output=True,
-            timeout=120,
-        )
-        return True
-    except Exception:
-        return False
+        with open("/proc/sys/kernel/random/boot_id") as f:
+            return f.read().strip()
+    except OSError:
+        return os.uname().nodename
+
+
+LIBS: dict[str, "NativeLib"] = {}
+
+
+class NativeLib:
+    """One g++-built shared library: build-if-unstamped, load, bind — once
+    per process. ``load()`` returns the CDLL or None; ``status`` records
+    which tier the process ended on and why."""
+
+    def __init__(self, stem: str, source: str, flags: tuple[str, ...], bind):
+        self.so = os.path.join(_HERE, f"lib{stem}.so")
+        self.src = os.path.join(_HERE, source)
+        self.flags = flags
+        self._bind = bind
+        self._lock = threading.Lock()
+        self._lib = None
+        self.status = "not loaded"
+        LIBS[stem] = self
+
+    def _stamp(self) -> str:
+        with open(self.src, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()
+        return f"{digest} {' '.join(self.flags)} {_machine_id()}\n"
+
+    def _build(self, stamp: str) -> str | None:
+        """Compile to a temp name and rename (concurrent processes may race
+        to build); returns None on success, else the reason."""
+        tmp = f"{self.so}.{os.getpid()}.tmp"
+        try:
+            subprocess.run(
+                ["g++", *self.flags, "-shared", "-fPIC", self.src, "-o", tmp],
+                check=True, capture_output=True, timeout=120,
+            )
+            os.replace(tmp, self.so)
+            with open(self.so + ".stamp", "w") as f:
+                f.write(stamp)
+        except FileNotFoundError:
+            return "no compiler (g++ not found)"
+        except subprocess.CalledProcessError as e:
+            return f"g++ failed: {e.stderr.decode(errors='replace')[-200:]}"
+        except (subprocess.TimeoutExpired, OSError) as e:
+            return f"build failed: {e}"
+        finally:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
+        return None
+
+    def load(self):
+        if self._lib is not None or self.status != "not loaded":
+            return self._lib
+        with self._lock:
+            if self._lib is not None or self.status != "not loaded":
+                return self._lib
+            stamp = self._stamp()
+            try:
+                with open(self.so + ".stamp") as f:
+                    fresh = f.read() == stamp and os.path.exists(self.so)
+            except OSError:
+                fresh = False
+            err = None if fresh else self._build(stamp)
+            if err is None:
+                try:
+                    L = ctypes.CDLL(self.so)
+                except OSError as e:
+                    err = f"load failed: {e}"
+            if err is not None:
+                self.status = f"fallback: {err}"
+                log.warning("native %s unavailable, using the Python tier: %s",
+                            os.path.basename(self.so), err)
+                return None
+            self._bind(L)
+            self._lib = L
+            self.status = ("native (built here)" if not fresh
+                           else "native (this machine's earlier build)")
+            return L
+
+
+def tiers() -> dict[str, str]:
+    """{library: tier the process ended on} after attempting every load —
+    what chip_smoke.py prints, so "no compiler" is a stated fact."""
+    from ..memstore import index_native  # noqa: F401 — registers its lib
+
+    for nl in LIBS.values():
+        nl.load()
+    return {stem: nl.status for stem, nl in LIBS.items()}
+
+
+def _bind_codecs(L) -> None:
+    L.fdb_nibble_pack.restype = ctypes.c_long
+    L.fdb_nibble_pack.argtypes = [
+        ctypes.POINTER(ctypes.c_uint64), ctypes.c_long,
+        ctypes.POINTER(ctypes.c_uint8), ctypes.c_long,
+    ]
+    L.fdb_nibble_unpack.restype = ctypes.c_long
+    L.fdb_nibble_unpack.argtypes = [
+        ctypes.POINTER(ctypes.c_uint8), ctypes.c_long,
+        ctypes.POINTER(ctypes.c_uint64), ctypes.c_long,
+    ]
+    L.fdb_nan_sum.restype = ctypes.c_double
+    L.fdb_nan_sum.argtypes = [ctypes.POINTER(ctypes.c_double), ctypes.c_long]
+    L.fdb_nan_count.restype = ctypes.c_long
+    L.fdb_nan_count.argtypes = [ctypes.POINTER(ctypes.c_double), ctypes.c_long]
+
+
+_CODECS = NativeLib("filodbcodecs", "codecs.cpp", ("-O3", "-march=native"),
+                    _bind_codecs)
 
 
 def lib():
-    """The loaded native library, or None when unavailable."""
-    global _lib, _tried
-    if _lib is not None or _tried:
-        return _lib
-    with _lock:
-        if _lib is not None or _tried:
-            return _lib
-        _tried = True
-        if not os.path.exists(_SO) or os.path.getmtime(_SO) < os.path.getmtime(_SRC):
-            if not _build():
-                return None
-        try:
-            L = ctypes.CDLL(_SO)
-        except OSError:
-            return None
-        L.fdb_nibble_pack.restype = ctypes.c_long
-        L.fdb_nibble_pack.argtypes = [
-            ctypes.POINTER(ctypes.c_uint64), ctypes.c_long,
-            ctypes.POINTER(ctypes.c_uint8), ctypes.c_long,
-        ]
-        L.fdb_nibble_unpack.restype = ctypes.c_long
-        L.fdb_nibble_unpack.argtypes = [
-            ctypes.POINTER(ctypes.c_uint8), ctypes.c_long,
-            ctypes.POINTER(ctypes.c_uint64), ctypes.c_long,
-        ]
-        L.fdb_nan_sum.restype = ctypes.c_double
-        L.fdb_nan_sum.argtypes = [ctypes.POINTER(ctypes.c_double), ctypes.c_long]
-        L.fdb_nan_count.restype = ctypes.c_long
-        L.fdb_nan_count.argtypes = [ctypes.POINTER(ctypes.c_double), ctypes.c_long]
-        _lib = L
-        return _lib
+    """The loaded native codec library, or None when unavailable."""
+    return _CODECS.load()
 
 
 def nibble_pack_native(values: np.ndarray) -> bytes | None:
@@ -121,11 +197,6 @@ def nan_count(values: np.ndarray) -> int:
 # Prometheus text-exposition scanner (promparse.cpp -> libfilodbprom.so)
 # ---------------------------------------------------------------------------
 
-_PROM_SO = os.path.join(_HERE, "libfilodbprom.so")
-_PROM_SRC = os.path.join(_HERE, "promparse.cpp")
-_prom_lib = None
-_prom_tried = False
-
 # must mirror FdbPromRec in promparse.cpp (x86-64 struct layout, 8-aligned)
 PROM_REC_DTYPE = np.dtype(
     {
@@ -139,39 +210,21 @@ PROM_REC_DTYPE = np.dtype(
 TS_ABSENT = np.iinfo(np.int64).min
 
 
-def prom_lib():
-    global _prom_lib, _prom_tried
-    if _prom_lib is not None or _prom_tried:
-        return _prom_lib
-    with _lock:
-        if _prom_lib is not None or _prom_tried:
-            return _prom_lib
-        _prom_tried = True
-        try:  # binary-only deployments may ship the .so without the source
-            stale = (not os.path.exists(_PROM_SO)
-                     or os.path.getmtime(_PROM_SO) < os.path.getmtime(_PROM_SRC))
-        except OSError:
-            stale = not os.path.exists(_PROM_SO)
-        if stale:
-            try:
-                subprocess.run(
-                    ["g++", "-O3", "-march=native", "-std=c++17", "-shared",
-                     "-fPIC", _PROM_SRC, "-o", _PROM_SO],
-                    check=True, capture_output=True, timeout=120,
-                )
-            except Exception:
-                return None
-        try:
-            L = ctypes.CDLL(_PROM_SO)
-        except OSError:
-            return None
-        L.fdb_parse_prom.restype = ctypes.c_long
-        L.fdb_parse_prom.argtypes = [
+def _bind_prom(L) -> None:
+    for fn in (L.fdb_parse_prom, L.fdb_parse_influx):
+        fn.restype = ctypes.c_long
+        fn.argtypes = [
             ctypes.c_char_p, ctypes.c_long,
             ctypes.c_void_p, ctypes.c_long,
         ]
-        _prom_lib = L
-        return _prom_lib
+
+
+_PROM = NativeLib("filodbprom", "promparse.cpp",
+                  ("-O3", "-march=native", "-std=c++17"), _bind_prom)
+
+
+def prom_lib():
+    return _PROM.load()
 
 
 # splitlines() separators the byte scanner cannot see (multi-byte UTF-8):
@@ -219,12 +272,6 @@ def parse_influx_records(payload: bytes):
         return None
     if any(s in payload for s in _UNICODE_SEPS):
         return None
-    if not hasattr(L, "_influx_bound"):
-        L.fdb_parse_influx.restype = ctypes.c_long
-        L.fdb_parse_influx.argtypes = [
-            ctypes.c_char_p, ctypes.c_long, ctypes.c_void_p, ctypes.c_long,
-        ]
-        L._influx_bound = True
     # a line can hold many fields: size by commas+lines (upper bound)
     max_out = (sum(payload.count(s) for s in b"\n\r\v\f\x1c\x1d\x1e")
                + payload.count(b",") + 2)
@@ -239,59 +286,36 @@ def parse_influx_records(payload: bytes):
 # Prometheus JSON sample renderer (promrender.cpp -> libfilodbrender.so)
 # ---------------------------------------------------------------------------
 
-_RENDER_SO = os.path.join(_HERE, "libfilodbrender.so")
-_RENDER_SRC = os.path.join(_HERE, "promrender.cpp")
-_render_lib = None
-_render_tried = False
 _render_scratch = threading.local()
 
 
+def _bind_render(L) -> None:
+    for name, vt in (("fdb_render_values_f64", ctypes.POINTER(ctypes.c_double)),
+                     ("fdb_render_values_f32", ctypes.POINTER(ctypes.c_float))):
+        fn = getattr(L, name)
+        fn.restype = ctypes.c_long
+        fn.argtypes = [ctypes.POINTER(ctypes.c_double), vt,
+                       ctypes.c_long, ctypes.c_void_p, ctypes.c_long]
+    for name, vt in (("fdb_render_matrix_f64", ctypes.POINTER(ctypes.c_double)),
+                     ("fdb_render_matrix_f32", ctypes.POINTER(ctypes.c_float))):
+        fn = getattr(L, name)
+        fn.restype = ctypes.c_longlong
+        fn.argtypes = [ctypes.POINTER(ctypes.c_double), vt,
+                       ctypes.c_longlong, ctypes.c_longlong,
+                       ctypes.c_void_p, ctypes.c_longlong,
+                       ctypes.POINTER(ctypes.c_longlong)]
+    L.fdb_format_double.restype = ctypes.c_int
+    L.fdb_format_double.argtypes = [ctypes.c_double, ctypes.c_char_p]
+    L.fdb_fmt_slow_count.restype = ctypes.c_long
+    L.fdb_fmt_slow_count.argtypes = []
+
+
+_RENDER = NativeLib("filodbrender", "promrender.cpp",
+                    ("-O3", "-march=native", "-std=c++17"), _bind_render)
+
+
 def render_lib():
-    global _render_lib, _render_tried
-    if _render_lib is not None or _render_tried:
-        return _render_lib
-    with _lock:
-        if _render_lib is not None or _render_tried:
-            return _render_lib
-        _render_tried = True
-        try:
-            stale = (not os.path.exists(_RENDER_SO)
-                     or os.path.getmtime(_RENDER_SO) < os.path.getmtime(_RENDER_SRC))
-        except OSError:
-            stale = not os.path.exists(_RENDER_SO)
-        if stale:
-            try:
-                subprocess.run(
-                    ["g++", "-O3", "-march=native", "-std=c++17", "-shared",
-                     "-fPIC", _RENDER_SRC, "-o", _RENDER_SO],
-                    check=True, capture_output=True, timeout=120,
-                )
-            except Exception:
-                return None
-        try:
-            L = ctypes.CDLL(_RENDER_SO)
-        except OSError:
-            return None
-        for name, vt in (("fdb_render_values_f64", ctypes.POINTER(ctypes.c_double)),
-                         ("fdb_render_values_f32", ctypes.POINTER(ctypes.c_float))):
-            fn = getattr(L, name)
-            fn.restype = ctypes.c_long
-            fn.argtypes = [ctypes.POINTER(ctypes.c_double), vt,
-                           ctypes.c_long, ctypes.c_void_p, ctypes.c_long]
-        for name, vt in (("fdb_render_matrix_f64", ctypes.POINTER(ctypes.c_double)),
-                         ("fdb_render_matrix_f32", ctypes.POINTER(ctypes.c_float))):
-            fn = getattr(L, name)
-            fn.restype = ctypes.c_longlong
-            fn.argtypes = [ctypes.POINTER(ctypes.c_double), vt,
-                           ctypes.c_longlong, ctypes.c_longlong,
-                           ctypes.c_void_p, ctypes.c_longlong,
-                           ctypes.POINTER(ctypes.c_longlong)]
-        L.fdb_format_double.restype = ctypes.c_int
-        L.fdb_format_double.argtypes = [ctypes.c_double, ctypes.c_char_p]
-        L.fdb_fmt_slow_count.restype = ctypes.c_long
-        L.fdb_fmt_slow_count.argtypes = []
-        _render_lib = L
-        return _render_lib
+    return _RENDER.load()
 
 
 def render_values(ts_s: np.ndarray, vals: np.ndarray):

@@ -175,7 +175,7 @@ def _pallas_variant(block, func: str, mesh) -> bool:
         return False
     from .pallas_kernels import PALLAS_FUNCS, pallas_enabled
 
-    return func in PALLAS_FUNCS and pallas_enabled()
+    return func in PALLAS_FUNCS and pallas_enabled(block.ts.shape[1])
 
 
 def batch_variant_supported(block, func: str, kind: str, is_delta: bool,
@@ -415,10 +415,11 @@ def _fused_pallas_jit(func, epilogue, ts, vals, raw, lens, gids, n_real, qv,
                       interpret: bool):
     """Truly-irregular-grid fused variant: the one-pass Pallas window-stats
     kernel (ops/pallas_kernels.window_aggregates, VMEM-tiled gather-scan) +
-    its finisher + the epilogue behind the SAME jit boundary — interpret
-    mode on CPU (tier-1), compiled on TPU. The Pallas grid pads S/J up to
-    its tile sizes; slice back to the block's own padding before the
-    epilogue so the trash-group/gids contract is unchanged."""
+    its finisher + the epilogue behind the SAME jit boundary (``interpret``
+    comes from pallas_kernels.interpret_mode: CPU backend only). The
+    Pallas grid pads S/J up to its tile sizes; slice back to the block's
+    own padding before the epilogue so the trash-group/gids contract is
+    unchanged."""
     from .pallas_kernels import finish, window_aggregates
 
     agg = window_aggregates(
@@ -506,7 +507,6 @@ def _fused_sharded_general_jit(mesh, func, epilogue, ts, vals, lens, baseline,
     outputs exist."""
     from jax.sharding import PartitionSpec as P
 
-    from ..jax_compat import shard_map
     from .kernels import range_kernel
 
     axis = mesh.axis_names[0]
@@ -520,11 +520,11 @@ def _fused_sharded_general_jit(mesh, func, epilogue, ts, vals, lens, baseline,
                                  num_groups, axis)
 
     row, vec = P(axis, None), P(axis)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(row, row, vec, vec, row, vec),
         out_specs=_sharded_out_specs(epilogue),
-        check=False,
+        check_vma=False,
     )(ts, vals, lens, baseline, raw, gids)
 
 
@@ -543,7 +543,6 @@ def _fused_sharded_mxu_jit(mesh, func, epilogue, vals, raw, baseline, W, F, L,
     the same program."""
     from jax.sharding import PartitionSpec as P
 
-    from ..jax_compat import shard_map
     from .mxu_kernels import mxu_range_kernel
 
     axis = mesh.axis_names[0]
@@ -558,11 +557,11 @@ def _fused_sharded_mxu_jit(mesh, func, epilogue, vals, raw, baseline, W, F, L,
                                  num_groups, axis)
 
     row, vec = P(axis, None), P(axis)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(row, row, vec, vec),
         out_specs=_sharded_out_specs(epilogue),
-        check=False,
+        check_vma=False,
     )(vals, raw, baseline, gids)
 
 
@@ -580,7 +579,6 @@ def _fused_sharded_jitter_jit(mesh, func, epilogue, vals, dev, raw, jwm,
     longer drops to the sharded general kernel (the PR 8 remainder)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..jax_compat import shard_map
     from .mxu_jitter import jitter_range_kernel
 
     axis = mesh.axis_names[0]
@@ -594,11 +592,11 @@ def _fused_sharded_jitter_jit(mesh, func, epilogue, vals, dev, raw, jwm,
                                  num_groups, axis)
 
     row, vec = P(axis, None), P(axis)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(row, row, row, vec),
         out_specs=_sharded_out_specs(epilogue),
-        check=False,
+        check_vma=False,
     )(vals, dev, raw, gids)
 
 
@@ -614,7 +612,6 @@ def _fused_sharded_masked_jit(mesh, func, epilogue, mba, mwm, window_ms,
     the replicated masked window structure rides the closure."""
     from jax.sharding import PartitionSpec as P
 
-    from ..jax_compat import shard_map
     from .mxu_jitter import jitter_masked_kernel
 
     axis = mesh.axis_names[0]
@@ -629,11 +626,11 @@ def _fused_sharded_masked_jit(mesh, func, epilogue, mba, mwm, window_ms,
                                  num_groups, axis)
 
     row, vec = P(axis, None), P(axis)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(tuple(row for _ in mba), vec),
         out_specs=_sharded_out_specs(epilogue),
-        check=False,
+        check_vma=False,
     )(mba, gids)
 
 
@@ -649,7 +646,6 @@ def _fused_sharded_jitter_minmax_jit(mesh, func, epilogue, vals, dev, jmm,
     sharding), and the epilogue combines over the mesh in one program."""
     from jax.sharding import PartitionSpec as P
 
-    from ..jax_compat import shard_map
     from .mxu_jitter import jitter_minmax
 
     axis = mesh.axis_names[0]
@@ -663,11 +659,11 @@ def _fused_sharded_jitter_minmax_jit(mesh, func, epilogue, vals, dev, jmm,
                                  num_groups, axis)
 
     row, vec = P(axis, None), P(axis)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(row, row, vec),
         out_specs=_sharded_out_specs(epilogue),
-        check=False,
+        check_vma=False,
     )(vals, dev, gids)
 
 
@@ -681,7 +677,6 @@ def _fused_sharded_masked_minmax_jit(mesh, func, epilogue, vals, dev, valid,
     arrays, replicated minmax structures in the closure)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..jax_compat import shard_map
     from .mxu_jitter import jitter_masked_minmax
 
     axis = mesh.axis_names[0]
@@ -695,11 +690,11 @@ def _fused_sharded_masked_minmax_jit(mesh, func, epilogue, vals, dev, valid,
                                  num_groups, axis)
 
     row, vec = P(axis, None), P(axis)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(row, row, row, row, vec),
         out_specs=_sharded_out_specs(epilogue),
-        check=False,
+        check_vma=False,
     )(vals, dev, valid, cc, gids)
 
 
@@ -861,13 +856,14 @@ def _fused_dispatch(func: str, epilogue: tuple, block, gids_padded,
             else:
                 fn, args = _fused_masked_jit, common
     elif variant == "pallas":
+        from .pallas_kernels import interpret_mode
+
         fn = _fused_pallas_jit
         args = (
             func, epilogue, block.ts, block.vals, raw, block.lens,
             gids_padded, n_real, qv, np.int32(start_off),
             np.int32(params.step_ms), np.int32(params.window_ms), j_pad,
-            num_groups, is_counter, is_delta,
-            jax.devices()[0].platform in ("cpu",),
+            num_groups, is_counter, is_delta, interpret_mode(),
         )
     elif mesh is not None:
         fn = _fused_sharded_general_jit
@@ -1325,7 +1321,6 @@ def _batched_sharded_general_jit(mesh, func, epilogue, ts, vals, lens,
     body, so one multi-device program serves every lane."""
     from jax.sharding import PartitionSpec as P
 
-    from ..jax_compat import shard_map
     from .kernels import range_kernel
 
     axis = mesh.axis_names[0]
@@ -1347,11 +1342,11 @@ def _batched_sharded_general_jit(mesh, func, epilogue, ts, vals, lens,
         return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
 
     row, vec = P(axis, None), P(axis)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(row, row, vec, vec, row, P(None, axis)),
         out_specs=_sharded_out_specs(epilogue),
-        check=False,
+        check_vma=False,
     )(ts, vals, lens, baseline, raw, gids_q)
 
 
@@ -1366,7 +1361,6 @@ def _batched_sharded_mxu_jit(mesh, func, epilogue, vals, raw, baseline, W_u,
                              is_counter: bool, is_delta: bool, fetch: str):
     from jax.sharding import PartitionSpec as P
 
-    from ..jax_compat import shard_map
     from .mxu_kernels import mxu_range_kernel
 
     axis = mesh.axis_names[0]
@@ -1389,11 +1383,11 @@ def _batched_sharded_mxu_jit(mesh, func, epilogue, vals, raw, baseline, W_u,
         return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
 
     row, vec = P(axis, None), P(axis)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(row, row, vec, P(None, axis)),
         out_specs=_sharded_out_specs(epilogue),
-        check=False,
+        check_vma=False,
     )(vals, raw, baseline, gids_q)
 
 
@@ -1412,7 +1406,6 @@ def _batched_sharded_jitter_jit(mesh, func, epilogue, vals, dev, raw, wm_u,
     lanes coalesce instead of dropping to per-lane dispatch."""
     from jax.sharding import PartitionSpec as P
 
-    from ..jax_compat import shard_map
     from .mxu_jitter import jitter_range_kernel
 
     axis = mesh.axis_names[0]
@@ -1434,11 +1427,11 @@ def _batched_sharded_jitter_jit(mesh, func, epilogue, vals, dev, raw, wm_u,
         return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
 
     row = P(axis, None)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(row, row, row, P(None, axis)),
         out_specs=_sharded_out_specs(epilogue),
-        check=False,
+        check_vma=False,
     )(vals, dev, raw, gids_q)
 
 
@@ -1455,7 +1448,6 @@ def _batched_sharded_masked_jit(mesh, func, epilogue, mba, wm_u,
     arrays, replicated stacked masked window structures in the closure)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..jax_compat import shard_map
     from .mxu_jitter import jitter_masked_kernel
 
     axis = mesh.axis_names[0]
@@ -1477,11 +1469,11 @@ def _batched_sharded_masked_jit(mesh, func, epilogue, mba, wm_u,
         return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
 
     row = P(axis, None)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(tuple(row for _ in mba), P(None, axis)),
         out_specs=_sharded_out_specs(epilogue),
-        check=False,
+        check_vma=False,
     )(mba, gids_q)
 
 

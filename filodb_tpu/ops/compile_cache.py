@@ -1,5 +1,6 @@
 """Persistent JAX compilation cache (SURVEY §7: recompilation is the #1
-risk; BENCH_r05 measured a 24.6 s cold stage+compile warmup).
+risk; the cold stage+compile of the canonical 100k-series query is tens of
+seconds).
 
 XLA executables for the shape-bucketed kernel set are small and extremely
 reusable — padding discipline (staging.pad_series/pad_time, kernels
@@ -8,12 +9,13 @@ then never again. Persisting them to disk makes that true ACROSS process
 restarts too: a rolling deploy or crash-restart skips straight to warm
 dispatch latencies instead of re-paying multi-second XLA compiles.
 
-Config: top-level ``compile_cache_dir`` —
-
-- ``"auto"`` (default): ``<store_root>/jax-compile-cache`` when a data dir
-  is configured, else ``~/.cache/filodb-tpu/jax-compile-cache``;
-- an explicit path: used as-is;
-- ``null``/empty: disabled.
+Placement — ONE rule, shared by the server, ``bench.py`` and
+``chip_smoke.py`` (``cache_dir``): ``JAX_COMPILATION_CACHE_DIR`` when the
+environment sets it, else ``<checkout>/.jax-compile-cache``. The directory
+is part of jax's cache key, so it is never derived from anything that moves
+between runs (a data dir, ``$HOME``, a pid, the clock). The top-level
+``compile_cache_dir`` config knob only turns the cache on (``"auto"``,
+default) or off (``null``).
 
 Thresholds are forced to zero so even the fast-compiling CPU-backend
 programs persist (jax's defaults skip entries under 1s compile time, which
@@ -108,70 +110,58 @@ def classify_dispatch(compiled: bool) -> tuple[str, int | None]:
     return "persistent", None
 
 
-def resolve_cache_dir(config: dict) -> str | None:
-    """Map the ``compile_cache_dir`` knob to a concrete path (or None)."""
-    raw = config.get("compile_cache_dir", "auto")
-    if not raw:
-        return None
-    if raw != "auto":
-        return str(raw)
-    store_root = config.get("store_root")
-    if store_root:
-        return os.path.join(str(store_root), "jax-compile-cache")
-    return os.path.join(
-        os.path.expanduser(os.environ.get("XDG_CACHE_HOME", "~/.cache")),
-        "filodb-tpu", "jax-compile-cache",
-    )
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax-compile-cache",
+)
 
 
-def enable_compile_cache(cache_dir: str | None) -> str | None:
-    """Point jax's persistent compilation cache at ``cache_dir``.
+def cache_dir() -> str:
+    """The placement rule (module docstring): the environment's directory,
+    else the checkout's."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _CHECKOUT_CACHE_DIR
 
-    Idempotent; returns the active dir or None when disabled/unsupported.
-    Must run before the first jit dispatch to benefit that process's cold
-    start (later calls still help subsequent compiles)."""
-    global _enabled_dir
-    if not cache_dir:
-        return None
-    if _enabled_dir == cache_dir:
-        return _enabled_dir
+
+def enable_compile_cache() -> str | None:
+    """Turn on jax's persistent compilation cache at ``cache_dir()``.
+
+    Idempotent; returns the active dir, or None when the directory cannot
+    be created (the cache is an optimization: a read-only checkout must not
+    stop the server; chip_smoke.py, which needs it, checks jax's config
+    for the directory). Must run before the first jit dispatch to benefit that
+    process's cold start (later calls still help subsequent compiles)."""
+    global _enabled_dir, _seen_entries
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as jcc
+
+    d = cache_dir()
+    if _enabled_dir == d:
+        return d
     try:
-        import jax
-
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        for knob, v in (
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-            ("jax_persistent_cache_min_entry_size_bytes", -1),
-        ):
-            try:
-                jax.config.update(knob, v)
-            except (AttributeError, ValueError):  # knob renamed/absent
-                pass
-        try:
-            # jax latches a cache-unused verdict at the FIRST compile
-            # (compilation_cache._cache_checked, initialized at most once):
-            # a process that compiled anything before this call would
-            # silently never persist. Reset so the new dir takes effect —
-            # existing executables stay in the in-process jit caches.
-            from jax._src import compilation_cache as _jcc
-
-            _jcc.reset_cache()
-        except Exception:  # noqa: BLE001 — internal API; best-effort
-            pass
-        _enabled_dir = cache_dir
-        # seed the provenance baseline: entries already on disk must read
-        # as persistent-cache HITS when a compile deserializes them, not
-        # as fresh traces (classify_dispatch diffs against this set)
-        global _seen_entries
-        with _seen_lock:
-            _seen_entries = set(_list_entries(cache_dir))
-        _register_ledger_account(cache_dir)
-        log.info("persistent jax compile cache at %s", cache_dir)
-    except Exception as e:  # noqa: BLE001 — cache is an optimization, never fatal
-        log.warning("persistent compile cache unavailable: %s", e)
+        os.makedirs(d, exist_ok=True)
+    except OSError as e:
+        log.warning("persistent compile cache unavailable at %s: %s", d, e)
         return None
-    return _enabled_dir
+    # with the variable set at start-up jax already holds this value and
+    # nothing here sets another
+    if jax.config.jax_compilation_cache_dir != d:
+        jax.config.update("jax_compilation_cache_dir", d)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # jax latches a cache-unused verdict at the FIRST compile: a process
+    # that compiled anything before this call would silently never persist.
+    # Reset so the dir takes effect — existing executables stay in the
+    # in-process jit caches.
+    jcc.reset_cache()
+    _enabled_dir = d
+    # seed the provenance baseline: entries already on disk must read as
+    # persistent-cache HITS when a compile deserializes them, not as fresh
+    # traces (classify_dispatch diffs against this set)
+    with _seen_lock:
+        _seen_entries = set(_list_entries(d))
+    _register_ledger_account(d)
+    log.info("persistent jax compile cache at %s", d)
+    return d
 
 
 class _CompileCacheProbe:
@@ -249,4 +239,14 @@ def _register_ledger_account(cache_dir: str) -> None:
 
 
 def enable_from_config(config: dict) -> str | None:
-    return enable_compile_cache(resolve_cache_dir(config))
+    """``compile_cache_dir``: ``"auto"`` enables, ``null`` disables. A path
+    is refused — placing the cache is JAX_COMPILATION_CACHE_DIR's job."""
+    knob = config.get("compile_cache_dir", "auto")
+    if not knob:
+        return None
+    if knob != "auto":
+        raise ValueError(
+            f"compile_cache_dir={knob!r}: the knob is \"auto\" or null; "
+            "set JAX_COMPILATION_CACHE_DIR to place the cache"
+        )
+    return enable_compile_cache()

@@ -306,8 +306,6 @@ def _fused_hist_jitter_sharded_jit(mesh, func, vals, dev, hwa, window, gids,
     structure rides the closure; [S, T, B] vals and [S, T] dev row bands)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..jax_compat import shard_map
-
     axis = mesh.axis_names[0]
 
     def local(vals_l, dev_l, gids_l):
@@ -316,10 +314,10 @@ def _fused_hist_jitter_sharded_jit(mesh, func, vals, dev, hwa, window, gids,
             sjb, gids_l, les, qv, num_groups, quantile, axis
         )
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None, None), P(axis, None), P(axis)),
-        out_specs=P(), check=False,
+        out_specs=P(), check_vma=False,
     )(vals, dev, gids)
 
 
@@ -412,8 +410,6 @@ def _fused_hist_shared_sharded_jit(mesh, func, vals, lo, hi, t_first, t_last,
     psum across the mesh inside the same program."""
     from jax.sharding import PartitionSpec as P
 
-    from ..jax_compat import shard_map
-
     axis = mesh.axis_names[0]
 
     def local(vals_l, gids_l):
@@ -424,9 +420,9 @@ def _fused_hist_shared_sharded_jit(mesh, func, vals, lo, hi, t_first, t_last,
             sjb, gids_l, les, qv, num_groups, quantile, axis
         )
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(P(axis, None, None), P(axis)),
-        out_specs=P(), check=False,
+        out_specs=P(), check_vma=False,
     )(vals, gids)
 
 
@@ -440,8 +436,6 @@ def _fused_hist_sharded_jit(mesh, func, ts, vals, lens, gids, les, qv,
     boundaries)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..jax_compat import shard_map
-
     axis = mesh.axis_names[0]
 
     def local(ts_l, vals_l, lens_l, gids_l):
@@ -453,10 +447,10 @@ def _fused_hist_sharded_jit(mesh, func, ts, vals, lens, gids, les, qv,
             sjb, gids_l, les, qv, num_groups, quantile, axis
         )
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None, None), P(axis), P(axis)),
-        out_specs=P(), check=False,
+        out_specs=P(), check_vma=False,
     )(ts, vals, lens, gids)
 
 
@@ -530,8 +524,6 @@ def _batched_hist_shared_sharded_jit(mesh, func, vals, lo_u, hi_u, tf_u,
                                      is_delta: bool, quantile: bool):
     from jax.sharding import PartitionSpec as P
 
-    from ..jax_compat import shard_map
-
     axis = mesh.axis_names[0]
 
     def local(vals_l, gids_ql):
@@ -550,10 +542,10 @@ def _batched_hist_shared_sharded_jit(mesh, func, vals, lo_u, hi_u, tf_u,
             for i in range(len(u_map))
         ])
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None, None), P(None, axis)),
-        out_specs=P(), check=False,
+        out_specs=P(), check_vma=False,
     )(vals, gids_q)
 
 
@@ -566,8 +558,6 @@ def _batched_hist_sharded_jit(mesh, func, ts, vals, lens, gids_q, les, qv_q,
                               num_steps: int, num_groups: int,
                               is_delta: bool, quantile: bool):
     from jax.sharding import PartitionSpec as P
-
-    from ..jax_compat import shard_map
 
     axis = mesh.axis_names[0]
 
@@ -587,11 +577,11 @@ def _batched_hist_sharded_jit(mesh, func, ts, vals, lens, gids_q, les, qv_q,
             for i in range(len(u_map))
         ])
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None, None), P(axis),
                   P(None, axis)),
-        out_specs=P(), check=False,
+        out_specs=P(), check_vma=False,
     )(ts, vals, lens, gids_q)
 
 

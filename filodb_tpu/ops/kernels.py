@@ -562,15 +562,13 @@ def _dispatch_range_function(
         run_pallas_range_function,
     )
 
-    if func in PALLAS_FUNCS and not args and pallas_enabled():
-        import jax as _jax
-
+    if (func in PALLAS_FUNCS and not args
+            and pallas_enabled(block.ts.shape[1])):
         # the ONE FILODB_PALLAS policy (pallas_kernels.pallas_enabled),
-        # shared with the fused dispatch ladder: the one-pass VMEM kernel
-        # on real hardware, interpret mode on CPU only when forced
+        # shared with the fused dispatch ladder: the one-pass VMEM kernel,
+        # compiled on real hardware (interpreted on CPU only when forced)
         return run_pallas_range_function(
             func, block, params, is_counter=is_counter, is_delta=is_delta,
-            interpret=_jax.devices()[0].platform in ("cpu",),
         ), "pallas"
     j_pad = pad_steps(params.num_steps)
     start_off = np.int32(params.start_ms - block.base_ms)

@@ -9,8 +9,9 @@ that reuses the series block across step tiles (the block index map keeps
 ts/vals constant along the step axis, so Pallas skips the re-fetch DMA).
 
 A small jit finisher then derives any range function from these statistics
-(Prometheus extrapolation for rate/increase/delta). Runs in interpret mode
-on CPU for tests; compiled on TPU via ``interpret=False``.
+(Prometheus extrapolation for rate/increase/delta). Compiled by Mosaic on
+a TPU; interpret mode exists for the CPU backend only (``interpret_mode``
+— the one place that decides, from the platform jax reports).
 """
 
 from __future__ import annotations
@@ -84,7 +85,7 @@ def _window_agg_kernel(params_ref, ts_ref, vals_ref, raw_ref, lens_ref,
 
 @functools.partial(jax.jit, static_argnames=("num_steps", "interpret"))
 def window_aggregates(ts, vals, raw, lens, start_off, step_ms, window_ms,
-                      num_steps: int, interpret: bool = True):
+                      num_steps: int, interpret: bool):
     """[S, T] staged block -> dict of [S, num_steps] per-window statistics."""
     S, T = ts.shape
     S_pad = ((S + BS - 1) // BS) * BS
@@ -127,18 +128,36 @@ PALLAS_FUNCS = {
 }
 
 
-def pallas_enabled() -> bool:
-    """The ONE FILODB_PALLAS policy, shared by the legacy range-function
-    dispatch (kernels._dispatch_range_function) and the fused variant
-    ladder (aggregations._pallas_variant): "0" disables outright; "auto"
-    (default) selects the one-pass VMEM kernel on real accelerators only
-    (measured ~23% over the multi-pass general path on irregular blocks,
-    BENCH_LOCAL.json pallas_vs_general); "1" forces it everywhere —
-    interpret mode on CPU, which is for tests."""
+def interpret_mode() -> bool:
+    """Whether the Pallas kernel runs interpreted: on the CPU backend only
+    (tier-1). On an accelerator it is always compiled — a kernel Mosaic
+    refuses is an error to repair, never a quiet interpreter run."""
+    return jax.devices()[0].platform == "cpu"
+
+
+# Widest staged block (padded samples per series) the kernel is selected
+# for. Its row tiles are (BS, T) with the whole T resident in VMEM. Measured
+# on ONE generation, a TPU v5e ("TPU v5 lite", jax 0.9.0): compiles and runs
+# at T=1024/2048/4096, Mosaic runs out of VMEM at T=6144 — not bisected in
+# between, so 4096 is the widest width SEEN to work, not the limit (chip
+# sweep, CHANGES.md PR 21). Wider irregular blocks — a >11 h selector at a
+# 10 s scrape — take the general kernel, chosen here from the shape, never by
+# catching the compile error; chip_smoke.py runs one set on each side.
+MAX_T = 4096
+
+
+def pallas_enabled(t_pad: int) -> bool:
+    """The ONE Pallas selection policy for a block of padded width
+    ``t_pad``, shared by the legacy range-function dispatch
+    (kernels._dispatch_range_function) and the fused variant ladder
+    (aggregations._pallas_variant): never past MAX_T; FILODB_PALLAS "0"
+    disables outright; "auto" (default) selects the one-pass VMEM kernel on
+    real accelerators only; "1" forces it everywhere — interpret mode on
+    CPU, which is for tests."""
     import os
 
     mode = os.environ.get("FILODB_PALLAS", "auto")
-    if mode == "0":
+    if mode == "0" or t_pad > MAX_T:
         return False
     return jax.devices()[0].platform not in ("cpu",) or mode == "1"
 
@@ -198,7 +217,7 @@ def finish(func: str, agg: dict, start_off, step_ms, window_ms,
 
 
 def run_pallas_range_function(func: str, block: StagedBlock, params,
-                              is_counter=False, is_delta=False, interpret=True):
+                              is_counter=False, is_delta=False):
     from .kernels import pad_steps
 
     J = pad_steps(params.num_steps)
@@ -207,7 +226,7 @@ def run_pallas_range_function(func: str, block: StagedBlock, params,
     agg = window_aggregates(
         block.ts, block.vals, raw, block.lens,
         start_off, np.int32(params.step_ms), np.int32(params.window_ms), J,
-        interpret=interpret,
+        interpret=interpret_mode(),
     )
     return finish(func, agg, start_off, np.int32(params.step_ms), np.int32(params.window_ms),
                   is_counter=is_counter, is_delta=is_delta)

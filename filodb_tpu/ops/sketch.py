@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..jax_compat import shard_map
 
 SUB = 32  # sub-bins per octave
 E_MIN = -24  # 2^-24 ~ 6e-8: smaller magnitudes collapse to the zero bin
@@ -136,12 +135,12 @@ def distributed_sketch_quantile(
 
     shard = P("shard")
     row = P("shard", None)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(row, row, shard, shard, row, shard),
         out_specs=P(),
-        check=False,
+        check_vma=False,
     )(ts, vals, lens, baseline, raw, gids)
 
 
@@ -291,12 +290,12 @@ def rollup_agg_sketch_quantile(func: str, mn, mx, sm, cnt, clast, gids, q,
         from jax.sharding import PartitionSpec as P
 
         row = P("shard", None)
-        merged = shard_map(
+        merged = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(row, row, row, row, row, P("shard")),
             out_specs=P(),
-            check=False,
+            check_vma=False,
         )(mn, mx, sm, cnt, clast, gids)
     return _sketch_readoff(merged, centers, q)
 

@@ -26,6 +26,7 @@ Shape discipline (SURVEY.md §7 "ragged data vs static shapes" — the #1 risk):
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from collections import OrderedDict
@@ -1120,6 +1121,54 @@ def _slot_align(shard, part_ids, column, series, start_ms: int, end_ms: int):
     return out
 
 
+# Shares of EACH device's memory the two device-resident caches may pin
+# there. Their byte ceilings (SuperblockCache.max_bytes 8 GiB, StoreConfig
+# .stage_cache_bytes 2 GiB x shards) were set without a chip and together
+# exceed a 16 GB v5e: at 100k series x 8 shards the shipped defaults ran
+# HBM out after ~8 distinct query keys (CHANGES.md PR 21). The rest of the
+# device is for what the caches do not count: the block being built while
+# the old ones are still pinned, window structures, kernel temporaries
+# (2.5 GB over the ledger at the peak of that run). The two figures come
+# from that ONE deployment size on a v5e — they bound the duplication
+# (per-shard blocks pinned beside the superblock built from them, the
+# pre-warm restaging each key), they do not repair it: ROADMAP S2 / D4.
+SUPERBLOCK_DEVICE_SHARE = 0.35
+STAGE_CACHE_DEVICE_SHARE = 0.20
+
+
+@functools.lru_cache(maxsize=1)
+def _device_bytes_limits() -> dict:
+    """``memory_stats()["bytes_limit"]`` of every visible device, keyed by
+    ``str(device)`` (the key ``mesh_device_bytes`` uses); a device whose
+    backend reports none (the CPU backend) is absent."""
+    import jax
+
+    out = {}
+    for d in jax.devices():
+        stats = d.memory_stats()
+        if stats and stats.get("bytes_limit"):
+            out[str(d)] = int(stats["bytes_limit"])
+    return out
+
+
+@functools.lru_cache(maxsize=1)
+def default_device_key() -> str:
+    """``str()`` of the device an un-sharded ``jax.device_put`` lands on."""
+    import jax
+
+    return str(jax.devices()[0])
+
+
+def device_cache_budget(share: float, ceiling: int,
+                        device: str | None = None) -> int:
+    """Bytes a device-resident cache may pin on ``device`` (default: where
+    un-sharded blocks land): ``share`` of that device's memory, never above
+    the configured ``ceiling`` — the ceiling alone where the device reports
+    no limit."""
+    limit = _device_bytes_limits().get(device or default_device_key())
+    return ceiling if limit is None else min(ceiling, int(limit * share))
+
+
 def staged_nbytes(block: StagedBlock) -> int:
     """True device-byte footprint of a staged block: every array a
     ``to_device`` pins in HBM. Histogram blocks carry [S, T, B] vals and
@@ -1318,6 +1367,9 @@ class SuperblockCache:
         from ..singleflight import KeyedSingleFlight
 
         self.max_entries = max_entries
+        # the knob: a ceiling on all entries together. Each device holds at
+        # most its own share besides (_fits): an un-sharded entry is charged
+        # whole to the default device, a sharded one band by band
         self.max_bytes = max_bytes
         self._d: OrderedDict = OrderedDict()
         # per-key introspection sidecar for /debug/superblocks: created
@@ -1453,17 +1505,42 @@ class SuperblockCache:
             float(self._pinned_bytes_locked())
         )
 
+    @staticmethod
+    def _charge(value, nbytes: int) -> dict:
+        """Bytes an entry pins on each device it is placed on: the even
+        band split of a sharded block, else all of it on the default
+        device."""
+        mesh = getattr(getattr(value, "block", None), "placement", None)
+        return mesh_device_bytes(mesh, nbytes) or {default_device_key(): nbytes}
+
+    def device_budget(self, device: str | None = None) -> int:
+        """Bytes this cache may pin on ``device`` (default: where
+        un-sharded entries land)."""
+        return device_cache_budget(SUPERBLOCK_DEVICE_SHARE, self.max_bytes,
+                                   device)
+
+    def _fits(self, used: int, used_dev: dict, nbytes: int, charge: dict) -> bool:
+        return used + nbytes <= self.max_bytes and all(
+            used_dev.get(d, 0) + b <= self.device_budget(d)
+            for d, b in charge.items()
+        )
+
     def put(self, key, versions: tuple, value, nbytes: int) -> None:
-        if nbytes > self.max_bytes:
+        charge = self._charge(value, nbytes)
+        if not self._fits(0, {}, nbytes, charge):
             return  # never pin more device memory than the whole budget
         with self._lock:
             replaced = self._d.pop(key, None)
             if replaced is not None:
                 self.ledger.free(replaced[2], reason="replace")
             used = sum(e[2] for e in self._d.values())
+            used_dev: dict = {}
+            for e in self._d.values():
+                for d, b in self._charge(e[1], e[2]).items():
+                    used_dev[d] = used_dev.get(d, 0) + b
             while self._d and (
                 len(self._d) >= self.max_entries
-                or used + nbytes > self.max_bytes
+                or not self._fits(used, used_dev, nbytes, charge)
             ):
                 # evict in LRU order but never a pinned entry; when only
                 # pinned entries remain, tolerate running over budget (the
@@ -1475,6 +1552,8 @@ class SuperblockCache:
                 ev = self._d.pop(ek)
                 self._meta.pop(ek, None)
                 used -= ev[2]
+                for d, b in self._charge(ev[1], ev[2]).items():
+                    used_dev[d] -= b
                 self.ledger.free(ev[2], reason="evict")
             self._d[key] = (versions, value, nbytes)
             self.ledger.alloc(nbytes)
@@ -1525,6 +1604,12 @@ class SuperblockCache:
                 entry["is_hist"] = bool(getattr(value, "is_hist", False))
                 entry["stage_mode"] = getattr(value, "stage_mode", None)
                 entry["grid"] = grid_class(block)
+                # where the value plane REALLY sits, off the array's own
+                # shards (metadata, no transfer) — device_bytes above is
+                # put()'s even split of the whole entry
+                shards = getattr(block.vals, "addressable_shards", None)
+                entry["vals_resident"] = None if shards is None else {
+                    str(sh.device): int(sh.data.nbytes) for sh in shards}
             out.append(entry)
         return out
 

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..jax_compat import shard_map
 from ..ops import aggregations as AGG
 from ..ops import kernels as K
 from ..ops.staging import StagedBlock, pad_series
@@ -97,12 +96,12 @@ def distributed_agg_range_mxu(
     shard = P("shard")
     row = P("shard", None)
     rep = P()
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(row, row, shard, shard, shard),
         out_specs=rep,
-        check=False,
+        check_vma=False,
     )(vals, raw, lens, baseline, gids)
 
 
@@ -147,12 +146,12 @@ def distributed_agg_range_jitter(
 
     shard = P("shard")
     row = P("shard", None)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(row, row, row, shard, shard),
         out_specs=P(),
-        check=False,
+        check_vma=False,
     )(vals, raw, dev, lens, gids)
 
 
@@ -197,12 +196,12 @@ def distributed_agg_range_masked(
 
     shard = P("shard")
     row = P("shard", None)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(row,) * 12 + (shard, shard),
         out_specs=P(),
-        check=False,
+        check_vma=False,
     )(vals, dev, raw, valid, cc, ffv, ffd, bfv, bfd, ff2v, ff2d, bfraw,
       lens, gids)
 
@@ -245,12 +244,12 @@ def distributed_agg_range(
 
     shard = P("shard")
     row = P("shard", None)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(row, row, shard, shard, row, shard),
         out_specs=P(),
-        check=False,
+        check_vma=False,
     )(ts, vals, lens, baseline, raw, gids)
 
 

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..jax_compat import shard_map
 from ..ops import kernels as K
 from ..ops.staging import StagedBlock
 from .timeshard import TS_NEG, split_time_axis
@@ -94,7 +93,7 @@ def mesh2d_agg_range(
             raise ValueError(f"2d mesh aggregation supports sum/count/avg, got {op}")
         return out[None, None]  # [1, 1, G, j_dev]
 
-    out = shard_map(
+    out = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -104,7 +103,7 @@ def mesh2d_agg_range(
             P("shard"), P("shard"),
         ),
         out_specs=P("shard", "time", None, None),
-        check=False,
+        check_vma=False,
     )(ts, vals, raw, lens, tail_ts, tail_vals, tail_raw, gids, baseline)
     # [Ds, Dt, G, j_dev]: shard axis already reduced (psum) — take slice 0,
     # concat time along steps

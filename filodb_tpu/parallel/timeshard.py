@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..jax_compat import shard_map
 from ..ops import kernels as K
 from ..ops.staging import TS_PAD, StagedBlock
 
@@ -150,13 +149,13 @@ def timesharded_range(
         )
         return grid[None]
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis),
                   P(axis), P(axis), P(axis), P()),
         out_specs=P(axis, None, None),
-        check=False,
+        check_vma=False,
     )(ts, vals, raw, lens, tail_ts, tail_vals, tail_raw, baseline)
 
 

@@ -339,7 +339,14 @@ def staged_block_for(ctx: "QueryContext", shard, ids, cache_key, col_name: str,
         if drop_reason is None:
             from ...memstore.shard import StageEntry
 
-            budget = getattr(shard.config, "stage_cache_bytes", 2 << 30)
+            # every shard of the memstore (all datasets) stages onto one
+            # device: each gets its slice of the device's stage-cache
+            # share, capped by the knob
+            budget = ST.device_cache_budget(
+                ST.STAGE_CACHE_DEVICE_SHARE
+                / max(ctx.memstore.local_shard_count(), 1),
+                shard.config.stage_cache_bytes,
+            )
             # a racing same-key stage (two queries sharing a leaf selector
             # both missed) may have inserted already: credit its entry or
             # the overwrite below would leak its ledger balance forever

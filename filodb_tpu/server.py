@@ -24,7 +24,7 @@ import logging
 import threading
 import time
 
-from .api.http import serve_background
+from .api.http import device_facts, serve_background
 from .coordinator.planner import QueryEngine
 from .core.schemas import Dataset
 from .memstore.memstore import TimeSeriesMemStore
@@ -424,20 +424,6 @@ class FiloServer:
                 )
             self.alerting = AlertingEngine(self.system_standing, acfg,
                                            notifier=notifier)
-        watch_log = tcfg.get("tpu_watch_log", "auto")
-        if watch_log:
-            import os as _os
-
-            if watch_log == "auto":
-                watch_log = _os.path.join(
-                    _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
-                    "TPU_WATCH_LOG.txt",
-                )
-                watch_log = watch_log if _os.path.exists(watch_log) else None
-            if watch_log:
-                from .telemetry import register_tpu_watch_collector
-
-                register_tpu_watch_collector(str(watch_log))
         self._stop = threading.Event()
         self._threads: list[threading.Thread] = []
         self._http = None
@@ -592,6 +578,9 @@ class FiloServer:
             tp.start()
             self._threads.append(tp)
         log.info("filodb-tpu serving on :%d (%d shards)", actual_port, self.n_shards)
+        log.info("kernels run on platform=%(platform)s "
+                 "device_kind=%(device_kind)s device_count=%(device_count)d",
+                 device_facts())
         return actual_port
 
     def stop(self):
@@ -671,9 +660,6 @@ class FiloServer:
 def main(argv=None):
     import argparse
 
-    from .config import apply_platform_env
-
-    apply_platform_env()
     p = argparse.ArgumentParser("filodb-tpu-server")
     p.add_argument("--config", help="JSON config file")
     p.add_argument("--port", type=int, default=None)

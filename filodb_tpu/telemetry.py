@@ -24,17 +24,12 @@ so every scrape ingests them as real series and
 through the fused path — which is also what the SLO burn-rate recording
 rules (obs/slo.py) evaluate against.
 
-Also here: the scrape-time collector that surfaces ``tools/tpu_watch.py``
-device-probe results as ``filodb_tpu_*`` gauges (the watchdog's log is the
-source of truth; parsing it at scrape time means the server needs no side
-channel to the watchdog process), and the query-log ring-depth collector.
+Also here: the query-log ring-depth collector.
 """
 
 from __future__ import annotations
 
 import logging
-import os
-import re
 import threading
 import time
 
@@ -117,76 +112,3 @@ def register_querylog_collector(registry=REGISTRY) -> None:
         registry.gauge("filodb_querylog_entries").set(float(len(QUERY_LOG)))
 
     registry.register_collector("querylog", collect)
-
-
-# -- tpu-watch probe gauges --------------------------------------------------
-
-_PROBE_RE = re.compile(
-    r"^(?P<ts>\S+) probe (?P<outcome>OK|FAIL|TIMEOUT)", re.M
-)
-_ATTEST_RE = re.compile(r"^\S+ ATTESTED ", re.M)
-_TS_FMT = "%Y-%m-%dT%H:%M:%S%z"
-
-
-def parse_tpu_watch_log(text: str) -> dict:
-    """Aggregate a TPU_WATCH_LOG.txt payload into probe stats: total/ok
-    counts, attested measurements, last outcome and its timestamp."""
-    probes = ok = 0
-    last_outcome = None
-    last_ts = None
-    for m in _PROBE_RE.finditer(text):
-        probes += 1
-        healthy = m.group("outcome") == "OK"
-        ok += healthy
-        last_outcome = healthy
-        try:
-            last_ts = time.mktime(
-                time.strptime(m.group("ts")[:19], "%Y-%m-%dT%H:%M:%S")
-            )
-        except ValueError:
-            last_ts = None
-    return {
-        "probes": probes,
-        "ok": ok,
-        "attested": len(_ATTEST_RE.findall(text)),
-        "last_healthy": last_outcome,
-        "last_ts": last_ts,
-    }
-
-
-def register_tpu_watch_collector(log_path: str,
-                                 registry=REGISTRY) -> None:
-    """Expose the tpu-watch watchdog's device-probe results as
-    ``filodb_tpu_*`` gauges, refreshed at scrape time from its log file
-    (keyed per path — re-registration replaces). Gauges:
-
-    - ``filodb_tpu_probe_healthy`` — last probe outcome (1/0; -1 = no
-      probes seen yet or log absent)
-    - ``filodb_tpu_probe_age_seconds`` — seconds since the last probe
-    - ``filodb_tpu_probes`` / ``filodb_tpu_probes_ok`` — cumulative counts
-      from the log
-    - ``filodb_tpu_bench_attested`` — attested benchmark measurements"""
-
-    def collect():
-        stats = None
-        try:
-            if os.path.exists(log_path):
-                with open(log_path) as f:
-                    stats = parse_tpu_watch_log(f.read())
-        except OSError:
-            stats = None
-        if not stats or not stats["probes"]:
-            registry.gauge("filodb_tpu_probe_healthy").set(-1.0)
-            return
-        registry.gauge("filodb_tpu_probe_healthy").set(
-            1.0 if stats["last_healthy"] else 0.0
-        )
-        if stats["last_ts"] is not None:
-            registry.gauge("filodb_tpu_probe_age_seconds").set(
-                max(0.0, time.time() - stats["last_ts"])
-            )
-        registry.gauge("filodb_tpu_probes").set(float(stats["probes"]))
-        registry.gauge("filodb_tpu_probes_ok").set(float(stats["ok"]))
-        registry.gauge("filodb_tpu_bench_attested").set(float(stats["attested"]))
-
-    registry.register_collector(f"tpu_watch:{log_path}", collect)

@@ -138,7 +138,7 @@ def test_kill_and_resume_two_processes(tmp_path):
     want = _oracle_totals(store, ms, 4)
     env = dict(
         os.environ, FILODB_DS_CRASH_AFTER_CLAIM="2",
-        JAX_PLATFORMS="cpu", FILODB_PLATFORM="cpu",
+        JAX_PLATFORMS="cpu",
     )
     code = (
         "import jax; jax.config.update('jax_platforms','cpu')\n"

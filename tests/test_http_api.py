@@ -102,6 +102,12 @@ def test_bad_query_is_400(api):
 def test_health(api):
     out = get(f"{api}/admin/health")
     assert out["status"] == "healthy"
+    # the server states where its kernels run, as jax reports it
+    import jax
+
+    assert out["platform"] == jax.devices()[0].platform == "cpu"
+    assert out["device_kind"] == jax.devices()[0].device_kind
+    assert out["device_count"] == len(jax.devices())
 
 
 def test_ingest_endpoint(api):

@@ -460,7 +460,7 @@ def test_crash_mid_commit_then_redo_recovers(tmp_path):
     store, ms = _seed_raw_store(tmp_path)
     want = _oracle_totals(store, ms, 2)
     env = dict(os.environ, FILODB_DS_CRASH_MID_COMMIT="1",
-               JAX_PLATFORMS="cpu", FILODB_PLATFORM="cpu")
+               JAX_PLATFORMS="cpu")
     code = (
         "import jax; jax.config.update('jax_platforms','cpu')\n"
         "from filodb_tpu.downsample.distributed import run_worker\n"

@@ -300,46 +300,98 @@ class TestStandingQuerylog:
 # compile-cache provenance reconciliation (satellite: tiered counters)
 
 
-class TestCompileCacheProvenance:
-    def test_tiers_reconcile_with_registry_provenance(self):
+@pytest.fixture
+def cache_env(monkeypatch):
+    """Point the ONE placement rule at a scratch dir through the
+    environment; afterwards put the process back on the directory the
+    rule gives without the patch (what every FiloServer test uses)."""
+    from filodb_tpu.ops import compile_cache as CC
+
+    def place(path):
+        if path is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(path))
+
+    yield place
+    monkeypatch.undo()
+    CC.enable_compile_cache()
+
+
+class TestCompileCachePlacement:
+    def test_environment_variable_wins_and_nothing_overrides_it(
+            self, cache_env, tmp_path):
+        import jax
+
         from filodb_tpu.ops import compile_cache as CC
 
-        cache_dir = tempfile.mkdtemp(prefix="filodb-cc-")
-        prev_dir = CC._enabled_dir
-        assert CC.enable_compile_cache(cache_dir) == cache_dir
-        try:
-            h_ip0 = _counter_value("filodb_compile_cache_hits",
-                                   tier="in_process")
-            m_ip0 = _counter_value("filodb_compile_cache_misses",
-                                   tier="in_process")
-            m_p0 = _counter_value("filodb_compile_cache_misses",
-                                  tier="persistent")
-            vals = np.ones((3, 5), np.float32)
-            gids = np.zeros(3, np.int32)
-            AGG.segment_aggregate("min", vals, gids, 677)  # fresh trace
-            AGG.segment_aggregate("min", vals, gids, 677)  # warm
-            assert _counter_value("filodb_compile_cache_misses",
-                                  tier="in_process") == m_ip0 + 1
-            assert _counter_value("filodb_compile_cache_hits",
-                                  tier="in_process") >= h_ip0 + 1
-            # the fresh trace wrote a persistent entry (thresholds are
-            # forced to zero) -> a persistent-tier miss, and the registry's
-            # record carries the same classification + the entry bytes
-            assert _counter_value("filodb_compile_cache_misses",
-                                  tier="persistent") == m_p0 + 1
-            key = executable_key({
-                "family": "segment_min", "variant": "general",
-                "epilogue": "agg:min", "shapes": "S3xJ5xG677",
-            })
-            rec = _record_for(KERNELS.snapshot(), key)
-            assert rec["cache"]["fresh"] == 1
-            assert rec["cache"]["in_process"] == 1
-            assert rec["executable_bytes"] and rec["executable_bytes"] > 0
-        finally:
-            # restore the previous cache dir (enable is idempotent per dir)
-            CC._enabled_dir = None
-            if prev_dir:
-                CC.enable_compile_cache(prev_dir)
+        cache_env(tmp_path / "from-env")
+        # neither a data dir nor the knob moves the cache off the variable
+        for cfg in ({}, {"store_root": str(tmp_path / "data")},
+                    {"compile_cache_dir": "auto"}):
+            assert CC.enable_from_config(cfg) == str(tmp_path / "from-env")
+            assert (jax.config.jax_compilation_cache_dir
+                    == str(tmp_path / "from-env"))
+        assert not (tmp_path / "data").exists()
+
+    def test_unset_means_the_checkout_dir_never_a_derived_one(
+            self, cache_env, tmp_path, monkeypatch):
+        from filodb_tpu.ops import compile_cache as CC
+
+        cache_env(None)
+        want = os.path.join(REPO, ".jax-compile-cache")
+        # not derived from store_root, home, pid or time
+        monkeypatch.setenv("HOME", str(tmp_path / "home"))
+        monkeypatch.setenv("XDG_CACHE_HOME", str(tmp_path / "xdg"))
+        a = CC.enable_from_config({"store_root": str(tmp_path / "data")})
+        b = CC.enable_from_config({})
+        assert a == b == CC.cache_dir() == want
+        assert not list(tmp_path.iterdir())
+
+    def test_knob_is_on_or_off_and_refuses_a_path(self, cache_env, tmp_path):
+        from filodb_tpu.ops import compile_cache as CC
+
+        cache_env(tmp_path / "c")
+        assert CC.enable_from_config({"compile_cache_dir": None}) is None
+        with pytest.raises(ValueError, match="JAX_COMPILATION_CACHE_DIR"):
+            CC.enable_from_config({"compile_cache_dir": str(tmp_path / "p")})
+        assert not (tmp_path / "p").exists()
+
+
+class TestCompileCacheProvenance:
+    def test_tiers_reconcile_with_registry_provenance(self, cache_env,
+                                                      tmp_path):
+        from filodb_tpu.ops import compile_cache as CC
+
+        cache_env(tmp_path)
+        assert CC.enable_compile_cache() == str(tmp_path)
+        h_ip0 = _counter_value("filodb_compile_cache_hits",
+                               tier="in_process")
+        m_ip0 = _counter_value("filodb_compile_cache_misses",
+                               tier="in_process")
+        m_p0 = _counter_value("filodb_compile_cache_misses",
+                              tier="persistent")
+        vals = np.ones((3, 5), np.float32)
+        gids = np.zeros(3, np.int32)
+        AGG.segment_aggregate("min", vals, gids, 677)  # fresh trace
+        AGG.segment_aggregate("min", vals, gids, 677)  # warm
+        assert _counter_value("filodb_compile_cache_misses",
+                              tier="in_process") == m_ip0 + 1
+        assert _counter_value("filodb_compile_cache_hits",
+                              tier="in_process") >= h_ip0 + 1
+        # the fresh trace wrote a persistent entry (thresholds are
+        # forced to zero) -> a persistent-tier miss, and the registry's
+        # record carries the same classification + the entry bytes
+        assert _counter_value("filodb_compile_cache_misses",
+                              tier="persistent") == m_p0 + 1
+        key = executable_key({
+            "family": "segment_min", "variant": "general",
+            "epilogue": "agg:min", "shapes": "S3xJ5xG677",
+        })
+        rec = _record_for(KERNELS.snapshot(), key)
+        assert rec["cache"]["fresh"] == 1
+        assert rec["cache"]["in_process"] == 1
+        assert rec["executable_bytes"] and rec["executable_bytes"] > 0
 
     def test_dir_walk_memoized_on_mtime(self):
         from filodb_tpu.ops.compile_cache import _CompileCacheProbe
@@ -378,9 +430,8 @@ class TestAttestation:
         proc = subprocess.run(
             [sys.executable, os.path.join(REPO, "tools", "attest.py"),
              "--floor-file", str(floor_file), "--no-multichip",
-             "--out", str(out)],
+             "--backend", "cpu", "--out", str(out)],
             capture_output=True, text=True, cwd=REPO, timeout=420,
-            env=dict(os.environ, JAX_PLATFORMS="cpu"),
         )
         assert proc.returncode == 0, proc.stdout + proc.stderr
         doc = json.loads(out.read_text())

@@ -78,3 +78,24 @@ def test_nan_sample_confined_to_its_window():
     # windows: step k covers (t_k-1s, t_k] = exactly sample k+1
     expect = [2.0, np.nan, 4.0, 5.0]
     np.testing.assert_allclose(out[:4], expect, equal_nan=True)
+
+
+def test_selection_stops_at_the_widest_block_the_chip_holds(monkeypatch):
+    """Past MAX_T (measured on a v5e: VMEM runs out at T=6144) the kernel
+    is not selected — from the block's shape, whatever the switch says —
+    so a wide irregular selector takes the general kernel instead of a
+    compile error."""
+    from filodb_tpu.ops import aggregations as AGG
+
+    monkeypatch.setenv("FILODB_PALLAS", "1")
+    assert PK.pallas_enabled(PK.MAX_T)
+    assert not PK.pallas_enabled(PK.MAX_T + 128)
+
+    class Irregular:  # what _pallas_variant reads of a staged block
+        regular_ts = nominal_ts = mgrid = None
+
+        def __init__(self, t):
+            self.ts = np.zeros((8, t), np.int32)
+
+    assert AGG._pallas_variant(Irregular(PK.MAX_T), "rate", None)
+    assert not AGG._pallas_variant(Irregular(PK.MAX_T + 128), "rate", None)

@@ -13,7 +13,7 @@ accounting & self-monitoring"):
 - slow-query ring under concurrent record/configure, ordering, threshold
   edge; ?trace=true carries the new resource stats;
 - Registry.remove + tenant series aging; HELP/TYPE + OpenMetrics +
-  exemplars; tpu-watch probe gauges.
+  exemplars.
 """
 
 from __future__ import annotations
@@ -80,6 +80,100 @@ def _assert_zero_drift():
     assert not bad, f"ledger drift: {bad}"
     for kind, slot in report["kinds"].items():
         assert slot["drift"] == 0, (kind, slot)
+
+
+# ---------------------------------------------------------------------------
+# device-derived cache budgets (a 16 GB chip must bound what the caches pin)
+
+
+class TestDeviceCacheBudgets:
+    def test_ceilings_alone_where_the_device_reports_no_limit(self):
+        from filodb_tpu.ops import staging as ST
+
+        assert ST._device_bytes_limits() == {}  # the CPU backend
+        assert ST.SuperblockCache().device_budget() == 8 << 30
+        assert ST.device_cache_budget(0.2, 2 << 30) == 2 << 30
+
+    def test_budgets_follow_the_device_and_never_exceed_the_knob(
+            self, monkeypatch):
+        from filodb_tpu.ops import staging as ST
+
+        dev0 = ST.default_device_key()
+        monkeypatch.setattr(ST, "_device_bytes_limits",
+                            lambda: {dev0: 16 << 30})
+        assert ST.SuperblockCache().device_budget() == int(
+            (16 << 30) * ST.SUPERBLOCK_DEVICE_SHARE)
+        assert ST.SuperblockCache(max_bytes=1 << 20).device_budget() == 1 << 20
+        # both caches together leave room for what they do not count
+        assert ST.SUPERBLOCK_DEVICE_SHARE + ST.STAGE_CACHE_DEVICE_SHARE <= 0.6
+
+    def test_superblocks_are_charged_to_the_devices_they_sit_on(
+            self, monkeypatch):
+        """A sharded entry costs each device its band, not the whole block:
+        beside un-sharded entries that nearly fill the default device it
+        evicts nothing, while one more un-sharded entry does."""
+        import jax
+        from types import SimpleNamespace
+
+        from filodb_tpu.ops import staging as ST
+        from filodb_tpu.parallel.mesh import make_mesh
+
+        devs = jax.devices()
+        assert len(devs) >= 4  # tests/conftest.py's virtual CPU mesh
+        mesh = make_mesh(devs[:4])
+        unit = 1 << 20
+        # every device's budget for this cache: 10 units
+        monkeypatch.setattr(ST, "SUPERBLOCK_DEVICE_SHARE", 0.25)
+        monkeypatch.setattr(ST, "_device_bytes_limits",
+                            lambda: {str(d): 40 * unit for d in devs})
+
+        def entry(placement):
+            return SimpleNamespace(block=SimpleNamespace(placement=placement))
+
+        c = ST.SuperblockCache(max_entries=64)
+        assert c.device_budget(str(devs[3])) == 10 * unit
+        for i in range(4):
+            c.put(("single", i), (0,), entry(None), 2 * unit)
+        # 8 of 10 units sit on the default device; a sharded 8-unit block
+        # adds 2 there and 2 on each of the others
+        c.put("sharded", (0,), entry(mesh), 8 * unit)
+        assert len(c) == 5
+        # the default device is full now: the next un-sharded entry evicts
+        # the oldest, on that device's budget alone
+        c.put(("single", 4), (0,), entry(None), 2 * unit)
+        assert c.peek(("single", 0)) is None and len(c) == 5
+        # nothing that exceeds a device's whole budget is ever pinned
+        c.put("monster", (0,), entry(None), 11 * unit)
+        assert c.peek("monster") is None
+        c.put("wide", (0,), entry(mesh), 11 * unit)  # 2.75 a device: fits
+        assert c.peek("wide") is not None
+
+    def test_stage_cache_evicts_to_its_slice_of_the_device(self, monkeypatch):
+        """With the knob at its 2 GiB default, a small device still bounds
+        each shard's staged bytes: distinct ranges evict earlier entries
+        instead of piling up, and the ledger stays exact. The device's
+        share is divided among the shards of EVERY dataset it serves."""
+        from filodb_tpu.ops import staging as ST
+
+        ms = _make_store()
+        ms.setup(Dataset("other"), [0, 1, 2, 3])
+        eng = QueryEngine(ms, "ds")
+        eng.query_range("rate(http_requests_total[5m])", START, START + 600, STEP)
+        one = max(sh.ledger.bytes for sh in ms.shards("ds"))
+        assert one > 0
+        n = ms.local_shard_count()
+        assert n == 8
+        # a device whose stage share is ~1.5 entries per shard
+        dev0 = ST.default_device_key()
+        monkeypatch.setattr(
+            ST, "_device_bytes_limits",
+            lambda: {dev0: int(1.5 * one * n / ST.STAGE_CACHE_DEVICE_SHARE)})
+        for i in range(1, 6):
+            eng.query_range("rate(http_requests_total[5m])", START + i,
+                            START + 600, STEP)
+        for sh in ms.shards("ds"):
+            assert len(sh.stage_cache) <= 1 and sh.ledger.bytes <= 1.5 * one
+        _assert_zero_drift()
 
 
 # ---------------------------------------------------------------------------
@@ -611,33 +705,3 @@ class TestRegistrySeries:
             and "trace_id" in l
         )
         assert res.trace.trace_id[:4] in line or "trace_id=" in line
-
-
-# ---------------------------------------------------------------------------
-# tpu-watch probe gauges
-
-
-class TestTpuWatchCollector:
-    def test_log_parses_into_gauges(self, tmp_path):
-        from filodb_tpu.telemetry import register_tpu_watch_collector
-
-        log = tmp_path / "TPU_WATCH_LOG.txt"
-        stamp = time.strftime("%Y-%m-%dT%H:%M:%S%z")
-        log.write_text(
-            f"{stamp} watchdog start: probe every 120s\n"
-            f"{stamp} probe TIMEOUT after 30s (wedged plugin)\n"
-            f"{stamp} probe FAIL rc=1: no device\n"
-            f"{stamp} probe OK: TPU_OK tpu v5e\n"
-            f"{stamp} ATTESTED quick: {{}}\n"
-        )
-        r = Registry()
-        register_tpu_watch_collector(str(log), registry=r)
-        text = r.expose()
-        assert "filodb_tpu_probes 3" in text
-        assert "filodb_tpu_probes_ok 1" in text
-        assert "filodb_tpu_probe_healthy 1" in text
-        assert "filodb_tpu_bench_attested 1" in text
-        # empty/missing log: healthy gauge reads -1, never crashes
-        r2 = Registry()
-        register_tpu_watch_collector(str(tmp_path / "missing.txt"), registry=r2)
-        assert "filodb_tpu_probe_healthy -1" in r2.expose()

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""CPU bench smoke gate (make bench-smoke): small bench.py workers on the
+"""CPU bench smoke gate (make bench-smoke): small bench.py runs on the
 CPU backend must not regress p50 by more than 25% against the checked-in
 floors (benchmarks/bench_smoke_floor.json), and must keep match=True
 against the numpy oracles. One floor entry per workload — the north-star
@@ -33,10 +33,11 @@ def run_entry(entry: dict, extra_env: dict | None = None,
     the smoke gate (tools/attest.py embeds floor verdicts + measurements
     into the attestation artifact) don't re-run the workload.
 
-    ``cpu=False`` (the attestation harness after a healthy accelerator
-    probe) leaves the platform to jax's auto-detection so the worker runs
-    — and honestly labels — the real backend; the smoke gate itself always
-    pins cpu (its floors are CPU numbers)."""
+    ``cpu=False`` (the attestation harness off the smoke gate) runs the
+    child on the device jax finds, and the child's ``"backend"`` field
+    names it; the smoke gate itself always pins cpu (its floors are CPU
+    numbers). This parent never touches jax, so the child can hold the
+    device."""
     env = dict(
         os.environ,
         FILODB_BENCH_SERIES=str(entry["series"]),
@@ -47,15 +48,15 @@ def run_entry(entry: dict, extra_env: dict | None = None,
     if cpu:
         env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--worker"]
+        [sys.executable, os.path.join(REPO, "bench.py")]
         + (["--cpu"] if cpu else []),
         env=env, capture_output=True, text=True, cwd=REPO, timeout=600,
     )
     sys.stderr.write(proc.stderr[-2000:])
     lines = [l for l in proc.stdout.splitlines() if l.startswith("{")]
     name = entry["metric"]
-    if proc.returncode != 0 or not lines:
-        return False, f"{name}: worker failed rc={proc.returncode}", None
+    if not lines:  # a non-matching run exits 1 WITH its line; judged below
+        return False, f"{name}: bench.py failed rc={proc.returncode}", None
     got = json.loads(lines[-1])
     if got.get("metric") != name:
         return False, (
