@@ -32,10 +32,12 @@ import time
 import urllib.parse
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
+import jax
 import numpy as np
 
 from ..coordinator.planner import QueryEngine
 from ..core.filters import ColumnFilter
+from ..metrics import REGISTRY, span
 from ..query.exec.transformers import QueryError
 from ..query.promql import PromQLError, Parser as PromParser
 from ..query.proto_plan import RemoteExecError
@@ -167,12 +169,39 @@ class PromApiHandler(BaseHTTPRequestHandler):
         filodb_response_bytes_total{format}, and (streaming only)
         filodb_render_stream_stalls_total — encoder waits on a D2H block
         the double-buffer failed to hide."""
-        from ..metrics import REGISTRY
-
         REGISTRY.histogram("filodb_render_seconds", format=fmt).observe(render_s)
         REGISTRY.counter("filodb_response_bytes", format=fmt).inc(nbytes)
         if stalls:
             REGISTRY.counter("filodb_render_stream_stalls").inc(stalls)
+
+    @staticmethod
+    def _observe_write(render_span) -> None:
+        """``filodb_render_write_seconds``: the socket write(s) inside one
+        query's ``render`` span — what is left of ``render`` is encoding."""
+        for c in render_span.children:
+            if c.name == "render:write":
+                REGISTRY.histogram("filodb_render_write_seconds").observe(
+                    c.seconds)
+
+    @staticmethod
+    def _pull_grids(res) -> float:
+        """Pull every result grid to the host HERE, timed, instead of
+        implicitly inside the encoder: the ``transfer`` phase, returned in
+        seconds. First the wait for the device to finish the program
+        (``transfer:ready``, ``filodb_transfer_ready_seconds``), then the
+        copy back. Not an added sync: the conversion blocked on the same
+        event one call later."""
+        with span("transfer") as sp:
+            with span("transfer:ready") as ready:
+                jax.block_until_ready([(g.values, g.hist) for g in res.grids])
+            with span("transfer:copy"):
+                for g in res.grids:
+                    g.values = np.asarray(g.values)
+                    if g.hist is not None:
+                        g.hist = np.asarray(g.hist)
+        REGISTRY.histogram("filodb_transfer_ready_seconds").observe(
+            ready.seconds)
+        return sp.seconds
 
     def _peer_accepts_arrow(self) -> bool:
         """Version negotiation for the node-to-node columnar hop: only a
@@ -198,8 +227,6 @@ class PromApiHandler(BaseHTTPRequestHandler):
         ``shed`` (429 admission sheds) is deliberate load management and
         is excluded from BOTH sides of the availability ratio; ``5xx`` is
         the error budget's numerator."""
-        from ..metrics import REGISTRY
-
         klass = ("shed" if code == 429 else "5xx" if code >= 500
                  else "4xx" if code >= 400 else "2xx")
         REGISTRY.counter("filodb_http_responses", code=str(code),
@@ -218,22 +245,27 @@ class PromApiHandler(BaseHTTPRequestHandler):
         here so buffered and streamed bodies are byte-identical."""
         raw_len = len(body)
         self._count_response(code)
-        self.send_response(code)
-        self.send_header("Content-Type", content_type)
-        for k, v in (headers or {}).items():
-            self.send_header(k, v)
         # transparent gzip for big results (remote execs request it)
-        if (
+        gzipped = (
             len(body) >= self.GZIP_MIN_BYTES
             and "gzip" in (self.headers.get("Accept-Encoding") or "")
-        ):
+        )
+        if gzipped:
             import gzip
 
             body = gzip.compress(body, compresslevel=1)
-            self.send_header("Content-Encoding", "gzip")
-        self.send_header("Content-Length", str(len(body)))
-        self.end_headers()
-        self.wfile.write(body)
+        # status line, headers and body onto the socket: the part of a
+        # query's ``render`` that is not encoding (_observe_write)
+        with span("render:write"):
+            self.send_response(code)
+            self.send_header("Content-Type", content_type)
+            for k, v in (headers or {}).items():
+                self.send_header(k, v)
+            if gzipped:
+                self.send_header("Content-Encoding", "gzip")
+            self.send_header("Content-Length", str(len(body)))
+            self.end_headers()
+            self.wfile.write(body)
         return raw_len
 
     def _send_chunked(self, code: int, chunks):
@@ -264,8 +296,6 @@ class PromApiHandler(BaseHTTPRequestHandler):
         except (BrokenPipeError, ConnectionResetError):
             raise  # client is gone; nothing to mark
         except Exception as e:  # noqa: BLE001 — producer died mid-stream
-            from ..metrics import REGISTRY
-
             marker = (b'\n{"status":"error","errorType":"stream_aborted",'
                       + b'"error":' + json.dumps(f"{type(e).__name__}: {e}").encode()
                       + b"}\n")
@@ -333,8 +363,22 @@ class PromApiHandler(BaseHTTPRequestHandler):
     def do_POST(self):
         self._route()
 
+    # the routes whose whole handler wall is clocked, entry to return:
+    # ``http:<route>`` spans, filodb_http_request_seconds{route}
+    TIMED_ROUTES = {"/api/v1/query_range": "query_range",
+                    "/api/v1/query": "query"}
+
     def _route(self):
         path = urllib.parse.urlparse(self.path).path
+        route = self.TIMED_ROUTES.get(path)
+        if route is None:
+            return self._dispatch(path)
+        with span(f"http:{route}") as sp:
+            self._dispatch(path)
+        REGISTRY.histogram(
+            "filodb_http_request_seconds", route=route).observe(sp.seconds)
+
+    def _dispatch(self, path: str):
         if self.auth_token and path != "/admin/health":
             import hmac
 
@@ -571,12 +615,12 @@ class PromApiHandler(BaseHTTPRequestHandler):
             }
             if trace is not None:
                 data["trace"] = trace
-            t_r = time.perf_counter()
-            nbytes = self._send(200, J.success(data, warnings=warnings,
-                                               partial=res.partial))
+            with span("render") as sp_r:
+                nbytes = self._send(200, J.success(data, warnings=warnings,
+                                                   partial=res.partial))
+            self._observe_write(sp_r)
             if record is not None:
-                QUERY_LOG.finish_serving(record, 0.0,
-                                         time.perf_counter() - t_r,
+                QUERY_LOG.finish_serving(record, 0.0, sp_r.seconds,
                                          body_bytes=nbytes, code=200,
                                          render_format=render_format)
             return
@@ -602,17 +646,13 @@ class PromApiHandler(BaseHTTPRequestHandler):
         if self._peer_accepts_arrow():
             from . import arrow_edge as AE
 
-            t_tr = time.perf_counter()
-            for g in res.grids:
-                g.values = np.asarray(g.values)
-                if g.hist is not None:
-                    g.hist = np.asarray(g.hist)
-            transfer_s = time.perf_counter() - t_tr
-            t_r = time.perf_counter()
-            body = AE.result_to_ipc(res, trace=trace)
-            nbytes = self._send_body(200, body,
-                                     content_type=AE.ARROW_CONTENT_TYPE)
-            render_s = time.perf_counter() - t_r
+            transfer_s = self._pull_grids(res)
+            with span("render") as sp_r:
+                body = AE.result_to_ipc(res, trace=trace)
+                nbytes = self._send_body(200, body,
+                                         content_type=AE.ARROW_CONTENT_TYPE)
+            render_s = sp_r.seconds
+            self._observe_write(sp_r)
             self._observe_render("arrow", render_s, nbytes)
             if record is not None:
                 QUERY_LOG.finish_serving(record, transfer_s, render_s,
@@ -636,16 +676,16 @@ class PromApiHandler(BaseHTTPRequestHandler):
             # on unfetched blocks (those waits ARE the transfer phase
             # leaking through the overlap — counted as stream stalls).
             phases: dict = {}
-            t_r = time.perf_counter()
-            nbytes = self._send_chunked(
-                200, J.stream_matrix(res, stats, warnings=warnings,
-                                     trace=trace, partial=res.partial,
-                                     block_rows=self.STREAM_BLOCK_ROWS or None,
-                                     phases=phases)
-            )
-            total_s = time.perf_counter() - t_r
+            with span("render") as sp_r:
+                nbytes = self._send_chunked(
+                    200, J.stream_matrix(
+                        res, stats, warnings=warnings, trace=trace,
+                        partial=res.partial,
+                        block_rows=self.STREAM_BLOCK_ROWS or None,
+                        phases=phases)
+                )
             transfer_s = phases.get("transfer", 0.0)
-            render_s = max(total_s - phases.get("stall_s", 0.0), 0.0)
+            render_s = max(sp_r.seconds - phases.get("stall_s", 0.0), 0.0)
             self._observe_render(render_format, render_s, nbytes,
                                  stalls=phases.get("stalls", 0))
             if record is not None:
@@ -653,21 +693,15 @@ class PromApiHandler(BaseHTTPRequestHandler):
                                          body_bytes=nbytes, code=200,
                                          render_format=render_format)
             return
-        # buffered path: pull every result grid to host HERE, timed,
-        # instead of implicitly inside the JSON encoder — the transfer vs
-        # render decomposition the result-plane phase plane needs. Not an
-        # added sync: rendering forced the same conversion one call later.
-        t_tr = time.perf_counter()
-        for g in res.grids:
-            g.values = np.asarray(g.values)
-            if g.hist is not None:
-                g.hist = np.asarray(g.hist)
-        transfer_s = time.perf_counter() - t_tr
-        t_r = time.perf_counter()
-        body = b"".join(J.stream_matrix(res, stats, warnings=warnings,
-                                        trace=trace, partial=res.partial))
-        nbytes = self._send_body(200, body)
-        render_s = time.perf_counter() - t_r
+        # buffered path: the transfer vs render decomposition the
+        # result-plane phase plane needs (_pull_grids)
+        transfer_s = self._pull_grids(res)
+        with span("render") as sp_r:
+            body = b"".join(J.stream_matrix(res, stats, warnings=warnings,
+                                            trace=trace, partial=res.partial))
+            nbytes = self._send_body(200, body)
+        render_s = sp_r.seconds
+        self._observe_write(sp_r)
         self._observe_render(render_format, render_s, nbytes)
         if record is not None:
             QUERY_LOG.finish_serving(record, transfer_s, render_s,
@@ -690,29 +724,24 @@ class PromApiHandler(BaseHTTPRequestHandler):
         from ..obs.querylog import QUERY_LOG
 
         record = getattr(res, "query_log", None)
-        t_tr = time.perf_counter()
-        for g in res.grids:
-            g.values = np.asarray(g.values)
-            if g.hist is not None:
-                g.hist = np.asarray(g.hist)
-        transfer_s = time.perf_counter() - t_tr
+        transfer_s = self._pull_grids(res)
         warnings = res.warnings or None
-        t_r = time.perf_counter()
-        if res.result_type == "scalar":
-            data = J.render_scalar(res, t)
-        elif res.raw is not None:
-            data = J.render_matrix(res)
-        else:
-            data = J.render_vector(res, t)
-        if trace_on and res.trace is not None:
-            from ..metrics import trace_to_dict
+        with span("render") as sp_r:
+            if res.result_type == "scalar":
+                data = J.render_scalar(res, t)
+            elif res.raw is not None:
+                data = J.render_matrix(res)
+            else:
+                data = J.render_vector(res, t)
+            if trace_on and res.trace is not None:
+                from ..metrics import trace_to_dict
 
-            data["trace"] = trace_to_dict(res.trace)
-        nbytes = self._send(200, J.success(data, warnings=warnings,
-                                           partial=res.partial))
+                data["trace"] = trace_to_dict(res.trace)
+            nbytes = self._send(200, J.success(data, warnings=warnings,
+                                               partial=res.partial))
+        self._observe_write(sp_r)
         if record is not None:
-            QUERY_LOG.finish_serving(record, transfer_s,
-                                     time.perf_counter() - t_r,
+            QUERY_LOG.finish_serving(record, transfer_s, sp_r.seconds,
                                      body_bytes=nbytes, code=200)
         return
 
@@ -767,8 +796,6 @@ class PromApiHandler(BaseHTTPRequestHandler):
         negotiation: an Accept header naming application/openmetrics-text
         gets the OpenMetrics 1.0 rendering (HELP/TYPE metadata, trace-id
         exemplars on latency buckets, # EOF terminator)."""
-        from ..metrics import REGISTRY
-
         openmetrics = "application/openmetrics-text" in (
             self.headers.get("Accept") or ""
         )
@@ -1337,8 +1364,6 @@ def register_shard_stats_collector(engine: QueryEngine) -> None:
     process-global registry must not pin a shut-down server's shards
     (staged chunks included) for the process lifetime."""
     import weakref
-
-    from ..metrics import REGISTRY
 
     ds = engine.dataset
     key = f"shard_stats:{ds}:{id(engine.memstore)}"

@@ -215,14 +215,11 @@ DEFAULTS: dict = {
     # these knobs size the table and the recompile-storm detector. A family
     # compiling more than storm_threshold times inside storm_window_s
     # counts filodb_xla_recompile_storms_total and annotates the unstable
-    # key dimension. device_timing adds a block_until_ready around each
-    # warm dispatch for exact device cost (bench/attest runs turn it on;
-    # serving keeps it off — the sync serializes the dispatch pipeline).
+    # key dimension.
     "kernel_obs": {
         "max_executables": 1024,
         "storm_threshold": 5,
         "storm_window_s": 60.0,
-        "device_timing": False,
     },
     # downsampling (reference downsample resolutions)
     "downsample": {"enabled": False, "periods_m": [5, 60]},

@@ -1339,10 +1339,20 @@ class QueryEngine:
         process's spans — and its slow-query entries — join that trace."""
         import time as _time
 
-        from ..metrics import Span, new_trace_id
+        from ..metrics import Span, current_span, new_trace_id
 
         root = Span("query", _time.perf_counter_ns())
-        root.trace_id = trace_id or new_trace_id()
+        # no upstream trace: the spans the caller has open around the engine
+        # (``http:<route>``, ``engine:query_range``) and the query's tree
+        # share one id, on the profiler trace too
+        cur = current_span()
+        root.trace_id = trace_id or (
+            cur.trace_id if cur is not None else new_trace_id())
+        if cur is not None:
+            # a caller's span sees the query's whole tree beneath it;
+            # parent_id stays the UPSTREAM linkage (None = this process is
+            # the query's origin, which is what publishes its cost record)
+            cur.children.append(root)
         root.parent_id = parent_span_id
         root.tags["promql"] = promql
         root.tags["dataset"] = self.dataset
@@ -1460,45 +1470,45 @@ class QueryEngine:
         count every CALLER (followers included), not executions — the
         coalescing factor must not deflate served QPS or the latency
         histogram."""
-        import time as _time
+        from ..metrics import REGISTRY, span
 
-        from ..metrics import REGISTRY
-
-        t0 = _time.perf_counter()
-        # resolve the tri-state BEFORE keying: "absent" and "explicitly the
-        # engine default" are the same query and must coalesce together
-        allow_partial = (
-            self.planner.params.allow_partial_results
-            if allow_partial_results is None else bool(allow_partial_results)
-        )
-        # trace linkage is NOT part of the coalescing key: followers share
-        # the leader's execution and therefore the leader's trace tree
-        if self.planner.params.coalesce_identical:
-            res = self._single_flight.run(
-                (self.dataset, promql, float(start_s), float(end_s), float(step_s),
-                 allow_partial),
-                lambda: self._query_range_uncoalesced(
-                    promql, start_s, end_s, step_s, allow_partial,
-                    trace_id=trace_id, parent_span_id=parent_span_id,
-                ),
-                timeout_s=self.planner.params.deadline_s,
+        # each caller's wall inside the engine, a follower's wait for a
+        # shared execution included: filodb_query_latency_seconds
+        with span("engine:query_range") as sp:
+            # resolve the tri-state BEFORE keying: "absent" and "explicitly
+            # the engine default" are the same query and must coalesce
+            allow_partial = (
+                self.planner.params.allow_partial_results
+                if allow_partial_results is None
+                else bool(allow_partial_results)
             )
-        else:
-            res = self._query_range_uncoalesced(promql, start_s, end_s, step_s,
-                                                allow_partial, trace_id=trace_id,
-                                                parent_span_id=parent_span_id)
-        REGISTRY.counter("filodb_queries", dataset=self.dataset).inc()
-        # trace-id exemplar: the OpenMetrics exposition attaches it to the
-        # latency bucket this query landed in, so a spiking bucket links
-        # straight to its trace / slow-query-log entry
-        tid = getattr(res.trace, "trace_id", None) if res.trace is not None \
-            else None
-        if tid is None and isinstance(res.trace, dict):
-            tid = res.trace.get("trace_id")
-        REGISTRY.histogram("filodb_query_latency_seconds", dataset=self.dataset).observe(
-            _time.perf_counter() - t0,
-            exemplar={"trace_id": tid} if tid else None,
-        )
+            # trace linkage is NOT part of the coalescing key: followers
+            # share the leader's execution and therefore its trace tree
+            if self.planner.params.coalesce_identical:
+                res = self._single_flight.run(
+                    (self.dataset, promql, float(start_s), float(end_s),
+                     float(step_s), allow_partial),
+                    lambda: self._query_range_uncoalesced(
+                        promql, start_s, end_s, step_s, allow_partial,
+                        trace_id=trace_id, parent_span_id=parent_span_id,
+                    ),
+                    timeout_s=self.planner.params.deadline_s,
+                )
+            else:
+                res = self._query_range_uncoalesced(
+                    promql, start_s, end_s, step_s, allow_partial,
+                    trace_id=trace_id, parent_span_id=parent_span_id)
+            REGISTRY.counter("filodb_queries", dataset=self.dataset).inc()
+            # trace-id exemplar: the OpenMetrics exposition attaches it to
+            # the latency bucket this query landed in, so a spiking bucket
+            # links straight to its trace / slow-query-log entry
+            tid = getattr(res.trace, "trace_id", None) \
+                if res.trace is not None else None
+            if tid is None and isinstance(res.trace, dict):
+                tid = res.trace.get("trace_id")
+        REGISTRY.histogram(
+            "filodb_query_latency_seconds", dataset=self.dataset
+        ).observe(sp.seconds, exemplar={"trace_id": tid} if tid else None)
         return res
 
     def _meter_tenant(self, plan, ctx, elapsed_s: float) -> None:
@@ -1659,7 +1669,12 @@ class QueryEngine:
         inline on the caller's thread."""
         sched = self.planner.params.scheduler
         if sched is None:
-            return exec_plan.execute(ctx)
+            from ..metrics import activate
+
+            # the plan's spans hang under the query's root, not under
+            # whatever span the caller has open around the engine
+            with activate(ctx.trace_root):
+                return exec_plan.execute(ctx)
         return sched.run(lambda: exec_plan.execute(ctx), deadline_s=ctx.deadline_s)
 
     def execute_plan(self, plan, deadline_s: float = 0.0, max_series: int = 0,

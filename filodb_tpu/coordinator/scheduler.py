@@ -21,7 +21,7 @@ import threading
 from concurrent.futures import ThreadPoolExecutor
 from concurrent.futures import TimeoutError as FutureTimeout
 
-from ..metrics import REGISTRY
+from ..metrics import REGISTRY, span
 from ..query.exec.transformers import QueryDeadlineExceeded, QueryError
 
 
@@ -63,12 +63,19 @@ class SingleFlight:
         if not leader:
             REGISTRY.counter("filodb_queries_coalesced").inc()
             try:
-                return fut.result(timeout=timeout_s)
+                with span("coalesce:wait") as sp:
+                    return fut.result(timeout=timeout_s)
             except FutureTimeout:
                 REGISTRY.counter("filodb_queries_deadline_exceeded").inc()
                 raise QueryDeadlineExceeded(
                     f"query exceeded deadline: {timeout_s:.1f}s (coalesced)"
                 ) from None
+            finally:
+                # the follower's wait for the leader's execution: a clock
+                # per caller beside the counter (the leader books nothing)
+                REGISTRY.histogram(
+                    "filodb_query_wait_seconds", kind="coalesced"
+                ).observe(sp.seconds)
         try:
             result = fn()
         except BaseException as e:

@@ -12,6 +12,10 @@ sampling profiler).
   (``activate``) so a trace survives thread-pool hops, and serializable
   (``Span.to_dict`` / ``from_dict``) so remote children return their span
   trees in-band and the origin stitches them under the dispatching span.
+  ``span`` is also the ONE place host walls are booked (``phase=``,
+  ``part=``) and the one place the program writes to a profiler trace:
+  every span holds a ``jax.profiler.TraceAnnotation`` of its own name, so
+  under a profiler session the span tree lies on the device trace's clock.
 - ``SlowQueryLog``: ring buffer of queries exceeding a configured
   threshold, each entry carrying the rendered trace tree (served at
   /debug/slow_queries and counted in /metrics).
@@ -23,13 +27,15 @@ from __future__ import annotations
 
 import bisect
 import contextlib
+import random
 import sys
 import threading
 import time
 import traceback
-import uuid
 from collections import Counter, deque
 from dataclasses import dataclass, field
+
+from jax.profiler import TraceAnnotation
 
 
 class Counter_:
@@ -106,6 +112,7 @@ def escape_help(v: str) -> str:
 HELP_TEXTS: dict[str, str] = {
     "filodb_queries": "Queries served, per dataset (coalesced followers included).",
     "filodb_query_latency_seconds": "End-to-end query latency.",
+    "filodb_queries_coalesced": "Callers that rode on an identical in-flight query's execution (single-flight followers).",
     "filodb_slow_queries": "Queries over the slow-query threshold (see /debug/slow_queries).",
     "filodb_breaker_transitions": "Circuit-breaker state transitions per endpoint.",
     "filodb_breaker_state": "Breaker state per endpoint: 0 closed, 0.5 half-open, 1 open.",
@@ -151,6 +158,13 @@ HELP_TEXTS: dict[str, str] = {
     "filodb_standing_promotions": "Standing-query lifecycle events (register|promote|demote).",
     "filodb_standing_rule_samples": "Samples written back into the memstore by recording rules.",
     "filodb_query_phase_seconds": "Per-phase query latency decomposition (parse_plan|admission|stage|dispatch|transfer|render|other).",
+    "filodb_stage_part_seconds": "Where the stage phase went, per execution (lookup|gather|assemble|h2d_shard|readback|concat|h2d_super); the parts sum to at most the stage phase.",
+    "filodb_stage_h2d_bytes": "Bytes a cold stage uploaded to the device, by part (h2d_shard = per-shard blocks, h2d_super = the superblock and its le vector).",
+    "filodb_stage_d2h_bytes": "Bytes a cold stage read back from device-resident staged arrays (the first np.asarray of each).",
+    "filodb_query_wait_seconds": "Per-caller wait for work another caller runs, by kind (coalesced = a follower of an identical in-flight query).",
+    "filodb_http_request_seconds": "Handler wall of a query route, entry to return, per caller (route = query_range|query).",
+    "filodb_transfer_ready_seconds": "Per-caller wait for the device to finish the query's program at the serving edge; the transfer phase less this is the copy back.",
+    "filodb_render_write_seconds": "Per-caller wall of writing a query response (status line, headers, body) to the socket; the render phase less this is encoding.",
     "filodb_query_path": "Queries by execution path (fused|fallback|tree|standing:delta|standing:full|standing:serve) per dataset.",
     "filodb_tenant_phase_seconds": "Per-phase query wall seconds attributed to the tenant (ws/ns).",
     "filodb_tenant_query_latency_seconds": "End-to-end query latency per tenant (the latency-SLO feed).",
@@ -165,7 +179,7 @@ HELP_TEXTS: dict[str, str] = {
     "filodb_xla_recompile_storms": "Recompile storms detected per kernel family (same family re-lowering past the threshold inside the window; /debug/kernels names the unstable dimension).",
     "filodb_xla_executables": "Live executables in the kernel observatory's registry.",
     "filodb_kernel_exec_dispatches": "Kernel dispatches accounted by the executable registry, per family.",
-    "filodb_kernel_exec_device_seconds": "Per-dispatch device cost of warm (non-compiling) dispatches, per kernel family (host dispatch wall; exact block_until_ready deltas with kernel_obs.device_timing).",
+    "filodb_kernel_exec_device_seconds": "Per-dispatch device cost of warm (non-compiling) dispatches, per kernel family (the host wall of the dispatch call).",
     "filodb_compile_cache_hits": "Compile-cache hits by tier (in_process = warm jit cache, persistent = compile deserialized from the on-disk XLA cache).",
     "filodb_compile_cache_misses": "Compile-cache misses by tier (in_process = a compile happened, persistent = a fresh trace wrote a new on-disk entry).",
     "filodb_index_postings_bytes": "Host posting-bitmap footprint of the part-key index, per shard.",
@@ -445,6 +459,26 @@ QUERY_PHASES = (
     "other",
 )
 
+# the ONE canonical set of parts of the ``stage`` phase, in the order a cold
+# fused query pays them (``span(..., part=...)``; linted and refused at
+# runtime exactly as the phases are). A part is booked only under a span
+# that books ``stage``, and nested parts book exclusive time, so
+# sum(parts) <= stage; what is left is bookkeeping between the parts.
+#
+# - lookup     — part-key index lookups, per shard
+# - gather     — the per-partition ``samples_in_range`` loop (chunk decode)
+# - assemble   — pad into [S, T(, B)] blocks, bucket-scheme unify, labels
+# - h2d_shard  — per-shard block: host mirror copies + ``device_put``
+# - readback   — ``np.asarray`` of a device-resident staged array (a D2H
+#                copy the first time; waits for the upload it reads)
+# - concat     — row-concatenate the shard blocks into the superblock
+# - h2d_super  — the superblock's (and the ``le`` vector's) upload
+STAGE_PARTS = (
+    "lookup", "gather", "assemble", "h2d_shard", "readback", "concat",
+    "h2d_super",
+)
+_STAGE_PART_SET = frozenset(STAGE_PARTS)
+
 
 # the executing query's PhaseRecorder (obs/querylog.py), activated per
 # thread by ExecPlan.execute exactly like the QueryStats attribution
@@ -476,11 +510,12 @@ _trace_local = threading.local()
 
 
 def new_trace_id() -> str:
-    return uuid.uuid4().hex[:16]
+    return "%016x" % random.getrandbits(64)
 
 
-def new_span_id() -> str:
-    return uuid.uuid4().hex[:16]
+# span ids need to be distinct within a trace, not unguessable: 64 random
+# bits at a tenth of a uuid4's cost (the hot path opens tens of spans)
+new_span_id = new_trace_id
 
 
 @dataclass(frozen=True)
@@ -516,6 +551,12 @@ class Span:
     @property
     def duration_ms(self) -> float:
         return (self.end_ns - self.start_ns) / 1e6
+
+    @property
+    def seconds(self) -> float:
+        """The closed span's wall: what a caller observes into a histogram
+        after the ``with`` block, so the span and the metric are one clock."""
+        return (self.end_ns - self.start_ns) / 1e9
 
     def context(self) -> TraceContext:
         return TraceContext(self.trace_id, self.span_id, self.parent_id)
@@ -572,43 +613,90 @@ class Span:
 _UNSET = object()
 
 
-@contextlib.contextmanager
-def span(name: str, parent=_UNSET, phase: str | None = None, **tags):
-    """Nested timing spans (Kamon.runWithSpan analog). The thread-local
-    current span is the default parent; an explicit ``parent=`` Span wires a
-    span into a trace across thread hops (a worker thread has no thread-local
-    context — the submitter captures ``current_span()`` and either passes it
-    here or re-activates it via ``activate``). The root span of a thread is
-    retrievable via current_trace().
+class span:
+    """Nested timing spans (Kamon.runWithSpan analog), as a context manager
+    yielding the :class:`Span`. The thread-local current span is the default
+    parent; an explicit ``parent=`` Span wires a span into a trace across
+    thread hops (a worker thread has no thread-local context — the submitter
+    captures ``current_span()`` and either passes it here or re-activates it
+    via ``activate``). The root span of a thread is retrievable via
+    current_trace().
 
     ``phase=`` additionally attributes the span's wall time to the active
     query's phase decomposition (QUERY_PHASES; the recorder bound via
     ``activate_phases``) — the query-observatory capture point for phases
-    that already run under a span (e.g. ``fused:stage``)."""
-    cur = getattr(_trace_local, "current", None)
-    eff_parent = cur if cur is not None else (None if parent is _UNSET else parent)
-    s = Span(name, time.perf_counter_ns())
-    if tags:
-        s.tags.update(tags)
-    if eff_parent is not None:
-        s.trace_id = eff_parent.trace_id
-        s.parent_id = eff_parent.span_id
-        # list.append is atomic under the GIL: children may attach from
-        # concurrent pool threads re-activating the same parent
-        eff_parent.children.append(s)
-    else:
-        s.trace_id = new_trace_id()
-        _trace_local.root = s
-    _trace_local.current = s
-    try:
-        yield s
-    finally:
+    that already run under a span (e.g. ``fused:stage``).
+
+    ``part=`` books the wall under a part of the phase that ENCLOSES the
+    span (STAGE_PARTS; an unknown name raises). Only a span running under a
+    ``phase="stage"`` span on this thread books; elsewhere (the reference
+    tree stages without a phase) it is a plain span. A part span nested in
+    another books its own wall and the outer one books the rest.
+
+    Every span also holds a ``jax.profiler.TraceAnnotation`` of its name
+    carrying its ``trace_id``: a no-op in jax's C++ without a profiler
+    session; with one, the span is a host event on the profile's clock."""
+
+    __slots__ = ("_s", "_cur", "_phase", "_part", "_outer_phase",
+                 "_outer_inner_ns", "_ann")
+
+    def __init__(self, name: str, parent=_UNSET, phase: str | None = None,
+                 part: str | None = None, **tags):
+        if part is not None and part not in _STAGE_PART_SET:
+            raise ValueError(
+                f"unknown stage part {part!r} (canonical set: "
+                f"{sorted(_STAGE_PART_SET)})"
+            )
+        self._phase, self._part = phase, part
+        self._cur = cur = getattr(_trace_local, "current", None)
+        eff_parent = cur if cur is not None else (
+            None if parent is _UNSET else parent)
+        self._s = s = Span(name, 0)
+        if tags:
+            s.tags.update(tags)
+        if eff_parent is not None:
+            s.trace_id = eff_parent.trace_id
+            s.parent_id = eff_parent.span_id
+            # list.append is atomic under the GIL: children may attach from
+            # concurrent pool threads re-activating the same parent
+            eff_parent.children.append(s)
+        else:
+            s.trace_id = new_trace_id()
+
+    def __enter__(self) -> "Span":
+        s = self._s
+        if s.parent_id is None:
+            _trace_local.root = s
+        _trace_local.current = s
+        if self._phase is not None:
+            self._outer_phase = getattr(_trace_local, "phase", None)
+            _trace_local.phase = self._phase
+        if self._part is not None:
+            self._outer_inner_ns = getattr(_trace_local, "part_inner_ns", 0)
+            _trace_local.part_inner_ns = 0
+        self._ann = TraceAnnotation(s.name, trace_id=s.trace_id)
+        self._ann.__enter__()
+        s.start_ns = time.perf_counter_ns()
+        return s
+
+    def __exit__(self, *exc) -> None:
+        s = self._s
         s.end_ns = time.perf_counter_ns()
-        _trace_local.current = cur
-        if phase is not None:
+        self._ann.__exit__(*exc)
+        _trace_local.current = self._cur
+        wall_ns = s.end_ns - s.start_ns
+        if self._phase is not None:
+            _trace_local.phase = self._outer_phase
             rec = current_phases()
             if rec is not None:
-                rec.add(phase, (s.end_ns - s.start_ns) / 1e9)
+                rec.add(self._phase, wall_ns / 1e9)
+        if self._part is not None:
+            inner_ns = _trace_local.part_inner_ns
+            _trace_local.part_inner_ns = self._outer_inner_ns + wall_ns
+            if getattr(_trace_local, "phase", None) == "stage":
+                rec = current_phases()
+                if rec is not None:
+                    rec.add_part(self._part, (wall_ns - inner_ns) / 1e9)
 
 
 @contextlib.contextmanager
@@ -819,7 +907,7 @@ def current_stats():
 
 def record_kernel_dispatch(kernel: str, seconds: float,
                            compiled: bool | None = None,
-                           key: dict | None = None, result=None) -> None:
+                           key: dict | None = None) -> None:
     """Latency histogram around an ops/ kernel entry point, plus JIT
     compile-cache hit/miss accounting when the caller can observe its jit
     cache (a grown cache across the call means this dispatch compiled).
@@ -829,9 +917,8 @@ def record_kernel_dispatch(kernel: str, seconds: float,
     dispatch.
 
     ``key`` (executable-key parts: variant/epilogue/shapes/mesh/batch —
-    obs.kernels.KEY_DIMS) and ``result`` (the dispatch's device output,
-    for the opt-in exact device timing) additionally feed the kernel &
-    compile observatory's per-executable registry; the family dimension is
+    obs.kernels.KEY_DIMS) additionally feeds the kernel & compile
+    observatory's per-executable registry; the family dimension is
     ``kernel`` itself, so the registry and this histogram's ``kernel=``
     label stay the same vocabulary."""
     REGISTRY.histogram("filodb_kernel_dispatch_seconds", kernel=kernel).observe(seconds)
@@ -847,8 +934,7 @@ def record_kernel_dispatch(kernel: str, seconds: float,
     # compile/dispatch/device-cost attribution + recompile-storm detection
     from .obs.kernels import KERNELS
 
-    KERNELS.observe_dispatch(kernel, seconds, compiled=compiled, parts=key,
-                             result=result)
+    KERNELS.observe_dispatch(kernel, seconds, compiled=compiled, parts=key)
 
 
 # -- sampling profiler ------------------------------------------------------

@@ -32,10 +32,9 @@ executable it ran:
 Per key the registry records compile count + compile seconds (the dispatch
 that grew the jit cache paid trace+compile inline — that wall time IS the
 measurable compile cost), per-dispatch counts and device-time
-:class:`~filodb_tpu.metrics.MicroHistogram` p50/p99 (host dispatch wall by
-default; with ``kernel_obs.device_timing`` a ``jax.block_until_ready``
-delta is folded in for exact device cost on the CPU backend — opt-in
-because the sync serializes the async dispatch pipeline), executable bytes
+:class:`~filodb_tpu.metrics.MicroHistogram` p50/p99 (the host wall of the
+dispatch call: the device's own time is on a profiler trace, and every
+caller's wait for it is ``filodb_transfer_ready_seconds``), executable bytes
 (the persistent compile cache's serialized entry, when one was written)
 and compile provenance: ``persistent`` (loaded from the on-disk XLA cache),
 ``in_process`` (the jit cache hit — the steady state) or ``fresh`` (traced
@@ -176,16 +175,11 @@ class ExecutableRegistry:
         self.max_entries = int(max_entries)
         self.storm_threshold = int(storm_threshold)
         self.storm_window_s = float(storm_window_s)
-        # opt-in exact device timing: block_until_ready around each
-        # dispatch (bench/attest runs turn this on; serving keeps it off —
-        # the sync would serialize the async dispatch pipeline)
-        self.device_timing = False
         self._local = threading.local()
 
     def configure(self, max_entries: int | None = None,
                   storm_threshold: int | None = None,
-                  storm_window_s: float | None = None,
-                  device_timing: bool | None = None) -> None:
+                  storm_window_s: float | None = None) -> None:
         with self._lock:
             if max_entries is not None:
                 self.max_entries = max(int(max_entries), 16)
@@ -193,8 +187,6 @@ class ExecutableRegistry:
                 self.storm_threshold = max(int(storm_threshold), 1)
             if storm_window_s is not None:
                 self.storm_window_s = max(float(storm_window_s), 1.0)
-            if device_timing is not None:
-                self.device_timing = bool(device_timing)
 
     # -- jit wrapper registration (the lint anchor) -----------------------
 
@@ -245,7 +237,7 @@ class ExecutableRegistry:
 
     def observe_dispatch(self, family: str, seconds: float,
                          compiled: bool | None = None,
-                         parts: dict | None = None, result=None) -> str:
+                         parts: dict | None = None) -> str:
         """Account one kernel dispatch (called from
         ``metrics.record_kernel_dispatch`` — the one funnel every ops/
         entry point already routes through). Returns the executable key
@@ -269,15 +261,6 @@ class ExecutableRegistry:
 
             provenance, entry_bytes = classify_dispatch(is_compile)
         device_s = float(seconds)
-        if self.device_timing and result is not None and not is_compile:
-            t0 = time.perf_counter()
-            try:
-                import jax
-
-                jax.block_until_ready(result)
-                device_s += time.perf_counter() - t0
-            except Exception:  # noqa: BLE001 — host-only results (np arrays)
-                pass
         now = time.time()
         with self._lock:
             rec = self._records.get(key)
@@ -398,7 +381,6 @@ class ExecutableRegistry:
                 "max_executables": self.max_entries,
                 "storm_threshold": self.storm_threshold,
                 "storm_window_s": self.storm_window_s,
-                "device_timing": self.device_timing,
             },
         }
 

@@ -44,9 +44,10 @@ import threading
 import time
 from collections import deque
 
-from ..metrics import QUERY_PHASES, REGISTRY
+from ..metrics import QUERY_PHASES, REGISTRY, STAGE_PARTS
 
 _PHASE_SET = frozenset(QUERY_PHASES)
+_PART_SET = frozenset(STAGE_PARTS)
 
 # phases measured inside the engine; transfer/render are added by the
 # serving edge after the engine returns, and ``other`` is the computed
@@ -63,10 +64,13 @@ class PhaseRecorder:
     so pool workers and the batch scheduler attribute to the right query
     without threading a context through every ops/ signature."""
 
-    __slots__ = ("seconds", "_lock")
+    __slots__ = ("seconds", "parts", "_lock")
 
     def __init__(self):
         self.seconds: dict[str, float] = {}
+        # parts of the ``stage`` phase (metrics.STAGE_PARTS), booked by
+        # ``span(..., part=...)``: sum(parts) <= seconds["stage"]
+        self.parts: dict[str, float] = {}
         self._lock = threading.Lock()
 
     def add(self, phase: str, seconds: float) -> None:
@@ -78,6 +82,17 @@ class PhaseRecorder:
         with self._lock:
             self.seconds[phase] = (
                 self.seconds.get(phase, 0.0) + max(float(seconds), 0.0)
+            )
+
+    def add_part(self, part: str, seconds: float) -> None:
+        if part not in _PART_SET:
+            raise ValueError(
+                f"unknown stage part {part!r} (canonical set: "
+                f"{sorted(_PART_SET)})"
+            )
+        with self._lock:
+            self.parts[part] = (
+                self.parts.get(part, 0.0) + max(float(seconds), 0.0)
             )
 
     @contextlib.contextmanager
@@ -93,6 +108,10 @@ class PhaseRecorder:
     def snapshot(self) -> dict[str, float]:
         with self._lock:
             return dict(self.seconds)
+
+    def parts_snapshot(self) -> dict[str, float]:
+        with self._lock:
+            return dict(self.parts)
 
     def total(self) -> float:
         with self._lock:
@@ -179,7 +198,7 @@ class QueryLogRing:
         # (finish_serving) — readers must get copies, nested mutable
         # fields included
         out = dict(e)
-        for k in ("phases_ms", "stats", "result", "grid"):
+        for k in ("phases_ms", "stage_parts_ms", "stats", "result", "grid"):
             if isinstance(out.get(k), dict):
                 out[k] = dict(out[k])
         return out
@@ -224,6 +243,7 @@ class QueryLogRing:
         leader's record; remote-child legs don't publish — the origin
         accounts the whole query, mirroring tenant metering)."""
         ph = phases.snapshot()
+        parts = phases.parts_snapshot()
         # the residual: engine wall time the named phases don't cover
         # (transformer folding, result assembly, scatter overhead) — makes
         # the engine-phase sum equal wall time by construction
@@ -273,6 +293,10 @@ class QueryLogRing:
                                 if realized_cost_s is not None else None),
             "duration_ms": round(float(elapsed_s) * 1e3, 3),
             "phases_ms": {k: round(v * 1e3, 3) for k, v in ph.items()},
+            # where ``stage`` went (metrics.STAGE_PARTS; empty on a warm
+            # superblock hit): sum <= phases_ms["stage"]
+            "stage_parts_ms": {k: round(v * 1e3, 3)
+                               for k, v in parts.items()},
             "stats": {
                 "series_scanned": getattr(stats, "series_scanned", 0),
                 "samples_scanned": getattr(stats, "samples_scanned", 0),
@@ -287,6 +311,10 @@ class QueryLogRing:
         }
         for phase, s in ph.items():
             observe_phase(dataset, phase, s, trace_id=query_id)
+        for part, s in parts.items():
+            REGISTRY.histogram(
+                "filodb_stage_part_seconds", part=part, dataset=dataset
+            ).observe(s)
         _record_tenant_phases(ws, ns, ph)
         REGISTRY.counter("filodb_query_path", path=path,
                          dataset=dataset).inc()

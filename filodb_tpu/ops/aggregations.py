@@ -40,12 +40,12 @@ def segment_aggregate(op: str, values, group_ids, num_groups: int):
         compiled=_segment_aggregate_jit._cache_size() > before,
         key={"variant": "general", "epilogue": f"agg:{op}",
              "shapes": f"S{s_}xJ{j_}xG{num_groups}"},
-        result=out,
     )
     return out
 
 
 @functools.partial(jax.jit, static_argnames=("op", "num_groups"))
+@jax.named_scope("group_reduce")
 def _segment_aggregate_jit(op: str, values, group_ids, num_groups: int):
     valid = ~jnp.isnan(values)
     v0 = jnp.where(valid, values, 0.0)
@@ -77,6 +77,7 @@ def _segment_aggregate_jit(op: str, values, group_ids, num_groups: int):
     raise ValueError(f"unknown aggregation {op}")
 
 
+@jax.named_scope("group_reduce")
 def _segment_psum_axis(op: str, grid, gids, num_groups: int, axis: str):
     """Local segment-reduce + collective combine over a mesh axis: the
     device-local half of ``segment_aggregate`` followed by psum/pmin/pmax,
@@ -250,6 +251,7 @@ def _mgrid_args(g) -> tuple:
             g.ff2v, g.ff2d, bfraw)
 
 
+@jax.named_scope("epilogue")
 def _apply_epilogue(sj, epilogue: tuple, gids, n_real, qv, num_groups: int):
     """Device-side epilogue over the [S, J] range grid, INSIDE the same
     compiled program as the range kernel. ``epilogue`` is a static tuple:
@@ -432,6 +434,7 @@ def _fused_pallas_jit(func, epilogue, ts, vals, raw, lens, gids, n_real, qv,
     return _apply_epilogue(sj, epilogue, gids, n_real, qv, num_groups)
 
 
+@jax.named_scope("epilogue")
 def _sharded_epilogue(sj, epilogue: tuple, gids_l, n_real, qv,
                       num_groups: int, axis: str):
     """Device-local half of _apply_epilogue inside a shard_map body, with
@@ -888,7 +891,6 @@ def _fused_dispatch(func: str, epilogue: tuple, block, gids_padded,
         name, _time.perf_counter() - t0, compiled=fn._cache_size() > before,
         key=_exec_key_parts(variant, epilogue, block, j_pad, num_groups,
                             mesh),
-        result=out,
     )
     return out
 
@@ -1126,7 +1128,6 @@ def fused_hist_range_aggregate(func: str, block, gids_padded,
             hist_variant, ("hist", "quantile" if q is not None else "sum"),
             block, j_pad, num_groups, mesh,
         ),
-        result=out,
     )
     return out
 
@@ -1694,7 +1695,6 @@ def fused_batched_scalar(func: str, epilogue: tuple, block, lanes,
             variant, epilogue, block, j_pad, num_groups, mesh,
             batch=f"Q{len(padded)}xU{len(_ukeys)}",
         ),
-        result=out,
     )
     return out
 
@@ -1757,7 +1757,6 @@ def fused_batched_hist(func: str, block, lanes, num_groups: int, j_pad: int,
             ("hist", "quantile" if quantile else "sum"), block, j_pad,
             num_groups, mesh, batch=f"Q{len(padded)}xU{len(_ukeys)}",
         ),
-        result=out,
     )
     return out
 

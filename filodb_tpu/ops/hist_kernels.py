@@ -26,6 +26,7 @@ def _gather3(arr, idx):
 
 
 @functools.partial(jax.jit, static_argnames=("func", "num_steps", "is_delta"))
+@jax.named_scope("range_fn")
 def hist_range_kernel(
     func: str,
     ts,  # [S, T] i32
@@ -83,6 +84,7 @@ def hist_range_kernel(
 
 
 @functools.partial(jax.jit, static_argnames=("even",))
+@jax.named_scope("epilogue")
 def histogram_quantile(q, buckets, les, even: bool = False):
     """Prometheus histogram_quantile over bucket-count/rate grids.
 
@@ -154,6 +156,7 @@ FUSED_HIST_FUNCS = frozenset({
 })
 
 
+@jax.named_scope("range_fn")
 def _hist_range_shared(func, vals, lo, hi, t_first, t_last, out_t, window,
                        is_delta: bool):
     """Shared-regular-grid form of hist_range_kernel: every series shares
@@ -204,6 +207,7 @@ def _hist_range_shared(func, vals, lo, hi, t_first, t_last, out_t, window,
     raise ValueError(f"unknown histogram range function {func}")
 
 
+@jax.named_scope("range_fn")
 def _hist_range_jitter(func, vals, dev, hwa, window, is_delta: bool):
     """Near-regular (jittered) grid form of hist_range_kernel: the SHARED
     certain-range boundary vectors [J] (clo/chi from the nominal grid,
@@ -381,17 +385,19 @@ def _hist_sharded_combine(sjb, gids_l, les, qv, num_groups: int,
     _segment_aggregate_jit's "sum" (a group with no members anywhere is
     NaN), via psum'd validity counts."""
     S, J, B = sjb.shape
-    flat = sjb.reshape(S, J * B)
-    valid = ~jnp.isnan(flat)
-    s = jax.ops.segment_sum(
-        jnp.where(valid, flat, 0.0), gids_l, num_groups + 1
-    )
-    c = jax.ops.segment_sum(valid.astype(flat.dtype), gids_l, num_groups + 1)
-    s = jax.lax.psum(s, axis)
-    c = jax.lax.psum(c, axis)
-    gjb = jnp.where(c > 0, s, jnp.nan)[:num_groups].reshape(
-        num_groups, J, B
-    )
+    with jax.named_scope("group_reduce"):
+        flat = sjb.reshape(S, J * B)
+        valid = ~jnp.isnan(flat)
+        s = jax.ops.segment_sum(
+            jnp.where(valid, flat, 0.0), gids_l, num_groups + 1
+        )
+        c = jax.ops.segment_sum(
+            valid.astype(flat.dtype), gids_l, num_groups + 1)
+        s = jax.lax.psum(s, axis)
+        c = jax.lax.psum(c, axis)
+        gjb = jnp.where(c > 0, s, jnp.nan)[:num_groups].reshape(
+            num_groups, J, B
+        )
     if quantile:
         return histogram_quantile(qv, gjb, les)
     return gjb

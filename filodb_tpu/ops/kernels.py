@@ -138,6 +138,7 @@ def _extrapolated(delta, t_first, t_last, count, v_first_raw, out_t, window, is_
 @functools.partial(
     jax.jit, static_argnames=("func", "num_steps", "is_counter", "is_delta")
 )
+@jax.named_scope("range_fn")
 def range_kernel(
     func: str,
     ts,  # [S, T] i32
@@ -496,7 +497,6 @@ def run_range_function(
         func, _time.perf_counter() - t0, compiled=_jit_cache_size() > before,
         key={"variant": variant,
              "shapes": f"S{s_}xT{t_}xJ{pad_steps(params.num_steps)}"},
-        result=out,
     )
     return out
 

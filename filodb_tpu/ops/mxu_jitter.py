@@ -233,6 +233,7 @@ _F0, _L0, _L2, _KLO, _KHI = range(5)
 @functools.partial(
     jax.jit, static_argnames=("func", "is_counter", "is_delta", "fetch")
 )
+@jax.named_scope("range_fn")
 def jitter_range_kernel(
     func: str,
     vals,  # [S, T] f32
@@ -425,6 +426,7 @@ def jitter_range_kernel(
 
 
 @functools.partial(jax.jit, static_argnames=("n_valid", "is_min", "fetch"))
+@jax.named_scope("range_fn")
 def jitter_minmax(vals, dev, SEL, idx, tile_mask, edge_onehot, edge_valid,
                   edge_idx, count0, has_klo, has_khi, blo_rel, ehi_rel,
                   n_valid: int, is_min: bool = True, fetch: str = "auto"):
@@ -475,6 +477,7 @@ def jitter_minmax(vals, dev, SEL, idx, tile_mask, edge_onehot, edge_valid,
 @functools.partial(
     jax.jit, static_argnames=("func", "is_counter", "is_delta", "fetch")
 )
+@jax.named_scope("range_fn")
 def jitter_masked_kernel(
     func: str,
     vals,  # [S, T] f32 slot-aligned, 0 at holes
@@ -706,6 +709,7 @@ def jitter_masked_kernel(
 
 
 @functools.partial(jax.jit, static_argnames=("is_min", "fetch"))
+@jax.named_scope("range_fn")
 def jitter_masked_minmax(vals, dev, valid, cc, SEL, idx, tile_mask,
                          edge_onehot, edge_valid, edge_idx, c0pos_g,
                          has_klo, has_khi, blo_rel, ehi_rel,

@@ -247,6 +247,7 @@ def window_matrices(block: StagedBlock, start_off: int, step_ms: int,
 @functools.partial(
     jax.jit, static_argnames=("func", "is_counter", "is_delta", "fetch")
 )
+@jax.named_scope("range_fn")
 def mxu_range_kernel(
     func: str,
     vals,  # [S, T] f32
@@ -368,6 +369,7 @@ def mxu_pair_count(flagged, P, has):
 
 
 @functools.partial(jax.jit, static_argnames=("n_valid", "is_min", "fetch"))
+@jax.named_scope("range_fn")
 def mxu_minmax(vals, tile_mask, edge_onehot, edge_valid, count,
                n_valid: int, is_min: bool = True, edge_idx=None,
                fetch: str = "auto"):

@@ -84,6 +84,7 @@ def _window_agg_kernel(params_ref, ts_ref, vals_ref, raw_ref, lens_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("num_steps", "interpret"))
+@jax.named_scope("range_fn")
 def window_aggregates(ts, vals, raw, lens, start_off, step_ms, window_ms,
                       num_steps: int, interpret: bool):
     """[S, T] staged block -> dict of [S, num_steps] per-window statistics."""
@@ -163,6 +164,7 @@ def pallas_enabled(t_pad: int) -> bool:
 
 
 @functools.partial(jax.jit, static_argnames=("func", "is_counter", "is_delta"))
+@jax.named_scope("range_fn")
 def finish(func: str, agg: dict, start_off, step_ms, window_ms,
            is_counter: bool = False, is_delta: bool = False):
     """Derive a range function from the fused window statistics."""

@@ -46,7 +46,6 @@ def intersect_on_device(dev_words: list) -> np.ndarray:
         "postings_intersect", _time.perf_counter() - t0,
         compiled=fn._cache_size() > before,
         key={"variant": "general", "shapes": f"M{m_}xW{w_}"},
-        result=out_dev,
     )
     out = np.asarray(out_dev)
     return np.ascontiguousarray(out).view(np.uint64)

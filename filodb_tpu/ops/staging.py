@@ -34,6 +34,8 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from ..metrics import REGISTRY, span
+
 # S pads to the next bucket; T pads to a multiple of 128 (TPU lane width)
 _S_BUCKETS = (8, 32, 128, 512, 1024, 2048, 4096, 8192, 16384, 32768, 65536, 131072)
 
@@ -1190,6 +1192,27 @@ def staged_nbytes(block: StagedBlock) -> int:
     return total
 
 
+def read_back(*arrays) -> list:
+    """``np.asarray`` of staged arrays that may be device-resident, as the
+    ``readback`` part of ``stage``. The first conversion of a device array
+    is a D2H copy (jax keeps the host copy on the array afterwards) and
+    waits for the upload that produced it; those bytes are counted in
+    ``filodb_stage_d2h_bytes_total``. ``None`` passes through."""
+    import jax
+
+    out, nbytes = [], 0
+    with span("stage:readback", part="readback"):
+        for a in arrays:
+            # _npy_value is where jax caches a fetched host copy; a jax
+            # without it would count every conversion, never too few
+            if isinstance(a, jax.Array) and getattr(a, "_npy_value", None) is None:
+                nbytes += int(a.nbytes)
+            out.append(None if a is None else np.asarray(a))
+    if nbytes:
+        REGISTRY.counter("filodb_stage_d2h_bytes").inc(nbytes)
+    return out
+
+
 def concat_blocks(blocks, force_raw: bool = False,
                   series_multiple: int = 1) -> StagedBlock:
     """Row-concatenate staged blocks into one padded superblock EXACTLY —
@@ -1221,12 +1244,13 @@ def concat_blocks(blocks, force_raw: bool = False,
     Sp = pad_series(S)
     if series_multiple > 1:
         Sp = ((Sp + series_multiple - 1) // series_multiple) * series_multiple
-    is_hist = any(np.asarray(b.vals).ndim == 3 for b in real)
+    host_vals = read_back(*(b.vals for b in real))
+    is_hist = any(v.ndim == 3 for v in host_vals)
     if is_hist:
-        assert len({np.asarray(b.vals).shape[2] for b in real}) == 1, (
+        assert len({v.shape[2] for v in host_vals}) == 1, (
             "histogram blocks must share one bucket scheme before concat"
         )
-        B = np.asarray(real[0].vals).shape[2]
+        B = host_vals[0].shape[2]
         val_shape, base_shape = (Sp, T, B), (Sp, B)
     else:
         val_shape, base_shape = (Sp, T), (Sp,)
@@ -1238,15 +1262,16 @@ def concat_blocks(blocks, force_raw: bool = False,
     baseline = np.zeros(base_shape, np.float32)
     part_refs: list = []
     o = 0
-    for b in real:
+    for b, b_vals in zip(real, host_vals):
         k, t = b.n_series, b.ts.shape[1]
-        ts[o : o + k, :t] = np.asarray(b.ts)[:k]
-        vals[o : o + k, :t] = np.asarray(b.vals)[:k]
+        b_ts, b_raw, b_lens, b_base = read_back(
+            b.ts, b.raw if raw is not None else None, b.lens, b.baseline)
+        ts[o : o + k, :t] = b_ts[:k]
+        vals[o : o + k, :t] = b_vals[:k]
         if raw is not None:
-            src_raw = b.raw if b.raw is not None else b.vals
-            raw[o : o + k, :t] = np.asarray(src_raw)[:k]
-        lens[o : o + k] = np.asarray(b.lens)[:k]
-        baseline[o : o + k] = np.asarray(b.baseline)[:k]
+            raw[o : o + k, :t] = (b_raw if b_raw is not None else b_vals)[:k]
+        lens[o : o + k] = b_lens[:k]
+        baseline[o : o + k] = b_base[:k]
         part_refs.extend(b.part_refs)
         o += k
     reg = real[0].regular_ts
@@ -1650,18 +1675,21 @@ def stage_from_shard(
     series = []
     refs = []
     hist_width = None
-    for pid in part_ids:
-        part = shard.partition(int(pid))
-        ts, vals = part.samples_in_range(start_ms, end_ms, column)
-        if vals.ndim == 2:
-            hist_width = vals.shape[1]
-        series.append((ts, vals))
-        refs.append((shard.shard_num, int(pid)))
+    with span("stage:gather", part="gather"):
+        for pid in part_ids:
+            part = shard.partition(int(pid))
+            ts, vals = part.samples_in_range(start_ms, end_ms, column)
+            if vals.ndim == 2:
+                hist_width = vals.shape[1]
+            series.append((ts, vals))
+            refs.append((shard.shard_num, int(pid)))
     if hist_width is not None:
-        return stage_histogram_series(
-            series, start_ms, hist_width, refs,
-            subtract_baseline=mode in ("corrected", "shifted"), dtype=dtype
-        )
+        with span("stage:assemble", part="assemble"):
+            return stage_histogram_series(
+                series, start_ms, hist_width, refs,
+                subtract_baseline=mode in ("corrected", "shifted"),
+                dtype=dtype,
+            )
 
     newest = max((int(ts[-1]) for ts, _ in series if len(ts)), default=None)
 
@@ -1672,21 +1700,24 @@ def stage_from_shard(
         # historical ranges never repair, so they never pay the wider T.
         live_edge = newest is not None and end_ms >= newest
         headroom = 256 if (live_edge and len(sr) <= 8192) else 0
-        return stage_series(
-            sr, start_ms, refs,
-            counter_corrected=mode == "corrected",
-            subtract_baseline=mode == "shifted",
-            diff_encode=mode == "diff",
-            dtype=dtype,
-            time_headroom=headroom,
-        )
+        with span("stage:assemble", part="assemble"):
+            return stage_series(
+                sr, start_ms, refs,
+                counter_corrected=mode == "corrected",
+                subtract_baseline=mode == "shifted",
+                diff_encode=mode == "diff",
+                dtype=dtype,
+                time_headroom=headroom,
+            )
 
     block = _stage(series)
     if (
         block.regular_ts is None and block.nominal_ts is None
         and block.n_series > 1
     ):
-        aligned = _slot_align(shard, part_ids, column, series, start_ms, end_ms)
+        with span("stage:gather", part="gather"):
+            aligned = _slot_align(shard, part_ids, column, series, start_ms,
+                                  end_ms)
         if aligned is not None:
             block = _stage(aligned)
     return block

@@ -16,6 +16,7 @@ import numpy as np
 
 from ...core.filters import ColumnFilter
 from ...core.schemas import METRIC_TAG, ColumnType
+from ...metrics import REGISTRY, span
 from ...ops import aggregations as AGG
 from ...ops import staging as ST
 from ..rangevector import Grid, QueryResult, QueryStats, RawGrid, ScalarResult
@@ -93,7 +94,7 @@ class ExecPlan:
 
     def execute(self, ctx: QueryContext) -> QueryResult:
         from ...metrics import (
-            Span, activate_phases, activate_stats, current_span, span,
+            Span, activate_phases, activate_stats, current_span,
         )
 
         t0 = time.perf_counter_ns()
@@ -319,7 +320,9 @@ def staged_block_for(ctx: "QueryContext", shard, ids, cache_key, col_name: str,
     # — the drift check walks the cache with this exact function
     nbytes = ST.staged_nbytes(block)
     ctx.stats.bump(bytes_staged=nbytes, cache_misses=1)
-    block.to_device(keep_host=True)  # mirrors enable append repair
+    with span("stage:h2d_shard", part="h2d_shard"):
+        block.to_device(keep_host=True)  # mirrors enable append repair
+    REGISTRY.counter("filodb_stage_h2d_bytes", part="h2d_shard").inc(nbytes)
     # byte-budgeted eviction, oldest entry first (the staging analog of
     # BlockManager reclaim under memory pressure). All cache mutations run
     # under the shard lock (the shard's selective invalidation iterates the
@@ -1079,7 +1082,7 @@ def _unify_hist_blocks(blocks, block_les):
     untouched."""
     from ...core.histograms import remap_buckets, unify_schemes
 
-    vals_in = [np.asarray(b.vals) for b in blocks]
+    vals_in = ST.read_back(*(b.vals for b in blocks))
     vals_out, union, changed = unify_schemes(vals_in, block_les)
     if not changed:
         return blocks, union
@@ -1137,7 +1140,7 @@ def _slice_bucket(block, les, bucket_le: float):
         b_idx = int(hits[0]) if len(hits) else -1
     if b_idx < 0:
         return None
-    vals3 = np.asarray(block.vals)
+    (vals3,) = ST.read_back(block.vals)
     scalar_vals = np.ascontiguousarray(vals3[..., b_idx])
     baseline = np.asarray(block.baseline)
     sliced = ST.StagedBlock(
@@ -1604,18 +1607,19 @@ class FusedAggregateExec(ExecPlan):
         for s in self.shard_nums:
             ctx.check_deadline()
             shard = ctx.memstore.shard(ctx.dataset, s)
-            pids = shard.lookup_partitions(
-                self.filters, self.raw_start_ms, self.raw_end_ms
-            )
             suffixed = False
-            if not len(pids) and rewritten is not None:
-                # classic-histogram suffix selector (m_sum / m_count /
-                # m_bucket): stage the base histogram schema's columns, same
-                # per-shard rewrite SelectRawPartitionsExec applies
+            with span("stage:lookup", part="lookup"):
                 pids = shard.lookup_partitions(
-                    rewritten, self.raw_start_ms, self.raw_end_ms
+                    self.filters, self.raw_start_ms, self.raw_end_ms
                 )
-                suffixed = len(pids) > 0
+                if not len(pids) and rewritten is not None:
+                    # classic-histogram suffix selector (m_sum / m_count /
+                    # m_bucket): stage the base histogram schema's columns,
+                    # same per-shard rewrite SelectRawPartitionsExec applies
+                    pids = shard.lookup_partitions(
+                        rewritten, self.raw_start_ms, self.raw_end_ms
+                    )
+                    suffixed = len(pids) > 0
             if not len(pids):
                 continue
             if len(pids) > ctx.max_series:
@@ -1629,8 +1633,9 @@ class FusedAggregateExec(ExecPlan):
             max_shard_series = max(max_shard_series, len(pids))
             if shard.odp_store is not None:
                 shard.odp_page_in(pids, self.raw_start_ms, self.raw_end_ms)
-            parts = [shard.partition(int(p)) for p in pids]
-            names = {p.schema.name for p in parts}
+            with span("stage:assemble", part="assemble"):
+                parts = [shard.partition(int(p)) for p in pids]
+                names = {p.schema.name for p in parts}
             if len(names) > 1 or (schema_name is not None
                                   and names != {schema_name}):
                 return "mixed_schemas"
@@ -1667,9 +1672,11 @@ class FusedAggregateExec(ExecPlan):
                 ctx, shard, pids, cache_key, col_name, self.raw_start_ms,
                 self.raw_end_ms, mode,
             )
-            part_labels = [dict(p.tags) for p in parts]
-            les = parts[0].bucket_les if hist_col else None
-            if hist_col and not _uniform_scheme(parts, les):
+            with span("stage:assemble", part="assemble"):
+                part_labels = [dict(p.tags) for p in parts]
+                les = parts[0].bucket_les if hist_col else None
+                uniform = not hist_col or _uniform_scheme(parts, les)
+            if not uniform:
                 # no scheme at all, or partitions WITHIN this shard disagree
                 # on bounds: one [S, T, B] block can't represent them (the
                 # union remap is per-shard) — keep the pre-fusion behavior
@@ -1682,7 +1689,7 @@ class FusedAggregateExec(ExecPlan):
                     # no such bucket on this shard: it contributes no rows,
                     # but its series/samples were scanned — count them, as
                     # the reference path does (it bumps before slicing)
-                    dropped_samples += int(np.asarray(block.lens).sum())
+                    dropped_samples += int(ST.read_back(block.lens)[0].sum())
                     continue
                 block, le_str = sliced
                 part_labels = [dict(l, le=le_str) for l in part_labels]
@@ -1693,7 +1700,7 @@ class FusedAggregateExec(ExecPlan):
             if hist_col != is_hist and blocks:
                 return "mixed_schemas"  # scalar + histogram blocks can't mix
             is_hist = hist_col
-            if np.asarray(block.vals).ndim != (3 if hist_col else 2):
+            if ST.read_back(block.vals)[0].ndim != (3 if hist_col else 2):
                 return "mixed_schemas"
             blocks.append(block)
             block_les.append(les)
@@ -1709,7 +1716,7 @@ class FusedAggregateExec(ExecPlan):
         if not blocks:
             return None  # empty selection: empty result, not a fallback
         samples = dropped_samples + int(
-            sum(int(np.asarray(b.lens).sum()) for b in blocks)
+            sum(int(h.sum()) for h in ST.read_back(*(b.lens for b in blocks)))
         )
         ctx.stats.bump(series_scanned=total, samples_scanned=samples,
                        cache_misses=1)
@@ -1720,7 +1727,8 @@ class FusedAggregateExec(ExecPlan):
             )
         les = None
         if is_hist:
-            blocks, les = _unify_hist_blocks(blocks, block_les)
+            with span("stage:assemble", part="assemble"):
+                blocks, les = _unify_hist_blocks(blocks, block_les)
         # host mirrors ride along so live-edge ingest can EXTEND the
         # superblock in place (ST.extend_superblock) instead of paying
         # concat + full re-upload per append — the delta-summation move.
@@ -1729,10 +1737,17 @@ class FusedAggregateExec(ExecPlan):
         # arrays pin SHARDED (PartitionSpec(axis) row bands) so the fused
         # program spans every device without a gather.
         multiple = self.mesh.devices.size if self.mesh is not None else 1
-        super_block = ST.concat_blocks(
-            blocks, series_multiple=multiple
-        ).to_device(keep_host=_SUPERBLOCK_EXTEND, mesh=self.mesh)
+        with span("stage:concat", part="concat"):
+            super_block = ST.concat_blocks(blocks, series_multiple=multiple)
+        with span("stage:h2d_super", part="h2d_super"):
+            super_block.to_device(keep_host=_SUPERBLOCK_EXTEND,
+                                  mesh=self.mesh)
+            les_dev = (ST.replicated_put(self.mesh)(
+                np.asarray(les, dtype=np.float32))
+                if les is not None else None)
         nbytes = ST.staged_nbytes(super_block)
+        REGISTRY.counter("filodb_stage_h2d_bytes", part="h2d_super").inc(
+            nbytes + (int(les_dev.nbytes) if les_dev is not None else 0))
 
         resolved_mode = (
             stage_mode if is_counter and not is_delta and not is_hist
@@ -1741,9 +1756,7 @@ class FusedAggregateExec(ExecPlan):
         value = SuperblockEntry(
             super_block, labels, is_counter, is_delta, samples,
             max_shard_series, series=total, is_hist=is_hist, les=les,
-            les_dev=(ST.replicated_put(self.mesh)(
-                np.asarray(les, dtype=np.float32))
-                if les is not None else None),
+            les_dev=les_dev,
             col_name=col_name,
             stage_mode=None if sliced_hist else resolved_mode,
         )
@@ -1886,7 +1899,6 @@ class FusedAggregateExec(ExecPlan):
         })
 
     def do_execute(self, ctx: QueryContext) -> QueryResult:
-        from ...metrics import span
         from ...ops.kernels import RangeParams, pad_steps
         from ..scheduler import FusedRequest
 
@@ -2137,7 +2149,7 @@ class RollupServeExec(ExecPlan):
         return self.fallback.execute(ctx)
 
     def do_execute(self, ctx: QueryContext) -> QueryResult:
-        from ...metrics import record_rollup_serve, span
+        from ...metrics import record_rollup_serve
         from ...ops import sketch as SKETCH
 
         rollups = self.rollups

@@ -202,7 +202,6 @@ class FiloServer:
             max_entries=int(kcfg["max_executables"]),
             storm_threshold=int(kcfg["storm_threshold"]),
             storm_window_s=float(kcfg["storm_window_s"]),
-            device_timing=bool(kcfg["device_timing"]),
         )
         register_kernel_obs_collector()
         # work cost model (query/costmodel.py): per-fingerprint predicted

@@ -132,22 +132,6 @@ class TestExecutableRegistry:
             KERNELS.observe_dispatch("x", 0.001, compiled=False,
                                      parts={"bogus": "1"})
 
-    def test_device_timing_opt_in(self):
-        vals = np.ones((4, 3), np.float32)
-        gids = np.zeros(4, np.int32)
-        AGG.segment_aggregate("sum", vals, gids, 1)  # compile outside timing
-        key = executable_key({"family": "segment_sum", "variant": "general",
-                              "epilogue": "agg:sum", "shapes": "S4xJ3xG1"})
-        before = _record_for(KERNELS.snapshot(), key)["device_total_ms"]
-        KERNELS.configure(device_timing=True)
-        try:
-            AGG.segment_aggregate("sum", vals, gids, 1)
-        finally:
-            KERNELS.configure(device_timing=False)
-        after = _record_for(KERNELS.snapshot(), key)
-        assert after["device_total_ms"] > before
-        assert after["dispatches"] >= 2
-
     def test_capacity_eviction_drops_stale_entries_not_the_new_one(self):
         from filodb_tpu.obs.kernels import ExecutableRegistry
 

@@ -165,11 +165,18 @@ class TestTracing:
         engine = QueryEngine(ms, "prometheus")
         with span("query") as root:
             engine.query_range("sum(heap_usage0)", (BASE + 600_000) / 1000, (BASE + 900_000) / 1000, 60)
-        names = [c.name for c in root.children]
+        # the caller's span holds the engine's (each caller's wall inside
+        # the engine), which holds the query's root and its plan nodes
+        (engine_span,) = root.children
+        assert engine_span.name == "engine:query_range"
+        (query_root,) = engine_span.children
+        assert query_root.name == "query"
+        assert query_root.trace_id == root.trace_id
+        names = [c.name for c in query_root.children]
         # default engine plans the aggregate as the fused single-dispatch
         # node; its stage/dispatch phases are child spans
         assert "FusedAggregateExec" in names
-        fused = root.children[names.index("FusedAggregateExec")]
+        fused = query_root.children[names.index("FusedAggregateExec")]
         child_names = {c.name for c in fused.children}
         assert "fused:stage" in child_names
         assert any(n.startswith("fused:dispatch") for n in child_names)

@@ -22,6 +22,8 @@ observatory" — mirroring check_metrics.py's fused-fallback taxonomy lint):
   ``rec.phase("x")`` context-manager calls, ``rec.add("x", ...)``) must be
   a member of the canonical ``metrics.QUERY_PHASES`` set — an unknown
   phase name would mint an undashboarded histogram series;
+- every ``span(..., part="x")`` literal must be a member of
+  ``metrics.STAGE_PARTS``, and every member must be booked somewhere;
 - every QueryEngine execution entry (``_query_range_uncoalesced``,
   ``query_instant``, ``execute_plan``) must capture ``parse_plan`` and
   ``admission`` exactly once;
@@ -80,18 +82,38 @@ def opens_span(fn: ast.FunctionDef) -> bool:
     return False
 
 
-def _canonical_phases() -> set[str]:
-    """metrics.QUERY_PHASES, read from the AST (no imports)."""
+def _canonical(name: str) -> set[str]:
+    """A canonical tuple of metrics.py (QUERY_PHASES, STAGE_PARTS), read
+    from the AST (no imports)."""
     out: set[str] = set()
     tree = ast.parse((PKG / "metrics.py").read_text())
     for node in ast.walk(tree):
         if (isinstance(node, ast.Assign) and node.targets
                 and isinstance(node.targets[0], ast.Name)
-                and node.targets[0].id == "QUERY_PHASES"):
+                and node.targets[0].id == name):
             for c in ast.walk(node.value):
                 if isinstance(c, ast.Constant) and isinstance(c.value, str):
                     out.add(c.value)
     return out
+
+
+def _part_literals(tree: ast.AST):
+    """(part-literal, lineno) pairs from one module: ``part=`` kwargs on
+    span() calls and ``<x>.add_part("...", ...)`` recorder bumps."""
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.Call):
+            continue
+        f = node.func
+        name = getattr(f, "attr", None) or getattr(f, "id", None)
+        if name == "span":
+            for kw in node.keywords:
+                if (kw.arg == "part" and isinstance(kw.value, ast.Constant)
+                        and isinstance(kw.value.value, str)):
+                    yield kw.value.value, node.lineno
+        elif name == "add_part" and node.args:
+            a = node.args[0]
+            if isinstance(a, ast.Constant) and isinstance(a.value, str):
+                yield a.value, node.lineno
 
 
 def _phase_literals(tree: ast.AST):
@@ -130,10 +152,15 @@ def _count_in(fn: ast.AST, want: str, kinds=("phase", "add", "span")) -> int:
 
 def phase_violations(classes: dict[str, ast.ClassDef]) -> list[str]:
     out: list[str] = []
-    canon = _canonical_phases()
+    canon = _canonical("QUERY_PHASES")
     if not canon:
         return ["phase lint: QUERY_PHASES not found in filodb_tpu/metrics.py"]
-    # (a) canonical-set rejection over the whole package
+    parts = _canonical("STAGE_PARTS")
+    if not parts:
+        return ["phase lint: STAGE_PARTS not found in filodb_tpu/metrics.py"]
+    # (a) canonical-set rejection over the whole package, phases and the
+    # parts of the stage phase alike
+    seen_parts: set[str] = set()
     for path in sorted(PKG.rglob("*.py")):
         if "__pycache__" in path.parts:
             continue
@@ -144,6 +171,18 @@ def phase_violations(classes: dict[str, ast.ClassDef]) -> list[str]:
                     f"{path}:{lineno}: unknown query phase {lit!r} — not in "
                     f"metrics.QUERY_PHASES {sorted(canon)}"
                 )
+        for lit, lineno in _part_literals(tree):
+            seen_parts.add(lit)
+            if lit not in parts:
+                out.append(
+                    f"{path}:{lineno}: unknown stage part {lit!r} — not in "
+                    f"metrics.STAGE_PARTS {sorted(parts)}"
+                )
+    for missing in sorted(parts - seen_parts):
+        out.append(
+            f"stage part {missing!r} is in metrics.STAGE_PARTS but no "
+            "span(..., part=...) books it — its series would read 0 for ever"
+        )
     # (b) engine entry coverage: parse_plan + admission exactly once each
     planner = ast.parse((PKG / "coordinator" / "planner.py").read_text())
     entries = {"_query_range_uncoalesced", "query_instant", "execute_plan"}
