@@ -306,6 +306,14 @@ def _channel(endpoint: str) -> grpc.Channel:
                 options=[
                     ("grpc.max_receive_message_length", 64 * 1024 * 1024),
                     ("grpc.max_send_message_length", 64 * 1024 * 1024),
+                    # no retry filter: exactly one layer retries (below), and
+                    # grpc-core 51's transparent retry of a stream the peer's
+                    # GOAWAY refused (a server stopping) wedges the call when
+                    # the re-attempt cannot connect — final status set, the
+                    # pending recv_message never completed, so the response
+                    # iterator outlives its timeout and ignores cancel().
+                    # Without the filter that call ends UNAVAILABLE at once.
+                    ("grpc.enable_retries", 0),
                 ],
             )
             _channels[target] = ch
@@ -392,7 +400,9 @@ def _call_stream(endpoint: str, method: str, request, serializer, auth_token,
     """unary_stream call with bounded UNAVAILABLE retries (mirrors the HTTP
     transport's retry discipline in planners.fetch_json). ``timeout_s`` is a
     TOTAL budget: retries and their per-attempt RPC deadlines all fit inside
-    it, so a hung peer cannot stall past the caller's query deadline."""
+    it. That a hung peer cannot stall past the caller's query deadline is
+    kept one level up, by faults.call_with_retries, which bounds its own wait
+    for this call; the one wedge seen here is cured in _channel's options."""
     import time as _t
 
     ch = _channel(endpoint)

@@ -693,9 +693,13 @@ class NonLeafExecPlan(ExecPlan):
                             raise self._annotate_child_error(children[i], e)
         finally:
             if pool is not None:
-                # on error: unstarted futures never run, and we do NOT block
-                # waiting for hung RPCs — in-flight calls finish on their own
-                # per-RPC deadlines (always <= the remaining query budget)
+                # on error: unstarted futures never run, and nothing here
+                # waits for the rest. Every worker comes back within the
+                # query's deadline because faults.call_with_retries bounds
+                # its own wait for each remote attempt (that is also why
+                # as_completed above needs no second clock); a call the
+                # transport never ends stays behind on a daemon thread, so
+                # these workers cannot hold the interpreter at exit
                 pool.shutdown(wait=False, cancel_futures=True)
         if failures:
             if len(failures) == len(children):

@@ -8,9 +8,12 @@ Three cooperating pieces, all consulted by
 
 - :class:`RetryPolicy` — exponential backoff + deterministic jitter for
   remote child plans. Budgets derive from ``QueryContext.deadline_s``: a
-  retry sequence never sleeps past the query deadline and every attempt's
-  RPC timeout is the *remaining* budget, so a hung peer cannot stall a
-  query beyond its deadline.
+  retry sequence never sleeps past the query deadline, and the dispatch
+  layer itself waits for each attempt no longer than the endpoint's budget
+  (:func:`_run_bounded`) — the one who waits bounds the wait, whatever the
+  transport does with its own timeout. The budget is the remaining deadline,
+  or an equal share of it while sibling replicas remain, so a silent
+  replica costs one short attempt and the sibling still has time to answer.
 - :class:`CircuitBreaker` / :class:`BreakerRegistry` — per-endpoint
   closed -> open -> half-open breaker with a failure-rate threshold over a
   sliding outcome window and a cooldown before half-open probing. State
@@ -35,10 +38,11 @@ import random
 import threading
 import time
 from collections import deque
+from concurrent import futures
 from dataclasses import dataclass
 from typing import Callable
 
-from .exec.transformers import QueryError
+from .exec.transformers import QueryDeadlineExceeded, QueryError
 
 _STATE_CLOSED = "closed"
 _STATE_OPEN = "open"
@@ -48,6 +52,16 @@ _STATE_HALF_OPEN = "half_open"
 class CircuitOpenError(QueryError):
     """Dispatch refused: the endpoint's breaker is open (fail-fast). The
     HTTP edge maps this to 503 like other unavailability."""
+
+
+class AttemptTimedOut(QueryError):
+    """The endpoint gave no sign of life in the whole budget the dispatch
+    layer had for it. That is peer-health evidence (unlike the transport's
+    own DEADLINE_EXCEEDED, which reflects whatever budget the origin had
+    left): it counts against the breaker and fails over to a sibling. Not
+    retryable — the budget it would retry in is the one it just spent."""
+
+    endpoint_failure = True
 
 
 def is_retryable(exc: BaseException) -> bool:
@@ -274,7 +288,8 @@ def dispatch_child(child, ctx):
     siblings = tuple(getattr(child, "sibling_endpoints", ()) or ())
     if siblings and hasattr(child, "with_endpoint"):
         return _dispatch_with_failover(child, ctx, base, endpoint, siblings)
-    res = call_with_retries(lambda: base(child, ctx), ctx, endpoint)
+    res = call_with_retries(lambda: base(child, ctx), ctx, endpoint,
+                            ctx.remaining_deadline_s())
     _note_endpoint(ctx, endpoint)
     return res
 
@@ -295,7 +310,11 @@ def _dispatch_with_failover(child, ctx, base, endpoint, siblings):
     replica is a ROUTING signal — re-pin the leg to the next sibling replica
     (same plan, same shard subset) before allow_partial_results is even
     considered. Non-endpoint errors (real query errors) raise immediately:
-    a sibling would answer the same way."""
+    a sibling would answer the same way.
+
+    Each candidate's budget is an equal share of what is left of the
+    deadline among the candidates not yet tried (the last one gets all of
+    it): a replica that never answers costs its share, not the query."""
     from ..metrics import record_replica_failover, record_replica_selection
 
     cands = (endpoint,) + tuple(s for s in siblings if s != endpoint)
@@ -303,7 +322,8 @@ def _dispatch_with_failover(child, ctx, base, endpoint, siblings):
     for i, ep in enumerate(cands):
         c = child if i == 0 else child.with_endpoint(ep)
         try:
-            res = call_with_retries(lambda: base(c, ctx), ctx, ep)
+            res = call_with_retries(lambda: base(c, ctx), ctx, ep,
+                                    ctx.remaining_deadline_s() / (len(cands) - i))
         except CircuitOpenError as e:
             last_exc = e
             if i + 1 < len(cands):
@@ -322,8 +342,43 @@ def _dispatch_with_failover(child, ctx, base, endpoint, siblings):
     raise last_exc
 
 
-def call_with_retries(fn, ctx, endpoint: str):
-    """Run ``fn`` with breaker consultation + budgeted backoff retries.
+def _run_bounded(fn, budget_s: float, endpoint: str):
+    """Run one attempt where it can be left behind — a daemon thread that
+    sets a Future — and wait for it no longer than ``budget_s``. A transport
+    whose call never returns (a gRPC stream wedged on a connection its peer
+    was closing ignores both its timeout and ``cancel()``) then costs the
+    caller its budget and the process nothing at exit. The thread-locals a
+    plan node reads (active span, stats, phases) are re-bound in the thread,
+    as ``execute_children`` does for its pool workers. An attempt left behind
+    that answers later still writes its stats and warnings to the context:
+    nobody reads its result, and QueryStats are best-effort across legs."""
+    from ..metrics import (
+        activate, activate_phases, activate_stats,
+        current_phases, current_span, current_stats,
+    )
+
+    fut: futures.Future = futures.Future()
+    sp, stats, phases = current_span(), current_stats(), current_phases()
+
+    def attempt():
+        try:
+            with activate(sp), activate_stats(stats), activate_phases(phases):
+                fut.set_result(fn())
+        except BaseException as e:  # noqa: BLE001 — re-raised by the waiter
+            fut.set_exception(e)
+
+    threading.Thread(target=attempt, name="filodb-attempt", daemon=True).start()
+    # not fut.result(timeout=): fn's own TimeoutError would read as ours
+    futures.wait((fut,), timeout=budget_s)
+    if not fut.done():
+        raise AttemptTimedOut(
+            f"no answer from endpoint {endpoint} within {budget_s:.1f}s")
+    return fut.result()
+
+
+def call_with_retries(fn, ctx, endpoint: str, budget_s: float):
+    """Run ``fn`` with breaker consultation + budgeted backoff retries, all
+    within ``budget_s`` from now (the endpoint's share of the deadline).
 
     Retry and breaker events annotate the active span (the dispatching merge
     node's — each ATTEMPT produces its own child span via the child's
@@ -337,6 +392,7 @@ def call_with_retries(fn, ctx, endpoint: str):
     breaker = registry.breaker_for(endpoint)
     sp = current_span()
     rng = policy.rng()
+    give_up_at = time.monotonic() + budget_s
     attempt = 0
     while True:
         ctx.check_deadline()
@@ -350,7 +406,7 @@ def call_with_retries(fn, ctx, endpoint: str):
         if state != _STATE_CLOSED and sp is not None:
             sp.tags.setdefault("breaker_state", {})[endpoint] = state
         try:
-            res = fn()
+            res = _run_bounded(fn, give_up_at - time.monotonic(), endpoint)
         except Exception as e:  # noqa: BLE001 — classified below
             if is_endpoint_failure(e):
                 breaker.record_failure()
@@ -358,6 +414,11 @@ def call_with_retries(fn, ctx, endpoint: str):
                 # typed query error: the peer answered — release any
                 # half-open probe slot without a state transition
                 breaker.record_neutral()
+            if isinstance(e, AttemptTimedOut) and ctx.remaining_deadline_s() <= 0:
+                # the silent endpoint had the whole deadline: that is the
+                # query's timeout, never a lost child a partial may absorb
+                raise QueryDeadlineExceeded(
+                    f"query exceeded deadline: {e}") from e
             if not is_retryable(e):
                 raise
             attempt += 1
@@ -369,10 +430,9 @@ def call_with_retries(fn, ctx, endpoint: str):
                 # into a CircuitOpenError that would mask it
                 raise
             backoff = policy.backoff_s(attempt - 1, rng)
-            remaining = ctx.remaining_deadline_s()
-            if backoff >= remaining:
-                # sleeping would outlive the query deadline: surface the
-                # last transport error now instead of burning the budget
+            if backoff >= give_up_at - time.monotonic():
+                # sleeping would outlive the endpoint's budget: surface the
+                # last transport error now instead of burning it
                 raise
             record_remote_retry(endpoint)
             if sp is not None:

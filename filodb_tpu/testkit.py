@@ -232,7 +232,7 @@ class FaultInjector:
 
 def grpc_cluster(batch=None, n_shards: int = 4, owned=(0, 1),
                  dataset: str = "prometheus", spread: int = 2,
-                 deadline_s: float = 120.0, **params_kw):
+                 deadline_s: float = 30.0, **params_kw):
     """Two-node in-process cluster over the gRPC plan transport: a parent
     engine owning ``owned`` shards that scatters every selector to a peer
     engine owning the rest (the distributed scatter-gather path, without
@@ -319,7 +319,7 @@ class ReplicaCluster:
 
 def replica_cluster(batch=None, n_shards: int = 4, num_nodes: int = 2,
                     num_replicas: int = 2, dataset: str = "prometheus",
-                    spread: int = 2, deadline_s: float = 120.0,
+                    spread: int = 2, deadline_s: float = 30.0,
                     standing: bool = False, retry_policy=None,
                     **params_kw) -> ReplicaCluster:
     """In-process replicated cluster: ``num_nodes`` data nodes behind a

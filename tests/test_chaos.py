@@ -9,6 +9,10 @@ same outcomes, so these run inside tier-1.
 from __future__ import annotations
 
 import json
+import os
+import subprocess
+import sys
+import threading
 import time
 import urllib.parse
 import urllib.request
@@ -20,7 +24,7 @@ from filodb_tpu.coordinator.cluster import ShardManager, ShardStatus
 from filodb_tpu.coordinator.planner import PlannerParams, QueryEngine
 from filodb_tpu.core.schemas import Dataset
 from filodb_tpu.memstore.memstore import TimeSeriesMemStore
-from filodb_tpu.query.exec.plans import ExecPlan, QueryContext
+from filodb_tpu.query.exec.plans import DistConcatExec, ExecPlan, QueryContext
 from filodb_tpu.query.exec.transformers import QueryDeadlineExceeded
 from filodb_tpu.query.faults import (
     BreakerRegistry,
@@ -72,6 +76,45 @@ class FlakyRemoteExec(ExecPlan):
         if self.always_fail or (self.fail_times is not None and n < self.fail_times):
             raise InjectedFault(f"flaky {self.endpoint} call {n}")
         return QueryResult()
+
+
+class SilentRemoteExec(ExecPlan):
+    """Remote leaf whose transport never returns on the ``silent`` endpoint:
+    ``execute`` blocks on an Event nobody sets, as a gRPC stream wedged on a
+    connection its server was closing does (timeout and cancel() ignored).
+    On any other endpoint it answers one raw series."""
+
+    is_remote = True
+
+    def __init__(self, endpoint: str, silent: str, sibling_endpoints=()):
+        super().__init__()
+        self.endpoint = endpoint
+        self.silent = silent
+        self.sibling_endpoints = tuple(sibling_endpoints)
+
+    def with_endpoint(self, endpoint: str) -> "SilentRemoteExec":
+        return SilentRemoteExec(endpoint, self.silent)
+
+    def args_str(self) -> str:
+        return f"endpoint={self.endpoint}"
+
+    def do_execute(self, ctx):
+        if self.endpoint == self.silent:
+            threading.Event().wait()
+        res = QueryResult()
+        res.raw = [({"job": "replicated"}, np.arange(4), np.arange(4) / 3.0)]
+        return res
+
+
+def silent_gather(silent: str, siblings=(), deadline_s: float = 1.0, **ctx_kw):
+    """Gather over a replicated leg pinned to ``grpc://a:1`` and a healthy
+    remote one; returns (context, merge node)."""
+    ctx = make_ctx(deadline_s=deadline_s, **ctx_kw)
+    plan = DistConcatExec([
+        SilentRemoteExec("grpc://a:1", silent, sibling_endpoints=siblings),
+        FlakyRemoteExec("grpc://healthy:1"),
+    ])
+    return ctx, plan
 
 
 def make_engine(dispatcher=None, **params):
@@ -497,6 +540,73 @@ class TestQueryDeadline:
         _, eng = make_engine(dispatcher=DeadlineBurner(), deadline_s=30)
         with pytest.raises(QueryDeadlineExceeded):
             eng.query_range(Q, S, E, 60, allow_partial_results=True)
+
+    @pytest.mark.parametrize("allow_partial", [False, True])
+    def test_silent_leg_without_sibling_ends_at_the_deadline(self, allow_partial):
+        """The one who waits bounds the wait: a remote leg whose transport
+        never returns costs the query its deadline and no more, is named,
+        and with the budget spent is an error even where a partial result
+        would be allowed."""
+        ctx, plan = silent_gather(
+            "grpc://a:1", breakers=BreakerRegistry(min_calls=1),
+            allow_partial_results=allow_partial)
+        t0 = time.monotonic()
+        with pytest.raises(QueryDeadlineExceeded,
+                           match=r"child SilentRemoteExec\(endpoint=grpc://a:1\)"):
+            plan.execute(ctx)
+        assert time.monotonic() - t0 < 1.0 + 2.0
+        assert not ctx.warnings
+        assert ctx.breakers.states() == {"grpc://a:1": "open",
+                                         "grpc://healthy:1": "closed"}
+
+    def test_silent_replica_costs_its_share_then_the_sibling_answers(self):
+        from filodb_tpu.metrics import REGISTRY
+
+        def failovers():
+            return sum(v for k, v in REGISTRY.counter_samples(
+                "filodb_replica_failovers").items()
+                if "endpoint_failure" in k and "grpc://a:1" in k)
+
+        healthy_ctx, healthy = silent_gather(None, siblings=("grpc://b:1",))
+        want = healthy.execute(healthy_ctx).raw
+        ctx, plan = silent_gather("grpc://a:1", siblings=("grpc://b:1",),
+                                  deadline_s=2.0,
+                                  breakers=BreakerRegistry(min_calls=1))
+        fo0 = failovers()
+        t0 = time.monotonic()
+        got = plan.execute(ctx).raw
+        elapsed = time.monotonic() - t0
+        # one share of two: half the deadline, and the sibling had the rest
+        assert 1.0 <= elapsed < 2.0
+        assert len(got) == len(want) == 1
+        for (gl, gt, gv), (wl, wt, wv) in zip(got, want):
+            assert gl == wl
+            assert gt.tobytes() == wt.tobytes() and gv.tobytes() == wv.tobytes()
+        assert failovers() == fo0 + 1
+        assert ctx.breakers.states()["grpc://a:1"] == "open"
+        assert ctx.breakers.states()["grpc://b:1"] == "closed"
+        assert ctx.obs["endpoints"] == ["grpc://healthy:1", "grpc://b:1"]
+
+    def test_process_that_left_a_silent_leg_behind_still_exits(self):
+        """The gather's pool workers come back once the attempt is bounded,
+        and the thread left on the silent call is a daemon: the interpreter
+        exits by itself after main returns."""
+        code = (
+            "import sys\n"
+            "sys.path.insert(0, 'tests')\n"
+            "import test_chaos as t\n"
+            "ctx, plan = t.silent_gather('grpc://a:1')\n"
+            "try:\n"
+            "    plan.execute(ctx)\n"
+            "except t.QueryDeadlineExceeded:\n"
+            "    print('MAIN_RETURNS', flush=True)\n"
+        )
+        tests_dir = os.path.dirname(os.path.abspath(__file__))
+        proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                              text=True, timeout=60,
+                              cwd=os.path.dirname(tests_dir))
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert "MAIN_RETURNS" in proc.stdout
 
 
 class TestPartialOverHttp:

@@ -21,6 +21,7 @@ from __future__ import annotations
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -413,7 +414,10 @@ class TestPrewarm:
         sched = DispatchScheduler(window_ms=5, prewarm_min_count=3)
         engine = QueryEngine(store, "ds", PlannerParams(
             batch_window_ms=5, dispatch_scheduler=sched))
-        # a grid shape nothing else in the suite compiles: 15 steps
+        # "not yet compiled" is made true, not hoped for: step counts pad to
+        # 64, so whether this shape was new depended on which files ran in
+        # the process before (after tests/test_engine.py it was not)
+        jax.clear_caches()
         end_s = START + 840
         q = "sum by (job) (rate(http_requests_total[6m]))"
         desc = {"promql": q, "step_ms": 60_000, "span_ms": 840_000,

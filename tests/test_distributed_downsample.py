@@ -146,7 +146,7 @@ def test_kill_and_resume_two_processes(tmp_path):
         f"run_worker({str(tmp_path)!r}, 'ds', range(4), (300000,), "
         "worker_id='victim')\n"
     )
-    p = subprocess.run([sys.executable, "-c", code], env=env, timeout=300,
+    p = subprocess.run([sys.executable, "-c", code], env=env,
                        capture_output=True, text=True)
     assert p.returncode == 17, p.stderr[-500:]
     job = _job_dir(str(tmp_path), "ds", "default")

@@ -309,7 +309,7 @@ class TestTwoServerGrpcScatter:
         from filodb_tpu.server import FiloServer
 
         base = {"dataset": "prometheus", "shards": 8, "grpc_port": 0,
-                "query": {"timeout_s": 300}}
+                "query": {"timeout_s": 30}}
         a = FiloServer({**base, "distributed": {"owned_shards": [0, 1, 2, 3]}})
         b = FiloServer({**base, "distributed": {"owned_shards": [4, 5, 6, 7]}})
         try:
@@ -320,7 +320,7 @@ class TestTwoServerGrpcScatter:
             for srv in (a, b):
                 srv.local_engine = QueryEngine(
                     srv.memstore, srv.dataset,
-                    PlannerParams(num_shards=8, deadline_s=300),
+                    PlannerParams(num_shards=8, deadline_s=30),
                 )
                 srv._grpc = None  # replaced below with local_engine wired in
             # restart grpc servers with local engines (ports were ephemeral)

@@ -467,7 +467,7 @@ def test_crash_mid_commit_then_redo_recovers(tmp_path):
         f"run_worker({str(tmp_path)!r}, 'ds', range(2), (300000,), "
         "worker_id='victim')\n"
     )
-    p = subprocess.run([sys.executable, "-c", code], env=env, timeout=300,
+    p = subprocess.run([sys.executable, "-c", code], env=env,
                        capture_output=True, text=True)
     assert p.returncode == 19, p.stderr[-500:]
     job = _job_dir(str(tmp_path), "ds", "default")

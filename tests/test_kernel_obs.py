@@ -152,9 +152,11 @@ class TestExecutableRegistry:
         assert "evict_fam0" not in by_fam  # the stale one paid
 
     def test_registered_jits_report_cache_sizes(self):
+        # the unfused aggregate dispatches the scalar wrapper here, so the
+        # cache size does not rest on what ran in this process before
+        _ms, eng = _make_engine(fused_aggregate=False)
+        eng.query_range(Q, START_S, END_S, 60)
         jits = KERNELS.registered_jits()
-        # the fused scalar wrappers registered at import and have compiled
-        # at least once by now (the engine tests above dispatched them)
         assert "ops.aggregations._segment_aggregate_jit" in jits
         assert jits["ops.aggregations._segment_aggregate_jit"]["cache_size"] >= 1
         assert any(k.startswith("ops.kernels.") for k in jits)
@@ -415,7 +417,7 @@ class TestAttestation:
             [sys.executable, os.path.join(REPO, "tools", "attest.py"),
              "--floor-file", str(floor_file), "--no-multichip",
              "--backend", "cpu", "--out", str(out)],
-            capture_output=True, text=True, cwd=REPO, timeout=420,
+            capture_output=True, text=True, cwd=REPO,
         )
         assert proc.returncode == 0, proc.stdout + proc.stderr
         doc = json.loads(out.read_text())

@@ -27,7 +27,7 @@ SERVER = textwrap.dedent("""
     srv = FiloServer({
         "dataset": "prometheus", "shards": 4,
         "store_root": sys.argv[1],
-        "query": {"timeout_s": 300},
+        "query": {"timeout_s": 30},
     })
     port = srv.start(port=0)
     print(f"PORT={port}", flush=True)
@@ -48,7 +48,7 @@ def _start(store):
     # the whole suite on readline forever
     sel = selectors.DefaultSelector()
     sel.register(proc.stdout, selectors.EVENT_READ)
-    deadline = time.time() + 120
+    deadline = time.time() + 60
     buf = ""
     while time.time() < deadline:
         if proc.poll() is not None:
@@ -61,17 +61,17 @@ def _start(store):
             sel.close()
             return proc, int(line.strip().split("=")[1])
     proc.kill()
-    raise TimeoutError(f"server did not start within 120s: {buf[-2000:]}")
+    raise TimeoutError(f"server did not start within 60s: {buf[-2000:]}")
 
 
 def _get(url):
-    with urllib.request.urlopen(url, timeout=120) as r:
+    with urllib.request.urlopen(url) as r:
         return json.loads(r.read())
 
 
 def _post(url, body=b""):
     req = urllib.request.Request(url, data=body, method="POST")
-    with urllib.request.urlopen(req, timeout=120) as r:
+    with urllib.request.urlopen(req) as r:
         return json.loads(r.read())
 
 

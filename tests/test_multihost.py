@@ -79,12 +79,11 @@ def test_two_process_psum():
     outs = []
     try:
         for p in procs:
-            out, _ = p.communicate(timeout=180)
+            out, _ = p.communicate()
             outs.append((p.returncode, out))
-    except subprocess.TimeoutExpired:
-        for p in procs:
+    finally:
+        for p in procs:  # neither outlives the test, whatever ended it
             p.kill()
-        pytest.skip("distributed coordination service timed out in this sandbox")
     for rc, out in outs:
         if rc != 0 and ("UNAVAILABLE" in out or "Failed to connect" in out or "barrier" in out.lower()):
             pytest.skip(f"sandbox blocks the coordination service: {out[-300:]}")
@@ -113,7 +112,7 @@ class TestMultiHostServing:
         from filodb_tpu.server import FiloServer
         from filodb_tpu.testkit import counter_batch
 
-        base_cfg = {"dataset": "prometheus", "shards": 8, "query": {"timeout_s": 300}}
+        base_cfg = {"dataset": "prometheus", "shards": 8, "query": {"timeout_s": 30}}
         a = FiloServer({**base_cfg, "distributed": {"owned_shards": [0, 1, 2, 3]}})
         b = FiloServer({**base_cfg, "distributed": {"owned_shards": [4, 5, 6, 7]}})
         pa = a.start(port=0)
@@ -130,7 +129,7 @@ class TestMultiHostServing:
         for srv in (a, b):
             srv.local_engine = QueryEngine(
                 srv.memstore, srv.dataset,
-                PlannerParams(num_shards=8, deadline_s=300),
+                PlannerParams(num_shards=8, deadline_s=30),
             )
             srv._http.RequestHandlerClass.local_engine = srv.local_engine
         batch = counter_batch(n_series=24, n_samples=120, start_ms=1_600_000_000_000)
@@ -174,7 +173,7 @@ class TestMultiHostServing:
             q = urllib.parse.quote("sum(rate(http_requests_total[5m]))")
             url = (f"http://127.0.0.1:{pa}/api/v1/query_range?query={q}"
                    f"&start={start_s}&end={end_s}&step=60")
-            with urllib.request.urlopen(url, timeout=300) as r:
+            with urllib.request.urlopen(url) as r:
                 out = _json.loads(r.read())
             assert out["status"] == "success"
             vals = out["data"]["result"][0]["values"]
@@ -185,7 +184,7 @@ class TestMultiHostServing:
             q2 = urllib.parse.quote("http_requests_total")
             url2 = (f"http://127.0.0.1:{pb}/api/v1/query_range?query={q2}"
                     f"&start={start_s}&end={end_s}&step=60")
-            with urllib.request.urlopen(url2, timeout=300) as r:
+            with urllib.request.urlopen(url2) as r:
                 out2 = _json.loads(r.read())
             assert len(out2["data"]["result"]) == 24
         finally:
@@ -208,13 +207,13 @@ class TestMultiHostMetadataAndPushdown:
             a, b, pa, pb, na, nb = pair._start_pair()
             # label values scatter: host A must see instances living on B
             url = f"http://127.0.0.1:{pa}/api/v1/label/instance/values"
-            with urllib.request.urlopen(url, timeout=300) as r:
+            with urllib.request.urlopen(url) as r:
                 vals = _json.loads(r.read())["data"]
             assert len(vals) == 24  # every series' instance, both hosts
             # series scatter
             m = urllib.parse.quote("http_requests_total")
             url2 = f"http://127.0.0.1:{pb}/api/v1/series?match[]={m}"
-            with urllib.request.urlopen(url2, timeout=300) as r:
+            with urllib.request.urlopen(url2) as r:
                 series = _json.loads(r.read())["data"]
             assert len(series) == 24
 

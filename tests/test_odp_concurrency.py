@@ -92,7 +92,7 @@ class TestIngestQueryConcurrency:
         for t in threads:
             t.start()
         for t in threads:
-            t.join(timeout=120)
+            t.join()
         stop.set()
         assert not errors, errors[:3]
 
@@ -124,7 +124,7 @@ class TestIngestQueryConcurrency:
         for t in threads:
             t.start()
         for t in threads:
-            t.join(timeout=120)
+            t.join()
         assert not errors, errors[:3]
 
 
@@ -164,5 +164,5 @@ def test_concurrent_flush_and_query(tmp_path):
     for t in threads:
         t.start()
     for t in threads:
-        t.join(timeout=120)
+        t.join()
     assert not errors, errors[:3]

@@ -150,7 +150,7 @@ class TestTwoServerPartials:
         from filodb_tpu.server import FiloServer
 
         base = {"dataset": "prometheus", "shards": 8, "grpc_port": 0,
-                "query": {"timeout_s": 300}}
+                "query": {"timeout_s": 30}}
         a = FiloServer({**base, "distributed": {"owned_shards": [0, 1, 2, 3]}})
         b = FiloServer({**base, "distributed": {"owned_shards": [4, 5, 6, 7]}})
         a.start(port=0)
@@ -158,7 +158,7 @@ class TestTwoServerPartials:
         for srv in (a, b):
             srv.local_engine = QueryEngine(
                 srv.memstore, srv.dataset,
-                PlannerParams(num_shards=8, deadline_s=300),
+                PlannerParams(num_shards=8, deadline_s=30),
             )
         ga, pa = serve_grpc(a.engine, port=0, host="127.0.0.1",
                             local_engine=a.local_engine)

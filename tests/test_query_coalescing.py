@@ -93,7 +93,7 @@ def engine():
         counter_batch(n_series=32, n_samples=120, start_ms=START),
         spread=2,
     )
-    return QueryEngine(ms, "prometheus", PlannerParams(deadline_s=120))
+    return QueryEngine(ms, "prometheus", PlannerParams(deadline_s=30))
 
 
 def test_engine_coalesces_identical_queries(engine, monkeypatch):
