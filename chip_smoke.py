@@ -48,6 +48,11 @@ WIDE_SAMPLES = 5200
 WIDE_STEP_S = 600
 WIDE_STEPS = 76
 RTOL = 5e-3  # what bench.py's oracles use; max rel err is printed to tighten
+# avg(avg_over_time) sums 1e9-sized values over every series: held to the
+# limit of the benchmark cell counters.repeat (its avg_avg_over_time panel;
+# tests/chip_benchmark/test_counters_cell.py keeps the two equal). RTOL let
+# the f32 segment sum's 1.06e-3 pass for seven PRs.
+WIDE_SUM_RTOL = 1.5e-6
 # sharded vs single-device: the same f32 sums in another order. The 2e-5
 # __graft_entry__ asserts is for its 32 series; at 131k series the two
 # orders measured 1.16e-5 apart on four v5e chips, 2.24e-5 after the
@@ -672,10 +677,10 @@ class Smoke:
         out_t, t0 = self.out_t, self.t0
         m = METRICS
 
-        def one(want, what):
+        def one(want, what, rtol=RTOL):
             def chk(rows):
                 check(len(rows) == 1, f"{what}: {len(rows)} result series")
-                return max_rel_err(rows[0][1], want, what)
+                return max_rel_err(rows[0][1], want, what, rtol=rtol)
             return chk
 
         self.say("queries (each: cold once, warm x%d, vs the numpy f64 oracle, "
@@ -702,7 +707,7 @@ class Smoke:
 
         q = f"avg(avg_over_time({m['main']}[5m]))"
         s_, n_ = nansum0(o_avg_over_time(main, out_t, t0))
-        self.run_query(q, one(s_ / n_, q), fused_variant="mxu")
+        self.run_query(q, one(s_ / n_, q, rtol=WIDE_SUM_RTOL), fused_variant="mxu")
 
         q = f"sum(irate({m['main']}[5m]))"
         self.run_query(q, one(nansum0(o_irate(main, out_t, t0))[0], q),

@@ -120,6 +120,7 @@ HELP_TEXTS: dict[str, str] = {
     "filodb_partial_results": "Queries answered with merged partials (children lost).",
     "filodb_shard_reassignments": "Shard reassignment outcomes from ingestion errors.",
     "filodb_fused_fallback": "Fused single-dispatch aggregates delegated to the reference tree, by reason.",
+    "filodb_group_reduce": "Fused scalar dispatches by the form of their cross-series reduction (wide = exact int32 pieces, plain = f32 segment reduce).",
     "filodb_stage_cache_insert_dropped": "Staged blocks not cached because ingest effects touched their range.",
     "filodb_superblock_maintenance": "Version-stale superblock maintenance outcomes (revalidate|extend|extend_abort|restage).",
     "filodb_downsample_claims": "Distributed-downsample claim lifecycle events.",
@@ -510,7 +511,14 @@ _trace_local = threading.local()
 
 
 def new_trace_id() -> str:
-    return "%016x" % random.getrandbits(64)
+    """Sixteen hex digits, at least one of them a letter that no number
+    holds: the profiler parses an annotation's ``trace_id`` stat, and an id
+    of decimal digits comes back as an int, ``12345e6789012345`` as inf —
+    the spans of that request then join nothing on the trace (1 id in 800)."""
+    while True:
+        i = "%016x" % random.getrandbits(64)
+        if i.strip("0123456789e"):
+            return i
 
 
 # span ids need to be distinct within a trace, not unguessable: 64 random

@@ -44,11 +44,128 @@ def segment_aggregate(op: str, values, group_ids, num_groups: int):
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("op", "num_groups"))
+# -- the wide sum -------------------------------------------------------------
+#
+# jax.ops.segment_sum adds a group's rows one after another in f32. Where the
+# rows are the samples themselves (a value-returning range function over
+# 100 000 counters that all read about 1e9), the running sum soon has an ulp
+# far above the low bits every addend shares, each add rounds the same way,
+# and the error grows with the rows: 1.06e-3 of the answer at 100 000 series
+# on a v5e (PERF.md 6, PR 28). The wide sum splits each value on a grid the
+# whole step shares into WIDE_PIECES signed integers of WIDE_PIECE_BITS bits
+# and a small f32 remainder. Sums of such integers are exact in int32
+# whatever the order (and the same on any mesh or batch), so only the
+# remainder and ONE final rounding are left of f32.
+
+# range functions that return sample values, or window sums of them
+WIDE_SUM_FUNCS = frozenset({
+    "last", "last_over_time", "first_over_time", "avg_over_time",
+    "sum_over_time", "min_over_time", "max_over_time",
+})
+WIDE_PIECE_BITS = 7  # |piece| <= 64: an int8, and 2**24 rows fit an int32
+WIDE_PIECES = 4      # 27 bits below the step's largest value; the rest is f32
+# at most this many groups (trash group included) sum as a one-hot matmul on
+# the MXU; more would make the [G, S] one-hot the larger operand
+WIDE_ONEHOT_MAX_GROUPS = 128
+
+
+def reduce_form(func: str, epilogue: tuple, num_groups: int) -> str:
+    """``"wide"`` or ``"plain"`` for one fused dispatch. Wide: a sum / avg
+    over series of a value-returning range function, whatever the number of
+    groups (the digits need it); and, on a device with an MXU, every other
+    sum / avg of up to WIDE_ONEHOT_MAX_GROUPS groups, because there the wide
+    sum's int8 one-hot matmul is also the faster reduce (0.94 ms against
+    the segment_sum's 2.38 over 131072 rows on a v5e: PERF.md 6, PR 28).
+    min / max / count / topk / quantile accumulate nothing: plain."""
+    if epilogue[:1] != ("agg",) or epilogue[1] not in ("sum", "avg"):
+        return "plain"
+    if func in WIDE_SUM_FUNCS:
+        return "wide"
+    few = num_groups + 1 <= WIDE_ONEHOT_MAX_GROUPS  # the trash group rides along
+    return "wide" if few and _has_mxu() else "plain"
+
+
+def _has_mxu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _with_reduce_form(func: str, epilogue: tuple, num_groups: int) -> tuple:
+    """The epilogue statics with the reduction's form made explicit:
+    ``("agg", op)`` -> ``("agg", op, "wide")`` where reduce_form says so. The
+    form is part of the executable's identity (a static jit argument and
+    the kernel observatory's ``epilogue`` key, ``agg:avg:wide``). Every
+    fused dispatch counts its form: filodb_group_reduce_total{form}."""
+    from ..metrics import REGISTRY
+
+    form = reduce_form(func, epilogue, num_groups)
+    REGISTRY.counter("filodb_group_reduce", form=form).inc()
+    return epilogue + ("wide",) if form == "wide" else epilogue
+
+
+@jax.named_scope("wide_sum")
+def _wide_aggregate(op: str, v0, valid, gids, num_groups: int,
+                    axis: str | None = None):
+    """[G, J] ``sum`` or ``avg`` per group of ``v0`` [S, J] (absent = 0,
+    flagged by ``valid``; a group with no member at a step yields NaN), the
+    sum as exact as one f32 rounding. With ``axis`` the rows are one
+    device's band of a series-sharded grid and the integer sums combine by
+    psum, still exactly."""
+    f32, i8 = jnp.float32, jnp.int8
+    J = v0.shape[1]
+    m = jnp.max(jnp.abs(v0), axis=0)  # [J]: the step's largest magnitude
+    if axis is not None:
+        m = jax.lax.pmax(m, axis)
+    # a step that holds an infinity has no digits to keep: it goes through
+    # the flags below, and its finite part through the remainder alone
+    step_ok = jnp.isfinite(m)
+    _, e = jnp.frexp(jnp.where(step_ok, m, 0.0))  # |v| < 2**e
+    # (every scale below stays a normal f32)
+    e = jnp.clip(e + 1, WIDE_PIECE_BITS * WIDE_PIECES - 126, 127)
+    step_ok = step_ok[None, :]
+    r = jnp.where(step_ok, v0, 0.0)
+    lanes, scales = [], []
+    for k in range(1, WIDE_PIECES + 1):
+        up = jnp.ldexp(f32(1.0), WIDE_PIECE_BITS * k - e)
+        dn = jnp.ldexp(f32(1.0), e - WIDE_PIECE_BITS * k)
+        p = jnp.round(r * up[None, :])  # |p| <= 64, every product exact
+        r = r - p * dn[None, :]
+        lanes.append(p.astype(i8))
+        scales.append(dn)
+    r = jnp.where(step_ok, r, jnp.where(jnp.isfinite(v0), v0, 0.0))
+    lanes += [valid.astype(i8), (v0 == jnp.inf).astype(i8),
+              (v0 == -jnp.inf).astype(i8)]
+    ints = jnp.concatenate(lanes, axis=1)  # [S, 7J] int8
+    if num_groups <= WIDE_ONEHOT_MAX_GROUPS:
+        member = gids[None, :] == jnp.arange(num_groups, dtype=gids.dtype)[:, None]
+        isum = jax.lax.dot(member.astype(i8), ints,
+                           preferred_element_type=jnp.int32)
+        rsum = jax.lax.dot(member.astype(f32), r,
+                           precision=jax.lax.Precision.HIGHEST)
+    else:
+        isum = jax.ops.segment_sum(ints.astype(jnp.int32), gids, num_groups)
+        rsum = jax.ops.segment_sum(r, gids, num_groups)
+    if axis is not None:
+        isum, rsum = jax.lax.psum(isum, axis), jax.lax.psum(rsum, axis)
+    total = rsum
+    for k in reversed(range(WIDE_PIECES)):  # smallest first: one rounding counts
+        total = total + isum[:, k * J:(k + 1) * J].astype(f32) * scales[k][None, :]
+    count, pinf, ninf = (isum[:, k * J:(k + 1) * J]
+                         for k in range(WIDE_PIECES, WIDE_PIECES + 3))
+    total = jnp.where(pinf > 0, jnp.where(ninf > 0, jnp.nan, jnp.inf),
+                      jnp.where(ninf > 0, -jnp.inf, total))
+    if op == "avg":
+        total = total / jnp.maximum(count, 1).astype(f32)
+    return jnp.where(count > 0, total, jnp.nan)
+
+
+@functools.partial(jax.jit, static_argnames=("op", "num_groups", "wide"))
 @jax.named_scope("group_reduce")
-def _segment_aggregate_jit(op: str, values, group_ids, num_groups: int):
+def _segment_aggregate_jit(op: str, values, group_ids, num_groups: int,
+                           wide: bool = False):
     valid = ~jnp.isnan(values)
     v0 = jnp.where(valid, values, 0.0)
+    if wide and op in ("sum", "avg"):
+        return _wide_aggregate(op, v0, valid, group_ids, num_groups)
     count = jax.ops.segment_sum(valid.astype(values.dtype), group_ids, num_groups)
     has = count > 0
     if op == "count":
@@ -78,16 +195,20 @@ def _segment_aggregate_jit(op: str, values, group_ids, num_groups: int):
 
 
 @jax.named_scope("group_reduce")
-def _segment_psum_axis(op: str, grid, gids, num_groups: int, axis: str):
+def _segment_psum_axis(op: str, grid, gids, num_groups: int, axis: str,
+                       wide: bool = False):
     """Local segment-reduce + collective combine over a mesh axis: the
     device-local half of ``segment_aggregate`` followed by psum/pmin/pmax,
     so a series-sharded [S_local, J] grid reduces to the REPLICATED [G, J]
     partials inside one program. Semantics mirror _segment_aggregate_jit
-    exactly (NaN = absence; a group with no members anywhere yields NaN).
+    exactly (NaN = absence; a group with no members anywhere yields NaN;
+    ``wide`` sums exactly, see _wide_aggregate).
     The ONE definition shared by the sharded fused path and the parallel/
     mesh engines (parallel.mesh._segment_psum delegates here)."""
     valid = ~jnp.isnan(grid)
     v0 = jnp.where(valid, grid, 0.0)
+    if wide and op in ("sum", "avg"):
+        return _wide_aggregate(op, v0, valid, gids, num_groups, axis)
     psum = jax.lax.psum
     c = psum(
         jax.ops.segment_sum(valid.astype(jnp.float32), gids, num_groups), axis
@@ -256,7 +377,8 @@ def _apply_epilogue(sj, epilogue: tuple, gids, n_real, qv, num_groups: int):
     """Device-side epilogue over the [S, J] range grid, INSIDE the same
     compiled program as the range kernel. ``epilogue`` is a static tuple:
 
-      ("agg", op)          -> [G, J] segment aggregate
+      ("agg", op)          -> [G, J] segment aggregate; ("agg", op, "wide")
+                              sums exactly (_with_reduce_form decides)
       ("topk", k, bottom)  -> ([k, J] values, [k, J] i32 series indices):
                               per-step top/bottom-k across series, the
                               compact form of ``topk_mask`` — only O(k*J)
@@ -271,7 +393,9 @@ def _apply_epilogue(sj, epilogue: tuple, gids, n_real, qv, num_groups: int):
     otherwise happily select)."""
     kind = epilogue[0]
     if kind == "agg":
-        return _segment_aggregate_jit(epilogue[1], sj, gids, num_groups + 1)[:num_groups]
+        return _segment_aggregate_jit(
+            epilogue[1], sj, gids, num_groups + 1, wide="wide" in epilogue[2:],
+        )[:num_groups]
     S, J = sj.shape
     rows = jax.lax.broadcasted_iota(jnp.int32, (S, J), 0)
     sj = jnp.where(rows < n_real, sj, jnp.nan)
@@ -457,7 +581,8 @@ def _sharded_epilogue(sj, epilogue: tuple, gids_l, n_real, qv,
     kind = epilogue[0]
     if kind == "agg":
         return _segment_psum_axis(
-            epilogue[1], sj, gids_l, num_groups + 1, axis
+            epilogue[1], sj, gids_l, num_groups + 1, axis,
+            wide="wide" in epilogue[2:],
         )[:num_groups]
     S_l, J = sj.shape
     d = jax.lax.axis_index(axis)
@@ -739,6 +864,7 @@ def _fused_dispatch(func: str, epilogue: tuple, block, gids_padded,
     from .kernels import pad_steps
 
     j_pad = pad_steps(params.num_steps)
+    epilogue = _with_reduce_form(func, epilogue, num_groups)
     raw = block.raw if block.raw is not None else block.vals
     n_real = np.int32(block.n_series)
     start_off = int(params.start_ms - block.base_ms)
@@ -1636,6 +1762,7 @@ def fused_batched_scalar(func: str, epilogue: tuple, block, lanes,
         from ..metrics import record_fused_fallback
 
         record_fused_fallback(_reason)
+    epilogue = _with_reduce_form(func, epilogue, num_groups)
     st = _batched_stacks(block, lanes, j_pad, variant, False, mesh)
     padded = _pad_lanes(lanes)
     u_idx, _ukeys = _unique_windows(padded, block.base_ms)

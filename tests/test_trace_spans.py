@@ -183,6 +183,21 @@ def test_span_ids_are_sixteen_hex_and_distinct():
     assert all(len(i) == 16 and int(i, 16) >= 0 for i in ids)
 
 
+def test_no_span_id_reads_as_a_number(monkeypatch):
+    """The profiler parses an annotation's stats: an id of decimal digits
+    came back as an int and ``..e..`` as a float, and the by-name checks of
+    the trace below failed on the one run in 400 that drew such an id for
+    the follower (five cases at once: the names a follower has events of)."""
+    draws = iter([0x4152310936287155, 0x12345e6789012345, 0x00000000000000e5,
+                  0xabcdef0123456789])
+    monkeypatch.setattr(metrics.random, "getrandbits", lambda _n: next(draws))
+    assert metrics.new_trace_id() == "abcdef0123456789"
+    monkeypatch.undo()
+    for i in (metrics.new_span_id() for _ in range(20000)):
+        with pytest.raises(ValueError):
+            float(i)
+
+
 def test_check_spans_lints_part_literals():
     spec = importlib.util.spec_from_file_location(
         "check_spans", os.path.join(ROOT, "tools", "check_spans.py"))
@@ -424,9 +439,9 @@ def _hist_shared_args():
             np.float32(0.9), 2, False, True)
 
 
-def _mxu_call(monkeypatch):
+def _mxu_call(monkeypatch, func="rate", op="sum"):
     """(jit, args) of the ``_fused_mxu_jit`` dispatch that
-    ``sum(rate())`` over a regular grid makes."""
+    ``<op>(<func>())`` over a regular grid makes."""
     from filodb_tpu.ops import staging as ST
     from filodb_tpu.ops.kernels import RangeParams
 
@@ -434,7 +449,7 @@ def _mxu_call(monkeypatch):
     ts = BASE + np.arange(64, dtype=np.int64) * 10_000
     series = [(ts, np.cumsum(rng.uniform(0, 10, 64))) for _ in range(8)]
     block = ST.stage_series(series, BASE, [(0, i) for i in range(8)],
-                            counter_corrected=True)
+                            counter_corrected=func == "rate")
     seen = {}
     real = AGG._fused_mxu_jit
 
@@ -448,9 +463,17 @@ def _mxu_call(monkeypatch):
     monkeypatch.setattr(AGG, "_fused_mxu_jit", Recorder())
     gids = (np.arange(block.ts.shape[0]) % 2).astype(np.int32)
     AGG.fused_range_aggregate(
-        "rate", "sum", block, jnp.asarray(gids), 2,
+        func, op, block, jnp.asarray(gids), 2,
         RangeParams(BASE + 300_000, 60_000, 5, 300_000), is_counter=True)
     return real, seen["args"]
+
+
+def _trace_join():
+    spec = importlib.util.spec_from_file_location(
+        "trace_join", os.path.join(ROOT, "tools", "trace_join.py"))
+    tj = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tj)
+    return tj
 
 
 @pytest.fixture
@@ -483,6 +506,21 @@ def test_a_scope_changes_metadata_only(program):
     if want is not None:  # another jax lowers to other text: nothing to hold
         got = hashlib.sha256(lowered.as_text().encode()).hexdigest()
         assert got == want[name]
+
+
+def test_the_wide_sum_has_a_scope_of_its_own_inside_group_reduce(monkeypatch):
+    """``avg(avg_over_time())`` takes the wide form: its reduce is
+    ``…/group_reduce/wide_sum/…`` on the trace, cut out by trace_join; the
+    plain ``sum(rate())`` program holds no such name."""
+    fn, plain_args = _mxu_call(monkeypatch)
+    assert "wide_sum" not in fn.lower(*plain_args).as_text(debug_info=True)
+    monkeypatch.undo()
+    fn, wide_args = _mxu_call(monkeypatch, "avg_over_time", "avg")
+    assert wide_args[1] == ("agg", "avg", "wide")
+    assert "group_reduce/wide_sum/" in fn.lower(*wide_args).as_text(debug_info=True)
+    assert _trace_join().scope_of({
+        "tf_op": "jit(f)/epilogue/jit(g)/group_reduce/wide_sum/dot_general"}
+    ) == "wide_sum"
 
 
 def test_scoped_program_is_bit_equal_to_its_unscoped_twin():
@@ -533,10 +571,7 @@ def test_trace_join_reads_the_op_name_from_the_events_metadata(tmp_path):
     which jax's ProfileData does not hand out: the tool reads it from the
     file's bytes. One device plane: stat names 7 = tf_op, 9 = hlo_category,
     300 = a referenced value; one op with a string and a ref stat."""
-    spec = importlib.util.spec_from_file_location(
-        "trace_join", os.path.join(ROOT, "tools", "trace_join.py"))
-    tj = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(tj)
+    tj = _trace_join()
 
     def stat_name(i, name):
         return _pb(5, _pb(1, i) + _pb(2, _pb(1, i) + _pb(2, name)))

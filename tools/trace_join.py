@@ -45,7 +45,9 @@ from benchmarks.chip.trace_reduce import (  # noqa: E402
     OPS_LINES, find_marker, short, union_seconds,
 )
 
-SCOPES = ("range_fn", "group_reduce", "epilogue")  # the fused programs' stages
+# the fused programs' stages, and the wide sum inside group_reduce (the
+# innermost name wins, so its ops are cut out of the reduce's)
+SCOPES = ("range_fn", "group_reduce", "epilogue", "wide_sum")
 
 
 def clipped(intervals, clip):
