@@ -160,8 +160,9 @@ HELP_TEXTS: dict[str, str] = {
     "filodb_standing_rule_samples": "Samples written back into the memstore by recording rules.",
     "filodb_query_phase_seconds": "Per-phase query latency decomposition (parse_plan|admission|stage|dispatch|transfer|render|other).",
     "filodb_stage_part_seconds": "Where the stage phase went, per execution (lookup|gather|assemble|h2d_shard|readback|concat|h2d_super); the parts sum to at most the stage phase.",
-    "filodb_stage_h2d_bytes": "Bytes a cold stage uploaded to the device, by part (h2d_shard = per-shard blocks, h2d_super = the superblock and its le vector).",
-    "filodb_stage_d2h_bytes": "Bytes a cold stage read back from device-resident staged arrays (the first np.asarray of each).",
+    "filodb_stage_h2d_bytes": "Bytes a cold stage uploaded to the device, by part (h2d_shard = per-shard blocks, h2d_super = a host-assembled superblock, a masked sidecar, the le vector).",
+    "filodb_stage_d2h_bytes": "Bytes a cold stage read back from device-resident staged arrays that have no host mirror (the first np.asarray of each).",
+    "filodb_superblock_assembled": "Superblocks built, by where their arrays were concatenated (device = from the shards' device-resident blocks, nothing uploaded again; host = concatenated on the host and uploaded).",
     "filodb_query_wait_seconds": "Per-caller wait for work another caller runs, by kind (coalesced = a follower of an identical in-flight query).",
     "filodb_http_request_seconds": "Handler wall of a query route, entry to return, per caller (route = query_range|query).",
     "filodb_transfer_ready_seconds": "Per-caller wait for the device to finish the query's program at the serving edge; the transfer phase less this is the copy back.",
@@ -470,10 +471,13 @@ QUERY_PHASES = (
 # - gather     — the per-partition ``samples_in_range`` loop (chunk decode)
 # - assemble   — pad into [S, T(, B)] blocks, bucket-scheme unify, labels
 # - h2d_shard  — per-shard block: host mirror copies + ``device_put``
-# - readback   — ``np.asarray`` of a device-resident staged array (a D2H
-#                copy the first time; waits for the upload it reads)
-# - concat     — row-concatenate the shard blocks into the superblock
-# - h2d_super  — the superblock's (and the ``le`` vector's) upload
+# - readback   — taking staged arrays on the host (``ST.read_back``): a
+#                mirror is an attribute away; an array without one is a D2H
+#                copy the first time, and waits for the upload it reads
+# - concat     — row-concatenate the shard blocks on the host: the whole
+#                superblock, or only its mirrors when the device assembles it
+# - h2d_super  — the superblock onto the device: assembled there from the
+#                shards' blocks, or uploaded; and the ``le`` vector's upload
 STAGE_PARTS = (
     "lookup", "gather", "assemble", "h2d_shard", "readback", "concat",
     "h2d_super",

@@ -32,9 +32,12 @@ import time
 from collections import OrderedDict
 from dataclasses import dataclass
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
-from ..metrics import REGISTRY, span
+from ..metrics import REGISTRY, record_kernel_dispatch, span
 
 # S pads to the next bucket; T pads to a multiple of 128 (TPU lane width)
 _S_BUCKETS = (8, 32, 128, 512, 1024, 2048, 4096, 8192, 16384, 32768, 65536, 131072)
@@ -67,8 +70,6 @@ def series_put(mesh):
     ``mesh`` is None, else series-axis row sharding
     (``PartitionSpec(axis)`` — trailing dims replicate implicitly, so ONE
     spec covers [S], [S, T] and [S, T, B] arrays alike)."""
-    import jax
-
     if mesh is None:
         return jax.device_put
     from jax.sharding import NamedSharding, PartitionSpec
@@ -81,8 +82,6 @@ def replicated_put(mesh):
     """``jax.device_put`` closure committing an array REPLICATED across the
     mesh (window matrices, group-id-free [J] vectors): placed once at build
     so warm dispatches pay no per-call broadcast transfer."""
-    import jax
-
     if mesh is None:
         return jax.device_put
     from jax.sharding import NamedSharding, PartitionSpec
@@ -116,6 +115,11 @@ def mesh_device_bytes(mesh, nbytes: int) -> dict | None:
 # masked (missing-scrape) grid detection: tolerate up to this fraction of
 # holes before dropping to the general gather path
 MAX_HOLE_FRAC = 0.05
+
+
+# the [S, T'] arrays of a MaskedGrid: what its to_device pins in HBM
+_MGRID_ARRAYS = ("valid", "vals", "dev", "raw", "ffv", "ffd", "bfv", "bfd",
+                 "ff2v", "ff2d", "bfraw", "cc")
 
 
 @dataclass
@@ -162,12 +166,9 @@ class MaskedGrid:
         """``put`` overrides the placement of every [S, T'] array (a
         series-sharded superblock passes its row-band sharding so the
         masked fused program spans the mesh without a gather)."""
-        import jax
-
         if put is None:
             put = jax.device_put
-        for f in ("valid", "vals", "dev", "raw", "ffv", "ffd", "bfv", "bfd",
-                  "ff2v", "ff2d", "bfraw", "cc"):
+        for f in _MGRID_ARRAYS:
             a = getattr(self, f)
             if a is not None:
                 setattr(self, f, put(a))
@@ -458,8 +459,6 @@ class StagedBlock:
         every [S, ...] array) so one shard_map program spans all devices —
         the padded S must be mesh-divisible (concat_blocks
         ``series_multiple``). The mesh is recorded as ``self.placement``."""
-        import jax
-
         if mesh is not None:
             self.placement = mesh
         if keep_host:
@@ -473,6 +472,8 @@ class StagedBlock:
                           if self.raw is not None else None)
             self.h_dev = (np.array(self.ts_dev, copy=True)
                           if self.ts_dev is not None else None)
+            # no repair writes a baseline: the array itself is its mirror
+            self.h_base = self.baseline
         put = series_put(self.placement)
         self.ts = put(self.ts)
         self.vals = put(self.vals)
@@ -930,6 +931,7 @@ def _append_to_parts(parts, block: StagedBlock, column: str,
     nb.h_lens = new_lens
     nb.h_raw = block.h_raw
     nb.h_dev = getattr(block, "h_dev", None)
+    nb.h_base = getattr(block, "h_base", None)
     if new_cont is not None:
         nb.cont = new_cont
     elif getattr(block, "cont", None) is not None:
@@ -1131,9 +1133,12 @@ def _slot_align(shard, part_ids, column, series, start_ms: int, end_ms: int):
 # device is for what the caches do not count: the block being built while
 # the old ones are still pinned, window structures, kernel temporaries
 # (2.5 GB over the ledger at the peak of that run). The two figures come
-# from that ONE deployment size on a v5e — they bound the duplication
-# (per-shard blocks pinned beside the superblock built from them, the
-# pre-warm restaging each key), they do not repair it: ROADMAP S2 / D4.
+# from that ONE deployment size on a v5e — they bound the duplication,
+# they do not repair it (ROADMAP S2 / D4). What is duplicated is residency
+# only: every row is on the device twice, in its shard's block and in the
+# superblock assemble_rows copied it into (and a key the pre-warm restages
+# holds both again), but it crosses to the device once, as part of its
+# shard's block, and is never read back.
 SUPERBLOCK_DEVICE_SHARE = 0.35
 STAGE_CACHE_DEVICE_SHARE = 0.20
 
@@ -1143,8 +1148,6 @@ def _device_bytes_limits() -> dict:
     """``memory_stats()["bytes_limit"]`` of every visible device, keyed by
     ``str(device)`` (the key ``mesh_device_bytes`` uses); a device whose
     backend reports none (the CPU backend) is absent."""
-    import jax
-
     out = {}
     for d in jax.devices():
         stats = d.memory_stats()
@@ -1156,8 +1159,6 @@ def _device_bytes_limits() -> dict:
 @functools.lru_cache(maxsize=1)
 def default_device_key() -> str:
     """``str()`` of the device an un-sharded ``jax.device_put`` lands on."""
-    import jax
-
     return str(jax.devices()[0])
 
 
@@ -1183,41 +1184,105 @@ def staged_nbytes(block: StagedBlock) -> int:
                 block.ts_dev):
         if arr is not None:
             total += int(arr.nbytes)
-    if block.mgrid is not None:
-        for f in ("valid", "vals", "dev", "raw", "ffv", "ffd", "bfv", "bfd",
-                  "ff2v", "ff2d", "bfraw", "cc"):
-            arr = getattr(block.mgrid, f)
-            if arr is not None:
-                total += int(arr.nbytes)
-    return total
+    return total + _mgrid_nbytes(block.mgrid)
 
 
-def read_back(*arrays) -> list:
-    """``np.asarray`` of staged arrays that may be device-resident, as the
-    ``readback`` part of ``stage``. The first conversion of a device array
-    is a D2H copy (jax keeps the host copy on the array afterwards) and
-    waits for the upload that produced it; those bytes are counted in
-    ``filodb_stage_d2h_bytes_total``. ``None`` passes through."""
-    import jax
+def _mgrid_nbytes(mgrid) -> int:
+    if mgrid is None:
+        return 0
+    return sum(
+        int(arr.nbytes)
+        for arr in (getattr(mgrid, f) for f in _MGRID_ARRAYS)
+        if arr is not None)
 
+
+# a staged block's arrays and the host mirrors ``to_device(keep_host=True)``
+# and the append repair leave beside them
+_MIRRORS = {"ts": "h_ts", "vals": "h_vals", "lens": "h_lens", "raw": "h_raw",
+            "ts_dev": "h_dev", "baseline": "h_base"}
+
+
+def read_back(blocks, *fields) -> list[list]:
+    """Per block, the host copy of each of its arrays named in ``fields``
+    (``ts``, ``vals``, ``lens``, ``raw``, ``ts_dev``, ``baseline``), as the
+    ``readback`` part of ``stage``. What the host still holds is not fetched:
+    an array that is still numpy is returned as it is, and one uploaded with
+    ``to_device(keep_host=True)`` (every block out of the stage cache) gives
+    its mirror — rows below ``lens`` are the device's bits; a repair that has
+    since moved on may have written columns beyond them. Only an array with
+    neither is converted, and that is the ONE place a staged array crosses
+    back: a D2H copy the first time (jax keeps the host copy on the array
+    afterwards) that waits for the upload it reads, its bytes counted in
+    ``filodb_stage_d2h_bytes_total``. An absent array gives ``None``."""
     out, nbytes = [], 0
     with span("stage:readback", part="readback"):
-        for a in arrays:
-            # _npy_value is where jax caches a fetched host copy; a jax
-            # without it would count every conversion, never too few
-            if isinstance(a, jax.Array) and getattr(a, "_npy_value", None) is None:
-                nbytes += int(a.nbytes)
-            out.append(None if a is None else np.asarray(a))
-    if nbytes:
-        REGISTRY.counter("filodb_stage_d2h_bytes").inc(nbytes)
+        for b in blocks:
+            row = []
+            for f in fields:
+                a = getattr(b, f)
+                if isinstance(a, jax.Array):
+                    mirror = getattr(b, _MIRRORS[f], None)
+                    if isinstance(mirror, np.ndarray):
+                        a = mirror
+                    else:
+                        # _npy_value is where jax caches a fetched host copy;
+                        # a jax without it would count every conversion,
+                        # never too few
+                        if getattr(a, "_npy_value", None) is None:
+                            nbytes += int(a.nbytes)
+                        a = np.asarray(a)
+                row.append(a)
+            out.append(row)
+    # booked even when nothing crossed: /metrics then says 0, not nothing
+    REGISTRY.counter("filodb_stage_d2h_bytes").inc(nbytes)
     return out
 
 
+def staged_samples(blocks) -> int:
+    """Samples the blocks hold: the sum of their ``lens``."""
+    return sum(int(lens.sum()) for (lens,) in read_back(blocks, "lens"))
+
+
+def _members(blocks) -> list:
+    """The blocks a superblock is made of: those with series, or the first
+    one when none has (an empty-but-shaped block: mesh rows can be empty)."""
+    return [b for b in blocks if b.n_series > 0] or list(blocks[:1])
+
+
+def _padded_rows(real, series_multiple: int = 1) -> int:
+    rows = pad_series(sum(b.n_series for b in real))
+    if series_multiple > 1:
+        rows = -(-rows // series_multiple) * series_multiple
+    return rows
+
+
+def _shared_regular(real, T: int):
+    """The [T] regular grid every member advertises identically, else None
+    (narrower padded members keep the shared grid)."""
+    reg = real[0].regular_ts
+    if reg is None or not all(
+        b.regular_ts is not None
+        and len(b.regular_ts) == len(reg)
+        and not (np.asarray(b.regular_ts) != np.asarray(reg)).any()
+        for b in real[1:]
+    ):
+        return None
+    regular = np.asarray(reg)
+    if len(regular) < T:
+        ext = np.full(T, TS_PAD, np.int32)
+        ext[: len(regular)] = regular
+        regular = ext
+    return regular
+
+
 def concat_blocks(blocks, force_raw: bool = False,
-                  series_multiple: int = 1) -> StagedBlock:
-    """Row-concatenate staged blocks into one padded superblock EXACTLY —
-    corrected values, raw sidecars, baselines and part refs carry over with
-    no restaging and no semantic drift. All blocks must share base_ms.
+                  series_multiple: int = 1, full: bool = True) -> StagedBlock:
+    """Row-concatenate staged blocks into one padded superblock EXACTLY, on
+    the host — corrected values, raw sidecars, baselines and part refs carry
+    over with no restaging and no semantic drift. All blocks must share
+    base_ms. Members' arrays are taken where the host has them (their
+    mirrors, :func:`read_back`): concatenating blocks out of the stage cache
+    reads nothing back from the device.
 
     Histogram blocks ([S, T, B] vals, [S, B] baselines) concatenate the same
     way into a ``[ΣS, T, B]`` superblock; all blocks must already share one
@@ -1234,59 +1299,42 @@ def concat_blocks(blocks, force_raw: bool = False,
     one. ``series_multiple`` rounds the padded series axis up to a multiple
     (a device-mesh size): series-axis sharding needs equal per-device row
     bands, and the trash-group/padded-row masking already makes the extra
-    rows inert."""
-    real = [b for b in blocks if b.n_series > 0]
-    if not real:  # keep an empty-but-shaped block (mesh rows can be empty)
-        real = list(blocks[:1])
+    rows inert.
+
+    ``full=False`` is for a caller that assembles the arrays on the device
+    (:func:`build_superblock`) and keeps no mirror: only what the grid
+    classification reads is concatenated — ``lens`` always, ``ts`` unless
+    the members share a regular grid, ``vals`` / ``raw`` for a masked build
+    — and the other arrays of the result are ``None``."""
+    real = _members(blocks)
     assert real and len({b.base_ms for b in real}) == 1
     T = max(b.ts.shape[1] for b in real)
     S = sum(b.n_series for b in real)
-    Sp = pad_series(S)
-    if series_multiple > 1:
-        Sp = ((Sp + series_multiple - 1) // series_multiple) * series_multiple
-    host_vals = read_back(*(b.vals for b in real))
-    is_hist = any(v.ndim == 3 for v in host_vals)
+    Sp = _padded_rows(real, series_multiple)
+    is_hist = any(b.vals.ndim == 3 for b in real)
+    buckets: tuple = ()
     if is_hist:
-        assert len({v.shape[2] for v in host_vals}) == 1, (
+        assert len({b.vals.shape[2] for b in real}) == 1, (
             "histogram blocks must share one bucket scheme before concat"
         )
-        B = host_vals[0].shape[2]
-        val_shape, base_shape = (Sp, T, B), (Sp, B)
-    else:
-        val_shape, base_shape = (Sp, T), (Sp,)
-    ts = np.full((Sp, T), TS_PAD, np.int32)
-    vals = np.zeros(val_shape, np.float32)
+        buckets = (real[0].vals.shape[2],)
     any_raw = (force_raw or any(b.raw is not None for b in real)) and not is_hist
-    raw = np.zeros((Sp, T), np.float32) if any_raw else None
-    lens = np.zeros(Sp, np.int32)
-    baseline = np.zeros(base_shape, np.float32)
+    host: list[dict] = [{} for _ in real]  # per member, what read_back gave
+    rows: dict = {}  # field -> the members' rows of it, concatenated
+
+    def need(*fields):
+        todo = [f for f in fields if f not in rows]
+        if todo:
+            rows.update(
+                zip(todo, _concat_rows(real, host, Sp, todo, T, buckets)))
+
+    big = ("vals", "raw") if any_raw else ("vals",)
+    need("lens", *(("ts", "baseline") + big if full else ()))
+    lens = rows["lens"]
     part_refs: list = []
-    o = 0
-    for b, b_vals in zip(real, host_vals):
-        k, t = b.n_series, b.ts.shape[1]
-        b_ts, b_raw, b_lens, b_base = read_back(
-            b.ts, b.raw if raw is not None else None, b.lens, b.baseline)
-        ts[o : o + k, :t] = b_ts[:k]
-        vals[o : o + k, :t] = b_vals[:k]
-        if raw is not None:
-            raw[o : o + k, :t] = (b_raw if b_raw is not None else b_vals)[:k]
-        lens[o : o + k] = b_lens[:k]
-        baseline[o : o + k] = b_base[:k]
+    for b in real:
         part_refs.extend(b.part_refs)
-        o += k
-    reg = real[0].regular_ts
-    regular = None
-    if reg is not None and all(
-        b.regular_ts is not None
-        and len(b.regular_ts) == len(reg)
-        and not (np.asarray(b.regular_ts) != np.asarray(reg)).any()
-        for b in real[1:]
-    ):
-        regular = np.asarray(reg)
-        if len(regular) < T:  # narrower padded blocks keep the shared grid
-            ext = np.full(T, TS_PAD, np.int32)
-            ext[: len(regular)] = regular
-            regular = ext
+    regular = _shared_regular(real, T)
     # grid classification does NOT stop at "not exactly regular": re-detect
     # the near-regular (jittered scrape) and masked (missing-scrape) grids
     # over the CONCATENATED rows, so a cross-shard superblock keeps the
@@ -1301,6 +1349,8 @@ def concat_blocks(blocks, force_raw: bool = False,
     maxdev = 0
     mgrid = None
     if regular is None and S > 0:
+        need("ts")
+        ts = rows["ts"]
         _reg2, nominal, ts_dev, maxdev = detect_shared_grid(
             ts, lens, S, T, Sp
         )
@@ -1312,14 +1362,17 @@ def concat_blocks(blocks, force_raw: bool = False,
         elif nominal is None and not is_hist and S > 1 and int(
             lens[:S].min()
         ) >= 2:
+            need(*big)
             base = real[0].base_ms
             cleaned = [
                 (ts[i, : lens[i]].astype(np.int64) + base, None)
                 for i in range(S)
             ]
-            mgrid = _build_masked_grid(cleaned, base, vals, raw, lens, T, Sp)
-    out = StagedBlock(ts, vals, lens, real[0].base_ms, baseline, S,
-                      part_refs, raw=raw, regular_ts=regular,
+            mgrid = _build_masked_grid(cleaned, base, rows["vals"],
+                                       rows.get("raw"), lens, T, Sp)
+    out = StagedBlock(rows.get("ts"), rows.get("vals"), lens,
+                      real[0].base_ms, rows.get("baseline"), S,
+                      part_refs, raw=rows.get("raw"), regular_ts=regular,
                       nominal_ts=nominal, ts_dev=ts_dev, maxdev_ms=maxdev,
                       mgrid=mgrid)
     if not is_hist:
@@ -1345,6 +1398,193 @@ def concat_blocks(blocks, force_raw: bool = False,
                 o += k
             out.cont = (cont_raw, cont_corr)
     return out
+
+
+def _concat_rows(real, host, rows: int, fields, T: int,
+                 buckets: tuple) -> list:
+    """Per field, a fresh ``[rows, ...]`` host array holding every member's
+    real series at its row band and padding (``TS_PAD`` timestamps, else 0)
+    everywhere else. ``host`` holds, per member, the arrays taken on the host
+    so far (:func:`read_back`), and gains what is missing: no array is
+    taken twice. ``raw`` takes a member's ``vals`` where it has no sidecar.
+    A member's columns are copied up to its longest series only: beyond it a
+    staged block holds padding, and its mirror may hold what a later repair
+    wrote (:func:`_append_to_parts`), which is not this block's. The arrays
+    are written once and belong to the caller: they become the superblock's
+    arrays on the host path and its mirrors on the device path."""
+    want = dict.fromkeys(tuple(fields) + ("lens",) + (
+        ("vals",) if "raw" in fields else ()))
+    missing = [f for f in want if f not in host[0]]
+    if missing:
+        for have, got in zip(host, read_back(real, *missing)):
+            have.update(zip(missing, got))
+    shapes = {"ts": (rows, T), "vals": (rows, T) + buckets, "raw": (rows, T),
+              "lens": (rows,), "baseline": (rows,) + buckets}
+    out = [np.full(shapes[f], TS_PAD, np.int32) if f == "ts"
+           else np.zeros(shapes[f], np.int32 if f == "lens" else np.float32)
+           for f in fields]
+    o = 0
+    for b, have in zip(real, host):
+        k = b.n_series
+        w = int(have["lens"][:k].max()) if k else 0
+        for f, dst in zip(fields, out):
+            src = have[f] if have[f] is not None else have["vals"]
+            if f in ("lens", "baseline"):
+                dst[o : o + k] = src[:k]
+            else:
+                dst[o : o + k, :w] = src[:k, :w]
+        o += k
+    return out
+
+
+def _place_rows(out, member, offset, count):
+    """``out`` with ``member``'s first ``count`` rows written at row
+    ``offset`` and every other row as it was. ``offset`` and ``count`` are
+    values, so one program serves every selection of these padded shapes.
+    The window written is the member's whole padded height; where that
+    would reach past ``out``'s last row (the last members' padding may) it
+    is moved up, as ``dynamic_update_slice`` would move it, and the member's
+    rows are moved down within it by as much; rows of the window that are
+    not the member's are written back as read."""
+    n = member.shape[0]
+    tail = (0,) * (member.ndim - 1)
+    start = jnp.clip(offset, 0, out.shape[0] - n)
+    shift = offset - start
+    row = lax.broadcasted_iota(jnp.int32, (n,) + (1,) * (member.ndim - 1), 0)
+    mine = (row >= shift) & (row < shift + count)
+    window = lax.dynamic_slice(out, (start,) + tail, member.shape)
+    moved = jnp.roll(member.astype(out.dtype), shift, axis=0)
+    return lax.dynamic_update_slice(
+        out, jnp.where(mine, moved, window), (start,) + tail)
+
+
+@functools.partial(jax.jit, static_argnames=("rows",))
+def assemble_rows(members, offsets, counts, jitter, *, rows: int):
+    """The device side of :func:`concat_blocks`: one program that writes
+    every member's ``(ts, vals, raw, lens, baseline)`` (``raw`` None
+    throughout for blocks without a sidecar) into its row band of fresh
+    ``[rows, ...]`` arrays, narrower members padded to the common T, all
+    else padding. ``jitter`` is None, or ``(nominal [T], n, m)`` of a
+    near-regular superblock: the per-sample deviations of its ``n`` series'
+    first ``m`` samples from the nominal grid come back as a sixth array
+    (``detect_shared_grid``'s ``ts_dev``). Compiled per padded shapes and
+    member count only."""
+    T = max(m[0].shape[1] for m in members)
+    buckets = members[0][1].shape[2:]
+    ts = jnp.full((rows, T), TS_PAD, jnp.int32)
+    vals = jnp.zeros((rows, T) + buckets, jnp.float32)
+    raw = None if members[0][2] is None else jnp.zeros((rows, T), jnp.float32)
+    lens = jnp.zeros((rows,), jnp.int32)
+    baseline = jnp.zeros((rows,) + buckets, jnp.float32)
+    for i, (m_ts, m_vals, m_raw, m_lens, m_base) in enumerate(members):
+        at = (offsets[i], counts[i])
+        ts = _place_rows(ts, m_ts, *at)
+        vals = _place_rows(vals, m_vals, *at)
+        if raw is not None:
+            raw = _place_rows(raw, m_raw, *at)
+        lens = _place_rows(lens, m_lens, *at)
+        baseline = _place_rows(baseline, m_base, *at)
+    ts_dev = None
+    if jitter is not None:
+        nominal, n, m = jitter
+        real = ((lax.broadcasted_iota(jnp.int32, (rows, 1), 0) < n)
+                & (lax.broadcasted_iota(jnp.int32, (1, T), 1) < m))
+        ts_dev = jnp.where(
+            real, (ts - nominal[None, :]).astype(jnp.float32), 0.0)
+    return ts, vals, raw, lens, baseline, ts_dev
+
+
+def _one_device(real) -> bool:
+    """Whether every array of every member is committed to one and the same
+    single device — what :func:`assemble_rows` can read in place."""
+    devices = set()
+    for b in real:
+        for a in (b.ts, b.vals, b.lens, b.baseline, b.raw):
+            if a is None:
+                continue
+            if not isinstance(a, jax.Array):
+                return False
+            devices |= a.devices()
+    return len(devices) == 1
+
+
+def build_superblock(blocks, mesh=None,
+                     keep_host: bool = True) -> tuple[StagedBlock, int]:
+    """The fused aggregate's superblock, resident where its kernels run:
+    ``(block, bytes uploaded for it)``. One algorithm — row-concatenate, pad,
+    classify the grid (:func:`concat_blocks`) — whose copy of the big arrays
+    runs where the bytes already are:
+
+    - every member on ONE device and no mesh (blocks straight out of the
+      stage cache): :func:`assemble_rows` builds the device arrays from the
+      members' device arrays, bit for bit what the host concatenation and a
+      ``device_put`` give. The host concatenates, once and from the members'
+      mirrors, only what it keeps: the superblock's own mirrors
+      (``keep_host``, for :func:`extend_superblock`) and what the grid
+      classification reads. Nothing but a masked sidecar is uploaded;
+    - otherwise (a member made on the host after staging — a remapped bucket
+      scheme, a ``le=`` slice — or a mesh placement): concatenate on the
+      host and upload the whole.
+
+    Books ``stage:concat`` (the host's part) and ``stage:h2d_super`` (the
+    device's), and one ``filodb_superblock_assembled_total{where}``."""
+    real = _members(blocks)
+    multiple = mesh.devices.size if mesh is not None else 1
+    rows = _padded_rows(real, multiple)
+    on_device = (mesh is None and _one_device(real)
+                 and max(b.ts.shape[0] for b in real) <= rows)
+    with span("stage:concat", part="concat"):
+        out = concat_blocks(blocks, series_multiple=multiple,
+                            full=keep_host or not on_device)
+    with span("stage:h2d_super", part="h2d_super"):
+        if on_device:
+            uploaded = _assemble_on_device(out, real, rows, keep_host)
+        else:
+            out.to_device(keep_host=keep_host, mesh=mesh)
+            uploaded = staged_nbytes(out)
+    REGISTRY.counter("filodb_superblock_assembled",
+                     where="device" if on_device else "host").inc()
+    return out, uploaded
+
+
+def _assemble_on_device(out: StagedBlock, real, rows: int,
+                        keep_host: bool) -> int:
+    """Give the host-concatenated ``out`` its device arrays, assembled from
+    the members'; what the host concatenated becomes its mirrors, or goes.
+    Returns the bytes uploaded (the masked sidecar's; the program's
+    arguments are not counted, as for any dispatch)."""
+    any_raw = any(b.raw is not None for b in real) and real[0].vals.ndim == 2
+    members = tuple(
+        (b.ts, b.vals,
+         (b.raw if b.raw is not None else b.vals) if any_raw else None,
+         b.lens, b.baseline)
+        for b in real)
+    offsets = np.cumsum([0] + [b.n_series for b in real[:-1]]).astype(np.int32)
+    counts = np.asarray([b.n_series for b in real], np.int32)
+    jitter = None
+    if out.nominal_ts is not None:
+        m = int(out.lens[0])
+        jitter = (out.nominal_ts, np.int32(out.n_series), np.int32(m))
+    t0 = time.perf_counter()
+    before = assemble_rows._cache_size()
+    ts, vals, raw, lens, baseline, ts_dev = assemble_rows(
+        members, offsets, counts, jitter, rows=rows)
+    record_kernel_dispatch(
+        "superblock_assemble", time.perf_counter() - t0,
+        compiled=assemble_rows._cache_size() > before,
+        key={"variant": "hist" if vals.ndim == 3 else "scalar",
+             "shapes": "x".join(
+                 ["S%d" % rows] + ["%s%d" % p for p in zip("TB", vals.shape[1:])]),
+             "batch": len(real)},
+    )
+    if keep_host:
+        out.h_ts, out.h_vals, out.h_lens = out.ts, out.vals, out.lens
+        out.h_raw, out.h_dev, out.h_base = out.raw, out.ts_dev, out.baseline
+    out.ts, out.vals, out.raw, out.lens = ts, vals, raw, lens
+    out.baseline, out.ts_dev = baseline, ts_dev
+    if out.mgrid is not None:
+        out.mgrid.to_device()
+    return _mgrid_nbytes(out.mgrid)
 
 
 def _superblock_cache_walker(cache) -> int:
@@ -1721,3 +1961,14 @@ def stage_from_shard(
         if aligned is not None:
             block = _stage(aligned)
     return block
+
+
+# kernel-observatory registration (obs/kernels.py; linted by
+# tools/check_metrics.py — every jit wrapper here must register)
+def _register_kernel_observatory() -> None:
+    from ..obs.kernels import KERNELS
+
+    KERNELS.register_jits("ops.staging", assemble_rows=assemble_rows)
+
+
+_register_kernel_observatory()

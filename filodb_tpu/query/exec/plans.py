@@ -456,7 +456,7 @@ class SelectRawPartitionsExec(ExecPlan):
             )
             ctx.stats.bump(
                 series_scanned=len(ids),
-                samples_scanned=int(np.asarray(block.lens).sum()),
+                samples_scanned=ST.staged_samples([block]),
             )
             if ctx.stats.samples_scanned > ctx.max_samples:
                 raise QueryError(
@@ -1086,7 +1086,7 @@ def _unify_hist_blocks(blocks, block_les):
     untouched."""
     from ...core.histograms import remap_buckets, unify_schemes
 
-    vals_in = ST.read_back(*(b.vals for b in blocks))
+    vals_in = [v for (v,) in ST.read_back(blocks, "vals")]
     vals_out, union, changed = unify_schemes(vals_in, block_les)
     if not changed:
         return blocks, union
@@ -1095,13 +1095,13 @@ def _unify_hist_blocks(blocks, block_les):
         if v_out is v_in:  # already on the union scheme
             out.append(b)
             continue
-        baseline = np.asarray(b.baseline)
+        ((ts, lens, baseline),) = ST.read_back([b], "ts", "lens", "baseline")
         if baseline.ndim == 2:
             baseline = remap_buckets(baseline, l, union)
         # remapping touches only the bucket axis: the shared regular time
         # grid (the fused shared-window fast path) survives verbatim
         out.append(ST.StagedBlock(
-            np.asarray(b.ts), v_out, np.asarray(b.lens), b.base_ms, baseline,
+            ts, v_out, lens, b.base_ms, baseline,
             b.n_series, list(b.part_refs), regular_ts=b.regular_ts,
         ))
     return out, union
@@ -1144,9 +1144,8 @@ def _slice_bucket(block, les, bucket_le: float):
         b_idx = int(hits[0]) if len(hits) else -1
     if b_idx < 0:
         return None
-    (vals3,) = ST.read_back(block.vals)
+    ((vals3, baseline),) = ST.read_back([block], "vals", "baseline")
     scalar_vals = np.ascontiguousarray(vals3[..., b_idx])
-    baseline = np.asarray(block.baseline)
     sliced = ST.StagedBlock(
         block.ts, scalar_vals, block.lens, block.base_ms,
         baseline[..., b_idx] if baseline.ndim == 2 else baseline,
@@ -1157,8 +1156,21 @@ def _slice_bucket(block, les, bucket_le: float):
         nominal_ts=block.nominal_ts, ts_dev=block.ts_dev,
         maxdev_ms=block.maxdev_ms,
     )
+    # the arrays the slice shares with the staged block keep its mirrors
+    for mirror in ("h_ts", "h_lens", "h_dev"):
+        setattr(sliced, mirror, getattr(block, mirror, None))
     le_str = "+Inf" if np.isinf(les64[b_idx]) else f"{les64[b_idx]:g}"
     return sliced, le_str
+
+
+def _key_mode(hint, stage_mode: str) -> str:
+    """The staging mode a superblock is cached under: the function's, unless
+    the column is known (``hint`` = (cumulative counter, delta), learned at
+    its first build) to stage raw whatever the function — a gauge, a
+    histogram, a delta-temporality counter."""
+    if hint is not None and not (hint[0] and not hint[1]):
+        return "raw"
+    return stage_mode
 
 
 # incremental superblock extension under live ingest (escape hatch: set
@@ -1386,23 +1398,8 @@ class FusedAggregateExec(ExecPlan):
             hints = {}
             ctx.memstore._fused_mode_hints = hints
         hint_key = (ctx.dataset, self.filters, self.column)
-        hint = hints.get(hint_key)
-        key_mode = stage_mode
-        if hint is not None and not (hint[0] and not hint[1]):
-            key_mode = "raw"  # known gauge / delta-temporality column
-        # sharded and single-device superblocks are distinct cache entries:
-        # placement (and the mesh-divisible padding) differs even over the
-        # identical selection, and engines sharing one memstore may run both
-        mesh_desc = (
-            None if self.mesh is None
-            else (self.mesh.axis_names[0],
-                  tuple(d.id for d in self.mesh.devices.flat))
-        )
-        sb_key = (
-            ctx.dataset, tuple(self.shard_nums), self.filters,
-            self.raw_start_ms, self.raw_end_ms, self.column, key_mode,
-            mesh_desc,
-        )
+        sb_key = self._cache_key(
+            ctx, _key_mode(hints.get(hint_key), stage_mode))
         # standing-query refresh contexts carry a pin sink: the maintainer
         # pins the key it resolves to (by standing qid) so ad-hoc eviction
         # storms can't churn the entry its delta refresh extends in place
@@ -1432,7 +1429,7 @@ class FusedAggregateExec(ExecPlan):
             if refreshed is not None:
                 return refreshed
             return self._build_superblock(
-                ctx, stage_mode, cache, sb_key, versions, hints, hint_key
+                ctx, stage_mode, cache, versions, hints, hint_key
             )
 
     def _refresh_superblock(self, ctx: QueryContext, cache, sb_key,
@@ -1600,7 +1597,7 @@ class FusedAggregateExec(ExecPlan):
         return self._serve_hit(ctx, new_entry)
 
     def _build_superblock(self, ctx: QueryContext, stage_mode: str, cache,
-                          sb_key, versions, hints, hint_key):
+                          versions, hints, hint_key):
         rewritten, col_override, bucket_le = _histogram_suffix_rewrite(
             self.filters
         )
@@ -1693,7 +1690,7 @@ class FusedAggregateExec(ExecPlan):
                     # no such bucket on this shard: it contributes no rows,
                     # but its series/samples were scanned — count them, as
                     # the reference path does (it bumps before slicing)
-                    dropped_samples += int(ST.read_back(block.lens)[0].sum())
+                    dropped_samples += ST.staged_samples([block])
                     continue
                 block, le_str = sliced
                 part_labels = [dict(l, le=le_str) for l in part_labels]
@@ -1704,7 +1701,7 @@ class FusedAggregateExec(ExecPlan):
             if hist_col != is_hist and blocks:
                 return "mixed_schemas"  # scalar + histogram blocks can't mix
             is_hist = hist_col
-            if ST.read_back(block.vals)[0].ndim != (3 if hist_col else 2):
+            if block.vals.ndim != (3 if hist_col else 2):
                 return "mixed_schemas"
             blocks.append(block)
             block_les.append(les)
@@ -1719,9 +1716,7 @@ class FusedAggregateExec(ExecPlan):
                                is_delta)
         if not blocks:
             return None  # empty selection: empty result, not a fallback
-        samples = dropped_samples + int(
-            sum(int(h.sum()) for h in ST.read_back(*(b.lens for b in blocks)))
-        )
+        samples = dropped_samples + ST.staged_samples(blocks)
         ctx.stats.bump(series_scanned=total, samples_scanned=samples,
                        cache_misses=1)
         if ctx.stats.samples_scanned > ctx.max_samples:
@@ -1739,19 +1734,21 @@ class FusedAggregateExec(ExecPlan):
         # With a mesh, the series axis pads to a mesh-divisible ΣS (the
         # existing trash-group masking keeps the extra rows inert) and the
         # arrays pin SHARDED (PartitionSpec(axis) row bands) so the fused
-        # program spans every device without a gather.
-        multiple = self.mesh.devices.size if self.mesh is not None else 1
-        with span("stage:concat", part="concat"):
-            super_block = ST.concat_blocks(blocks, series_multiple=multiple)
-        with span("stage:h2d_super", part="h2d_super"):
-            super_block.to_device(keep_host=_SUPERBLOCK_EXTEND,
-                                  mesh=self.mesh)
-            les_dev = (ST.replicated_put(self.mesh)(
-                np.asarray(les, dtype=np.float32))
-                if les is not None else None)
+        # program spans every device without a gather. Without one, blocks
+        # straight out of the stage cache are concatenated on the device
+        # that holds them and nothing is uploaded again.
+        super_block, uploaded = ST.build_superblock(
+            blocks, mesh=self.mesh, keep_host=_SUPERBLOCK_EXTEND)
+        les_dev = None
+        if les is not None:
+            with span("stage:h2d_super", part="h2d_super"):
+                les_dev = ST.replicated_put(self.mesh)(
+                    np.asarray(les, dtype=np.float32))
+            uploaded += int(les_dev.nbytes)
+        if uploaded:
+            REGISTRY.counter("filodb_stage_h2d_bytes",
+                             part="h2d_super").inc(uploaded)
         nbytes = ST.staged_nbytes(super_block)
-        REGISTRY.counter("filodb_stage_h2d_bytes", part="h2d_super").inc(
-            nbytes + (int(les_dev.nbytes) if les_dev is not None else 0))
 
         resolved_mode = (
             stage_mode if is_counter and not is_delta and not is_hist
@@ -1771,8 +1768,24 @@ class FusedAggregateExec(ExecPlan):
             ctx.memstore.shard(ctx.dataset, s).version for s in self.shard_nums
         )
         if versions_now == versions:
+            # under the key the NEXT query of this column looks up: the
+            # first build came keyed by its function's mode and has only now
+            # learned the column's (hints, above)
+            sb_key = self._cache_key(
+                ctx, _key_mode(hints.get(hint_key), stage_mode))
             cache.put(sb_key, versions, value, nbytes)
         return value
+
+    def _cache_key(self, ctx: QueryContext, key_mode: str) -> tuple:
+        """The superblock cache's key of this plan's selection. Sharded and
+        single-device superblocks are distinct entries: placement (and the
+        mesh-divisible padding) differs even over the identical selection,
+        and engines sharing one memstore may run both."""
+        return (
+            ctx.dataset, tuple(self.shard_nums), self.filters,
+            self.raw_start_ms, self.raw_end_ms, self.column, key_mode,
+            self._mesh_desc(),
+        )
 
     def _mesh_desc(self) -> tuple | None:
         """Hashable mesh identity for the batching coalescing key (mirrors
