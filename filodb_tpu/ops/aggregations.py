@@ -14,10 +14,33 @@ no members at a step yields NaN.
 from __future__ import annotations
 
 import functools
+import time
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
+
+from ..metrics import record_fused_fallback, record_kernel_dispatch
+from ..singleflight import memo_on
+from .hist_kernels import (
+    _hist_range_jitter,
+    _hist_range_shared,
+    hist_range_kernel,
+    histogram_quantile,
+)
+from .kernels import pad_steps, range_kernel
+from .mxu_jitter import (
+    jitter_masked_kernel,
+    jitter_masked_minmax,
+    jitter_minmax,
+    jitter_range_kernel,
+    jitter_window_matrices,
+    masked_window_matrices,
+)
+from .mxu_kernels import fetch_strategy, mxu_range_kernel, window_matrices
+from .staging import replicated_put, series_put
 
 SIMPLE_AGG_OPS = ("sum", "count", "avg", "min", "max", "stddev", "stdvar", "group")
 
@@ -27,16 +50,12 @@ def segment_aggregate(op: str, values, group_ids, num_groups: int):
 
     Instrumented entry point: per-op dispatch latency + JIT cache hit/miss
     (metrics.record_kernel_dispatch) around the jitted kernel."""
-    import time as _time
-
-    from ..metrics import record_kernel_dispatch
-
-    t0 = _time.perf_counter()
+    t0 = time.perf_counter()
     before = _segment_aggregate_jit._cache_size()
     out = _segment_aggregate_jit(op, values, group_ids, num_groups)
     s_, j_ = np.shape(values)
     record_kernel_dispatch(
-        f"segment_{op}", _time.perf_counter() - t0,
+        f"segment_{op}", time.perf_counter() - t0,
         compiled=_segment_aggregate_jit._cache_size() > before,
         key={"variant": "general", "epilogue": f"agg:{op}",
              "shapes": f"S{s_}xJ{j_}xG{num_groups}"},
@@ -258,10 +277,9 @@ def _grid_variant(block, func: str, is_delta: bool):
     (near-regular: certain-membership matmul + per-series boundary
     corrections, ops/mxu_jitter) > ``masked`` (near-regular with missed
     scrapes: validity-masked sidecar) > ``general``. The ONE selection
-    shared by the single-query dispatch (_fused_dispatch) and the
-    cross-query batcher (fused_batched_scalar) — a batched lane MUST
-    compute through the same variant its unbatched execution would, or
-    batched-vs-sequential parity breaks.
+    (through _fused_body) of the single-query dispatch and the cross-query
+    batcher — a batched lane MUST compute through the same variant its
+    unbatched execution would, or batched-vs-sequential parity breaks.
 
     Returns ``(variant, degrade_reason)``: ``degrade_reason`` is a
     fused-fallback taxonomy entry (``grid_jitter``/``grid_holes``) set only
@@ -298,35 +316,6 @@ def _pallas_variant(block, func: str, mesh) -> bool:
     from .pallas_kernels import PALLAS_FUNCS, pallas_enabled
 
     return func in PALLAS_FUNCS and pallas_enabled(block.ts.shape[1])
-
-
-def batch_variant_supported(block, func: str, kind: str, is_delta: bool,
-                            mesh) -> bool:
-    """Whether the batched program set models this dispatch's kernel
-    variant. The scheduler consults this BEFORE grouping
-    (FusedAggregateExec._dispatch_fused): a structurally-unbatchable
-    request runs unbatched immediately instead of paying the batch window
-    and a guaranteed-to-raise launch (which would also mint
-    ``outcome="fallback"`` dispatches operators are told to investigate).
-    The raises inside fused_batched_scalar/fused_batched_hist remain as
-    the defensive backstop for window-dependent cases (a merged window
-    failing the jitter safety bound)."""
-    if kind == "hist":
-        # jittered hist grids take the unbatched jitter variant
-        return block.regular_ts is not None or block.nominal_ts is None
-    variant, reason = _grid_variant(block, func, is_delta)
-    if variant in ("jitter", "masked") and func in (
-        "min_over_time", "max_over_time"
-    ):
-        # the fused minmax programs (tile hierarchy + edge one-hots) have
-        # no batched twin — the query still runs ONE fused dispatch, it
-        # just doesn't coalesce with other lanes
-        return False
-    if variant == "general" and reason is None and _pallas_variant(
-        block, func, mesh
-    ):
-        return False
-    return True
 
 
 def _jwm_args(wm) -> tuple:
@@ -372,6 +361,41 @@ def _mgrid_args(g) -> tuple:
             g.ff2v, g.ff2d, bfraw)
 
 
+def _hist_jwm_args(wm) -> tuple:
+    """Jitter window structure in hist_kernels._hist_range_jitter's order:
+    shared certain-range boundaries + the uncertain-slot selections."""
+    return (wm.d_clo, wm.d_chi, wm.d_idx, wm.d_count0, wm.d_c0pos,
+            wm.d_has_klo, wm.d_has_khi, wm.d_F0_rel, wm.d_L0_rel,
+            wm.d_Klo_rel, wm.d_Khi_rel, wm.d_blo_rel, wm.d_ehi_rel)
+
+
+def _hist_shared_windows(block, start_off: int, step_ms: int, j_pad: int,
+                         window_ms: int, mesh):
+    """Host-precomputed [J] searchsorted window-boundary vectors for a
+    shared-regular-grid histogram (super)block, memoized device-resident on
+    the block (the O(S*J*T) per-series boundary compare never runs for
+    scraped histograms), then the window itself: the hist_shared body's
+    window operands in _hist_range_shared's order."""
+    key = (start_off, int(step_ms), j_pad, int(window_ms), mesh is not None)
+
+    def build_windows():
+        m = int(np.asarray(block.lens)[0])
+        tsv = np.asarray(block.regular_ts)[:m].astype(np.int64)
+        out_t = start_off + np.arange(j_pad, dtype=np.int64) * int(step_ms)
+        hi = np.searchsorted(tsv, out_t, side="right").astype(np.int32)
+        lo = np.searchsorted(
+            tsv, out_t - int(window_ms), side="right"
+        ).astype(np.int32)
+        t_first = tsv[np.minimum(lo, m - 1)].astype(np.int32)
+        t_last = tsv[np.minimum(hi - 1, m - 1)].astype(np.int32)
+        put = replicated_put(mesh)
+        return (put(lo), put(hi), put(t_first), put(t_last),
+                put(out_t.astype(np.int32)))
+
+    return memo_on(block, "_hist_win_cache", key, build_windows) + (
+        np.int32(window_ms),)
+
+
 @jax.named_scope("epilogue")
 def _apply_epilogue(sj, epilogue: tuple, gids, n_real, qv, num_groups: int):
     """Device-side epilogue over the [S, J] range grid, INSIDE the same
@@ -413,149 +437,6 @@ def _apply_epilogue(sj, epilogue: tuple, gids, n_real, qv, num_groups: int):
     if kind == "quantile":
         return segment_quantile(sj, gids, num_groups + 1, qv)[:num_groups]
     raise ValueError(f"unknown fused epilogue {epilogue}")
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "func", "epilogue", "num_steps", "num_groups", "is_counter", "is_delta"
-))
-def _fused_general_jit(func, epilogue, ts, vals, lens, baseline, raw, gids,
-                       n_real, qv, start_off, step_ms, window,
-                       num_steps: int, num_groups: int, is_counter: bool,
-                       is_delta: bool):
-    """range_kernel -> epilogue as ONE compiled program: only the [G, J]
-    group partials (or [k, J] top-k rows) ever exist as program outputs —
-    no [S, J] grid reaches the host, and no second dispatch happens. See
-    _apply_epilogue for the trash-group / padded-row contract."""
-    from .kernels import range_kernel
-
-    sj = range_kernel(
-        func, ts, vals, lens, baseline, raw, start_off, step_ms, window,
-        num_steps, is_counter=is_counter, is_delta=is_delta,
-    )
-    return _apply_epilogue(sj, epilogue, gids, n_real, qv, num_groups)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "func", "epilogue", "num_groups", "is_counter", "is_delta", "fetch"
-))
-def _fused_mxu_jit(func, epilogue, vals, raw, baseline, W, F, L, L2, count,
-                   t_first, t_last, t_last2, out_t, window_ms, idx, gids,
-                   n_real, qv, num_groups: int, is_counter: bool,
-                   is_delta: bool, fetch: str):
-    """Regular-grid fused variant: the MXU window-matmul kernel and the
-    epilogue in one compiled program (see _apply_epilogue for the
-    trash-group / padded-row contract)."""
-    from .mxu_kernels import mxu_range_kernel
-
-    sj = mxu_range_kernel(
-        func, vals, raw, baseline, W, F, L, L2, count, t_first, t_last,
-        t_last2, out_t, window_ms, idx=idx, is_counter=is_counter,
-        is_delta=is_delta, fetch=fetch,
-    )
-    return _apply_epilogue(sj, epilogue, gids, n_real, qv, num_groups)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "func", "epilogue", "num_groups", "is_counter", "is_delta", "fetch"
-))
-def _fused_jitter_jit(func, epilogue, vals, dev, raw, jwm, window_ms, gids,
-                      n_real, qv, num_groups: int, is_counter: bool,
-                      is_delta: bool, fetch: str):
-    """Near-regular-grid fused variant: the jitter kernel (certain-window
-    matmul + per-series boundary corrections, ops/mxu_jitter) and the
-    epilogue in ONE compiled program — a jittered scrape grid stays a
-    single warm dispatch instead of paying the multi-pass general path.
-    ``jwm`` is the flat window-structure tuple (_jwm_args)."""
-    from .mxu_jitter import jitter_range_kernel
-
-    sj = jitter_range_kernel(
-        func, vals, dev, raw, *jwm, window_ms,
-        is_counter=is_counter, is_delta=is_delta, fetch=fetch,
-    )
-    return _apply_epilogue(sj, epilogue, gids, n_real, qv, num_groups)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "func", "epilogue", "num_groups", "is_counter", "is_delta", "fetch"
-))
-def _fused_masked_jit(func, epilogue, mba, mwm, window_ms, maxdev, gids,
-                      n_real, qv, num_groups: int, is_counter: bool,
-                      is_delta: bool, fetch: str):
-    """Missing-scrape fused variant: the validity-masked jitter kernel over
-    the block's slot-aligned sidecar (staging.MaskedGrid) + epilogue, one
-    program. ``mba`` = _mgrid_args sidecar tuple, ``mwm`` = _mwm_args;
-    ``maxdev`` enables the kernel's lean gather plan."""
-    from .mxu_jitter import jitter_masked_kernel
-
-    sj = jitter_masked_kernel(
-        func, *mba, *mwm, window_ms,
-        is_counter=is_counter, is_delta=is_delta, fetch=fetch,
-        maxdev=maxdev,
-    )
-    return _apply_epilogue(sj, epilogue, gids, n_real, qv, num_groups)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "func", "epilogue", "num_groups", "n_valid", "fetch"
-))
-def _fused_jitter_minmax_jit(func, epilogue, vals, dev, jmm, gids, n_real,
-                             qv, num_groups: int, n_valid: int, fetch: str):
-    """min/max_over_time on a near-regular grid: the tile-hierarchy minmax
-    kernel (ops/mxu_jitter.jitter_minmax) + epilogue in ONE compiled
-    program — min/max no longer degrade jittered grids to the multi-pass
-    general path. ``jmm`` is the flat minmax structure tuple (_jmm_args,
-    built lazily via wm.ensure_minmax BEFORE the timed span)."""
-    from .mxu_jitter import jitter_minmax
-
-    sj = jitter_minmax(
-        vals, dev, *jmm, n_valid=n_valid,
-        is_min=(func == "min_over_time"), fetch=fetch,
-    )
-    return _apply_epilogue(sj, epilogue, gids, n_real, qv, num_groups)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "func", "epilogue", "num_groups", "fetch"
-))
-def _fused_masked_minmax_jit(func, epilogue, vals, dev, valid, cc, mmm,
-                             gids, n_real, qv, num_groups: int, fetch: str):
-    """Missing-scrape min/max fused variant: the validity-masked tile
-    hierarchy (jitter_masked_minmax) + epilogue in one program. ``mmm`` =
-    _mmm_args (after wm.ensure_minmax)."""
-    from .mxu_jitter import jitter_masked_minmax
-
-    sj = jitter_masked_minmax(
-        vals, dev, valid, cc, *mmm,
-        is_min=(func == "min_over_time"), fetch=fetch,
-    )
-    return _apply_epilogue(sj, epilogue, gids, n_real, qv, num_groups)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "func", "epilogue", "j_pad", "num_groups", "is_counter", "is_delta",
-    "interpret"
-))
-def _fused_pallas_jit(func, epilogue, ts, vals, raw, lens, gids, n_real, qv,
-                      start_off, step_ms, window, j_pad: int,
-                      num_groups: int, is_counter: bool, is_delta: bool,
-                      interpret: bool):
-    """Truly-irregular-grid fused variant: the one-pass Pallas window-stats
-    kernel (ops/pallas_kernels.window_aggregates, VMEM-tiled gather-scan) +
-    its finisher + the epilogue behind the SAME jit boundary (``interpret``
-    comes from pallas_kernels.interpret_mode: CPU backend only). The
-    Pallas grid pads S/J up to its tile sizes; slice back to the block's
-    own padding before the epilogue so the trash-group/gids contract is
-    unchanged."""
-    from .pallas_kernels import finish, window_aggregates
-
-    agg = window_aggregates(
-        ts, vals, raw, lens, start_off, step_ms, window, j_pad,
-        interpret=interpret,
-    )
-    sj = finish(func, agg, start_off, step_ms, window,
-                is_counter=is_counter, is_delta=is_delta)
-    sj = sj[: vals.shape[0], :j_pad]
-    return _apply_epilogue(sj, epilogue, gids, n_real, qv, num_groups)
 
 
 @jax.named_scope("epilogue")
@@ -615,215 +496,359 @@ def _sharded_epilogue(sj, epilogue: tuple, gids_l, n_real, qv,
 
 
 def _sharded_out_specs(epilogue: tuple):
-    from jax.sharding import PartitionSpec as P
-
     return (P(), P()) if epilogue[0] == "topk" else P()
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "mesh", "func", "epilogue", "num_steps", "num_groups", "is_counter",
-    "is_delta"
-))
-def _fused_sharded_general_jit(mesh, func, epilogue, ts, vals, lens, baseline,
-                               raw, gids, n_real, qv, start_off, step_ms,
-                               window, num_steps: int, num_groups: int,
-                               is_counter: bool, is_delta: bool):
-    """Series-sharded twin of _fused_general_jit: the row-wise range kernel
-    runs on each device's row band and the epilogue combines across the
-    mesh (psum / gathered winner state) INSIDE the same compiled program —
-    one dispatch spans every device, and only replicated [G, J] / [k, J]
-    outputs exist."""
-    from jax.sharding import PartitionSpec as P
+def _hist_epilogue(sjb, epilogue: tuple, gids, les, qv, num_groups: int):
+    """The hist family's epilogue over the [S, J, B] range grid: per-bucket
+    segment-sum, then (``epilogue == ("hist", "quantile")``) the device-side
+    histogram_quantile interpolation — only the [G, J, B] group partials, or
+    just the [G, J] quantile grid, exist as program outputs. ``gids``
+    follows the trash-group contract (padded rows -> group ``num_groups``);
+    per-bucket summation is the flattened [S, J*B] form of the same segment
+    reduce the reference partial-merge path runs, so the two paths agree
+    bit-for-bit on identical schemes."""
+    S, J, B = sjb.shape
+    gjb = _segment_aggregate_jit(
+        "sum", sjb.reshape(S, J * B), gids, num_groups + 1
+    )[:num_groups].reshape(num_groups, J, B)
+    if epilogue[1] == "quantile":
+        return histogram_quantile(qv, gjb, les)
+    return gjb
 
-    from .kernels import range_kernel
 
+def _hist_sharded_combine(sjb, epilogue: tuple, gids_l, les, qv,
+                          num_groups: int, axis: str):
+    """Device-local half of _hist_epilogue inside a shard_map body: local
+    per-bucket segment-sum + psum over the mesh axis, then the (optional)
+    histogram_quantile interpolation on the REPLICATED [G, J, B] partials —
+    the whole hist pipeline stays one multi-device program. NaN-absence
+    semantics match _segment_aggregate_jit's "sum" (a group with no members
+    anywhere is NaN), via psum'd validity counts."""
+    S, J, B = sjb.shape
+    with jax.named_scope("group_reduce"):
+        flat = sjb.reshape(S, J * B)
+        valid = ~jnp.isnan(flat)
+        s = jax.ops.segment_sum(
+            jnp.where(valid, flat, 0.0), gids_l, num_groups + 1
+        )
+        c = jax.ops.segment_sum(
+            valid.astype(flat.dtype), gids_l, num_groups + 1)
+        s = jax.lax.psum(s, axis)
+        c = jax.lax.psum(c, axis)
+        gjb = jnp.where(c > 0, s, jnp.nan)[:num_groups].reshape(
+            num_groups, J, B
+        )
+    if epilogue[1] == "quantile":
+        return histogram_quantile(qv, gjb, les)
+    return gjb
+
+
+# ---------------------------------------------------------------------------
+# the fused program family: range body x placement x lanes
+# ---------------------------------------------------------------------------
+#
+# Every fused query is the same three steps — a range body over the block's
+# row arrays, then an epilogue, optionally under shard_map, optionally
+# unrolled over lanes. Each part is written ONCE: the bodies in FUSED_BODIES,
+# the epilogue pairs above, the placement and the lane plan in
+# _fused_program_jit. A sharded or batched program is therefore the same
+# text as its single-device, single-query form by construction, which is
+# what the parities asserted in tests/test_fused_programs.py,
+# test_fused_mesh.py and test_scheduler.py rest on.
+
+
+class FusedBody(NamedTuple):
+    """One range body. ``rows(block)`` are the operands whose leading axis
+    is the series axis (a mesh shards them there; their PartitionSpec
+    follows from their rank). ``windows(block, start_off, step_ms, j_pad,
+    window_ms, mesh)`` builds — memoized on the block — what depends on the
+    window alone and is replicated, or returns None where the window fails
+    the grid's safety bound (the dispatch then degrades to the general body
+    and counts ``degrade``). ``statics(block, j_pad, is_counter, is_delta)``
+    are the trailing static arguments of ``grid(func, rows, windows,
+    *statics)``, the pure function to the [S, J] / [S, J, B] grid."""
+    variant: str  # the executable key's vocabulary (_exec_key_parts)
+    rows: Callable
+    windows: Callable
+    statics: Callable
+    grid: Callable
+    hist: bool = False
+    mesh: bool = True   # has a series-sharded form
+    lanes: bool = True  # has a cross-query batched form
+    degrade: str | None = None
+
+
+def _raw(block):
+    return block.raw if block.raw is not None else block.vals
+
+
+def _fetch_statics(block, j_pad, is_counter, is_delta):
+    return (is_counter, is_delta, fetch_strategy())
+
+
+def _int_windows(block, start_off, step_ms, j_pad, window_ms, mesh):
+    return (np.int32(start_off), np.int32(step_ms), np.int32(window_ms))
+
+
+def _mxu_windows(block, start_off, step_ms, j_pad, window_ms, mesh):
+    # window_matrices reads block.placement: a sharded block's set is
+    # committed mesh-replicated at build, so no per-dispatch broadcast
+    wm = window_matrices(block, start_off, step_ms, j_pad, window_ms)
+    return (wm.dW, wm.dF, wm.dL, wm.dL2, wm.d_count, wm.d_tf, wm.d_tl,
+            wm.d_tl2, wm.d_out_t, np.float32(window_ms), wm.d_idx)
+
+
+def _near_regular_windows(build, take, tail, minmax: bool = False):
+    """Window hook of the jitter / masked bodies: ``take``'s flat tuple of
+    the memoized window structure, then ``tail(block, window_ms)``."""
+    def windows(block, start_off, step_ms, j_pad, window_ms, mesh):
+        wm = build(block, start_off, step_ms, j_pad, window_ms)
+        if not wm.ok:  # window not wider than the deviation band
+            return None
+        if minmax:
+            # the tile/edge structures build lazily on the memoized window
+            # structure (only min/max_over_time read them)
+            wm.ensure_minmax()
+        return take(wm) + tail(block, window_ms)
+    return windows
+
+
+def _general_grid(func, rows, win, num_steps, is_counter, is_delta):
+    return range_kernel(func, *rows, *win, num_steps, is_counter=is_counter,
+                        is_delta=is_delta)
+
+
+def _mxu_grid(func, rows, win, is_counter, is_delta, fetch):
+    return mxu_range_kernel(func, *rows, *win[:-1], idx=win[-1],
+                            is_counter=is_counter, is_delta=is_delta,
+                            fetch=fetch)
+
+
+def _jitter_grid(func, rows, win, is_counter, is_delta, fetch):
+    return jitter_range_kernel(func, *rows, *win, is_counter=is_counter,
+                               is_delta=is_delta, fetch=fetch)
+
+
+def _masked_grid(func, rows, win, is_counter, is_delta, fetch):
+    # the trailing ``maxdev`` enables the kernel's lean gather plan
+    return jitter_masked_kernel(func, *rows, *win[:-1], is_counter=is_counter,
+                                is_delta=is_delta, fetch=fetch,
+                                maxdev=win[-1])
+
+
+def _jitter_minmax_grid(func, rows, win, n_valid, fetch):
+    # ``n_valid`` masks the TIME axis, unchanged by series sharding
+    return jitter_minmax(*rows, *win, n_valid=n_valid,
+                         is_min=(func == "min_over_time"), fetch=fetch)
+
+
+def _masked_minmax_grid(func, rows, win, fetch):
+    return jitter_masked_minmax(*rows, *win,
+                                is_min=(func == "min_over_time"), fetch=fetch)
+
+
+def _pallas_grid(func, rows, win, j_pad, is_counter, is_delta, interpret):
+    """The one-pass Pallas window-stats kernel (VMEM-tiled gather-scan) and
+    its finisher. The Pallas grid pads S/J up to its tile sizes; slice back
+    to the block's own padding before the epilogue so the trash-group/gids
+    contract is unchanged."""
+    from .pallas_kernels import finish, window_aggregates
+
+    agg = window_aggregates(*rows, *win, j_pad, interpret=interpret)
+    sj = finish(func, agg, *win, is_counter=is_counter, is_delta=is_delta)
+    return sj[: rows[1].shape[0], :j_pad]
+
+
+def _pallas_statics(block, j_pad, is_counter, is_delta):
+    from .pallas_kernels import interpret_mode  # True on the CPU backend only
+
+    return (j_pad, is_counter, is_delta, interpret_mode())
+
+
+def _hist_general_grid(func, rows, win, num_steps, is_delta):
+    return hist_range_kernel(func, *rows, *win, num_steps, is_delta=is_delta)
+
+
+def _hist_shared_grid(func, rows, win, is_delta):
+    return _hist_range_shared(func, *rows, *win, is_delta)
+
+
+def _hist_jitter_grid(func, rows, win, is_delta):
+    return _hist_range_jitter(func, *rows, win[:-1], win[-1], is_delta)
+
+
+# pallas has no sharded or batched form (irregular mesh grids run the
+# sharded general body); min/max on jitter/masked grids and a jittered hist
+# grid have no batched form: such a query still runs ONE fused dispatch, it
+# just doesn't coalesce with other lanes.
+FUSED_BODIES = {
+    "general": FusedBody(
+        "general",
+        lambda b: (b.ts, b.vals, b.lens, b.baseline, _raw(b)),
+        _int_windows, lambda b, j, c, d: (j, c, d), _general_grid),
+    "mxu": FusedBody(
+        "mxu", lambda b: (b.vals, _raw(b), b.baseline),
+        _mxu_windows, _fetch_statics, _mxu_grid),
+    "jitter": FusedBody(
+        "jitter", lambda b: (b.vals, b.ts_dev, _raw(b)),
+        _near_regular_windows(jitter_window_matrices, _jwm_args,
+                              lambda b, w: (np.float32(w),)),
+        _fetch_statics, _jitter_grid, degrade="grid_jitter"),
+    "masked": FusedBody(
+        "masked", lambda b: _mgrid_args(b.mgrid),
+        _near_regular_windows(
+            masked_window_matrices, _mwm_args,
+            lambda b, w: (np.float32(w), np.float32(b.mgrid.maxdev_ms))),
+        _fetch_statics, _masked_grid, degrade="grid_holes"),
+    "jitter_minmax": FusedBody(
+        "jitter", lambda b: (b.vals, b.ts_dev),
+        _near_regular_windows(jitter_window_matrices, _jmm_args,
+                              lambda b, w: (), minmax=True),
+        lambda b, j, c, d: (int(np.asarray(b.lens)[0]), fetch_strategy()),
+        _jitter_minmax_grid, lanes=False, degrade="grid_jitter"),
+    "masked_minmax": FusedBody(
+        "masked",
+        lambda b: (b.mgrid.vals, b.mgrid.dev, b.mgrid.valid, b.mgrid.cc),
+        _near_regular_windows(masked_window_matrices, _mmm_args,
+                              lambda b, w: (), minmax=True),
+        lambda b, j, c, d: (fetch_strategy(),),
+        _masked_minmax_grid, lanes=False, degrade="grid_holes"),
+    "pallas": FusedBody(
+        "pallas", lambda b: (b.ts, b.vals, _raw(b), b.lens),
+        _int_windows, _pallas_statics, _pallas_grid, mesh=False, lanes=False),
+    "hist_general": FusedBody(
+        "hist_general", lambda b: (b.ts, b.vals, b.lens),
+        _int_windows, lambda b, j, c, d: (j, d), _hist_general_grid,
+        hist=True),
+    "hist_shared": FusedBody(
+        "hist_shared", lambda b: (b.vals,),
+        _hist_shared_windows, lambda b, j, c, d: (d,), _hist_shared_grid,
+        hist=True),
+    "hist_jitter": FusedBody(
+        "hist_jitter", lambda b: (b.vals, b.ts_dev),
+        _near_regular_windows(jitter_window_matrices, _hist_jwm_args,
+                              lambda b, w: (np.int32(w),)),
+        lambda b, j, c, d: (d,), _hist_jitter_grid,
+        hist=True, lanes=False, degrade="grid_jitter"),
+}
+
+
+def _fused_body(hist: bool, block, func: str, is_delta: bool, mesh):
+    """``(body, degrade_reason)`` of one fused dispatch, before its window
+    is known: the _grid_variant ladder (mxu > jitter > masked > pallas >
+    general) with min/max_over_time on its dedicated tile-hierarchy bodies,
+    or the hist rule (shared grid > near-regular > per-series). The ONE
+    selection the single-query dispatch, the cross-query batcher and
+    batch_variant_supported share."""
+    if hist:
+        if block.regular_ts is not None:
+            return "hist_shared", None
+        return ("hist_jitter" if block.nominal_ts is not None
+                else "hist_general"), None
+    variant, reason = _grid_variant(block, func, is_delta)
+    if variant in ("jitter", "masked"):
+        if func in ("min_over_time", "max_over_time"):
+            variant += "_minmax"
+    elif variant == "general" and reason is None and _pallas_variant(
+        block, func, mesh
+    ):
+        variant = "pallas"
+    return variant, reason
+
+
+class FusedSpec(NamedTuple):
+    """The static half of one fused program: what selects the executable."""
+    body: str
+    func: str
+    epilogue: tuple
+    num_groups: int
+    statics: tuple
+    mesh: Any = None             # a 1-D device mesh: one shard_map frame
+    u_map: tuple | None = None   # lane -> unique window: the batched form
+
+
+# The batched form UNROLLS over lanes (static lane count + static
+# lane->unique-window map) instead of vmapping: each lane's subgraph is the
+# EXACT single-query computation — bit-equality is structural, not a
+# property of vmap batching rules — while XLA CSEs the work lanes share
+# (the unique-window range grids, and the NaN-validity masks lanes with the
+# same grid recompute). vmap was measured 3-10x slower here: its
+# segment-reduce batching rules materialize per-lane [S, J] operand copies.
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _fused_program_jit(spec: FusedSpec, rows, windows, gids, shared, qv):
+    """range body -> epilogue as ONE compiled program: only the [G, J] group
+    partials (or [k, J] top-k rows, or the hist family's [G, J, B]) ever
+    exist as program outputs — no [S, J] grid reaches the host, and no
+    second dispatch happens. ``shared`` is ``n_real`` (scalar family) or
+    ``les`` (hist family).
+
+    With ``spec.mesh`` the same two calls run inside one shard_map frame:
+    the row operands and gids are each device's row band, the replicated
+    window operands ride the closure (committed mesh-replicated at build),
+    and the epilogue combines across the mesh INSIDE the program — one
+    dispatch spans every device, and only replicated outputs exist.
+
+    With ``spec.u_map`` every window operand carries a leading
+    unique-window axis and ``gids`` / ``qv`` a leading lane axis: the body
+    evaluates ONCE per unique window, the epilogue once per lane, and the
+    outputs stack. The unbatched program is not one lane of the batched:
+    its outputs stay unstacked."""
+    body = FUSED_BODIES[spec.body]
+    mesh, u_map = spec.mesh, spec.u_map
+    if mesh is not None and not body.mesh:
+        raise NotImplementedError(f"the {spec.body} body has no sharded form")
+    if u_map is not None and not body.lanes:
+        raise NotImplementedError(f"the {spec.body} body has no batched form")
+    local, combine = ((_hist_epilogue, _hist_sharded_combine) if body.hist
+                      else (_apply_epilogue, _sharded_epilogue))
+
+    def run(rows, gids, finish):
+        if u_map is None:
+            return finish(
+                body.grid(spec.func, rows, windows, *spec.statics), gids, qv
+            )
+        grids = [
+            body.grid(spec.func, rows, tuple(a[u] for a in windows),
+                      *spec.statics)
+            for u in range(max(u_map) + 1)
+        ]
+        outs = [finish(grids[u_map[i]], gids[i], qv[i])
+                for i in range(len(u_map))]
+        return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
+
+    if mesh is None:
+        return run(rows, gids, lambda grid, g, q: local(
+            grid, spec.epilogue, g, shared, q, spec.num_groups))
     axis = mesh.axis_names[0]
 
-    def local(ts_l, vals_l, lens_l, base_l, raw_l, gids_l):
-        sj = range_kernel(
-            func, ts_l, vals_l, lens_l, base_l, raw_l, start_off, step_ms,
-            window, num_steps, is_counter=is_counter, is_delta=is_delta,
-        )
-        return _sharded_epilogue(sj, epilogue, gids_l, n_real, qv,
-                                 num_groups, axis)
+    def band(rows_l, gids_l):
+        return run(rows_l, gids_l, lambda grid, g, q: combine(
+            grid, spec.epilogue, g, shared, q, spec.num_groups, axis))
 
-    row, vec = P(axis, None), P(axis)
     return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(row, row, vec, vec, row, vec),
-        out_specs=_sharded_out_specs(epilogue),
+        band, mesh=mesh,
+        in_specs=(tuple(P(axis, *(None,) * (r.ndim - 1)) for r in rows),
+                  P(axis) if u_map is None else P(None, axis)),
+        out_specs=_sharded_out_specs(spec.epilogue),
         check_vma=False,
-    )(ts, vals, lens, baseline, raw, gids)
+    )(rows, gids)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "mesh", "func", "epilogue", "num_groups", "is_counter", "is_delta",
-    "fetch"
-))
-def _fused_sharded_mxu_jit(mesh, func, epilogue, vals, raw, baseline, W, F, L,
-                           L2, count, t_first, t_last, t_last2, out_t,
-                           window_ms, idx, gids, n_real, qv,
-                           num_groups: int, is_counter: bool, is_delta: bool,
-                           fetch: str):
-    """Series-sharded twin of _fused_mxu_jit: replicated [T, J] window
-    matrices ride the closure (committed replicated at build), the matmul
-    kernel runs per row band, and the epilogue combines over the mesh in
-    the same program."""
-    from jax.sharding import PartitionSpec as P
-
-    from .mxu_kernels import mxu_range_kernel
-
-    axis = mesh.axis_names[0]
-
-    def local(vals_l, raw_l, base_l, gids_l):
-        sj = mxu_range_kernel(
-            func, vals_l, raw_l, base_l, W, F, L, L2, count, t_first, t_last,
-            t_last2, out_t, window_ms, idx=idx, is_counter=is_counter,
-            is_delta=is_delta, fetch=fetch,
-        )
-        return _sharded_epilogue(sj, epilogue, gids_l, n_real, qv,
-                                 num_groups, axis)
-
-    row, vec = P(axis, None), P(axis)
-    return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(row, row, vec, vec),
-        out_specs=_sharded_out_specs(epilogue),
-        check_vma=False,
-    )(vals, raw, baseline, gids)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "mesh", "func", "epilogue", "num_groups", "is_counter", "is_delta",
-    "fetch"
-))
-def _fused_sharded_jitter_jit(mesh, func, epilogue, vals, dev, raw, jwm,
-                              window_ms, gids, n_real, qv, num_groups: int,
-                              is_counter: bool, is_delta: bool, fetch: str):
-    """Series-sharded twin of _fused_jitter_jit: the replicated window
-    structure rides the closure (committed mesh-replicated at build, like
-    the MXU matrices), the jitter kernel runs per row band, and the
-    epilogue combines over the mesh in the same program — mesh + jitter no
-    longer drops to the sharded general kernel (the PR 8 remainder)."""
-    from jax.sharding import PartitionSpec as P
-
-    from .mxu_jitter import jitter_range_kernel
-
-    axis = mesh.axis_names[0]
-
-    def local(vals_l, dev_l, raw_l, gids_l):
-        sj = jitter_range_kernel(
-            func, vals_l, dev_l, raw_l, *jwm, window_ms,
-            is_counter=is_counter, is_delta=is_delta, fetch=fetch,
-        )
-        return _sharded_epilogue(sj, epilogue, gids_l, n_real, qv,
-                                 num_groups, axis)
-
-    row, vec = P(axis, None), P(axis)
-    return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(row, row, row, vec),
-        out_specs=_sharded_out_specs(epilogue),
-        check_vma=False,
-    )(vals, dev, raw, gids)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "mesh", "func", "epilogue", "num_groups", "is_counter", "is_delta",
-    "fetch"
-))
-def _fused_sharded_masked_jit(mesh, func, epilogue, mba, mwm, window_ms,
-                              maxdev, gids, n_real, qv, num_groups: int,
-                              is_counter: bool, is_delta: bool, fetch: str):
-    """Series-sharded twin of _fused_masked_jit: every [S, T'] sidecar
-    array is a row band (staging pins them with the block's placement),
-    the replicated masked window structure rides the closure."""
-    from jax.sharding import PartitionSpec as P
-
-    from .mxu_jitter import jitter_masked_kernel
-
-    axis = mesh.axis_names[0]
-
-    def local(mba_l, gids_l):
-        sj = jitter_masked_kernel(
-            func, *mba_l, *mwm, window_ms,
-            is_counter=is_counter, is_delta=is_delta, fetch=fetch,
-            maxdev=maxdev,
-        )
-        return _sharded_epilogue(sj, epilogue, gids_l, n_real, qv,
-                                 num_groups, axis)
-
-    row, vec = P(axis, None), P(axis)
-    return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(tuple(row for _ in mba), vec),
-        out_specs=_sharded_out_specs(epilogue),
-        check_vma=False,
-    )(mba, gids)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "mesh", "func", "epilogue", "num_groups", "n_valid", "fetch"
-))
-def _fused_sharded_jitter_minmax_jit(mesh, func, epilogue, vals, dev, jmm,
-                                     gids, n_real, qv, num_groups: int,
-                                     n_valid: int, fetch: str):
-    """Series-sharded twin of _fused_jitter_minmax_jit: the replicated
-    minmax structures ride the closure, the tile-hierarchy kernel runs per
-    row band (``n_valid`` masks the TIME axis, unchanged by series
-    sharding), and the epilogue combines over the mesh in one program."""
-    from jax.sharding import PartitionSpec as P
-
-    from .mxu_jitter import jitter_minmax
-
-    axis = mesh.axis_names[0]
-
-    def local(vals_l, dev_l, gids_l):
-        sj = jitter_minmax(
-            vals_l, dev_l, *jmm, n_valid=n_valid,
-            is_min=(func == "min_over_time"), fetch=fetch,
-        )
-        return _sharded_epilogue(sj, epilogue, gids_l, n_real, qv,
-                                 num_groups, axis)
-
-    row, vec = P(axis, None), P(axis)
-    return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(row, row, vec),
-        out_specs=_sharded_out_specs(epilogue),
-        check_vma=False,
-    )(vals, dev, gids)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "mesh", "func", "epilogue", "num_groups", "fetch"
-))
-def _fused_sharded_masked_minmax_jit(mesh, func, epilogue, vals, dev, valid,
-                                     cc, mmm, gids, n_real, qv,
-                                     num_groups: int, fetch: str):
-    """Series-sharded twin of _fused_masked_minmax_jit (row-band sidecar
-    arrays, replicated minmax structures in the closure)."""
-    from jax.sharding import PartitionSpec as P
-
-    from .mxu_jitter import jitter_masked_minmax
-
-    axis = mesh.axis_names[0]
-
-    def local(vals_l, dev_l, valid_l, cc_l, gids_l):
-        sj = jitter_masked_minmax(
-            vals_l, dev_l, valid_l, cc_l, *mmm,
-            is_min=(func == "min_over_time"), fetch=fetch,
-        )
-        return _sharded_epilogue(sj, epilogue, gids_l, n_real, qv,
-                                 num_groups, axis)
-
-    row, vec = P(axis, None), P(axis)
-    return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(row, row, row, row, vec),
-        out_specs=_sharded_out_specs(epilogue),
-        check_vma=False,
-    )(vals, dev, valid, cc, gids)
+def batch_variant_supported(block, func: str, kind: str, is_delta: bool,
+                            mesh) -> bool:
+    """Whether this dispatch's body has a batched form. The scheduler
+    consults this BEFORE grouping (FusedAggregateExec._dispatch_fused): a
+    structurally-unbatchable request runs unbatched immediately instead of
+    paying the batch window and a guaranteed-to-raise launch (which would
+    also mint ``outcome="fallback"`` dispatches operators are told to
+    investigate). The raises inside _fused_dispatch remain as the defensive
+    backstop, and for window-dependent cases (a merged window failing the
+    jitter safety bound)."""
+    body, _reason = _fused_body(kind == "hist", block, func, is_delta, mesh)
+    return FUSED_BODIES[body].lanes
 
 
 def _exec_key_parts(variant: str, epilogue, block, j_pad: int,
@@ -846,416 +871,6 @@ def _exec_key_parts(variant: str, epilogue, block, j_pad: int,
         "mesh": mesh.devices.size if mesh is not None else None,
         "batch": batch,
     }
-
-
-def _fused_dispatch(func: str, epilogue: tuple, block, gids_padded,
-                    num_groups: int, params, qv, is_counter: bool,
-                    is_delta: bool, name: str, mesh=None):
-    """Shared kernel-variant selection (_grid_variant ladder: mxu > jitter >
-    masked > pallas > general) + instrumentation for every fused scalar
-    entry point (one dispatch, one latency observation, one JIT hit/miss
-    account). With ``mesh`` (a 1-D device mesh matching the block's
-    series-sharded placement) the same program shape dispatches ONCE across
-    every device via shard_map — every variant has a sharded twin except
-    pallas (irregular mesh grids run the sharded general kernel)."""
-    import time as _time
-
-    from ..metrics import record_fused_fallback, record_kernel_dispatch
-    from .kernels import pad_steps
-
-    j_pad = pad_steps(params.num_steps)
-    epilogue = _with_reduce_form(func, epilogue, num_groups)
-    raw = block.raw if block.raw is not None else block.vals
-    n_real = np.int32(block.n_series)
-    start_off = int(params.start_ms - block.base_ms)
-    variant, reason = _grid_variant(block, func, is_delta)
-    # window structures build (memoized per block) BEFORE the timed span,
-    # for every variant alike — the dispatch-latency observation must
-    # compare kernel cost across grid classes, not host-side build
-    # placement
-    wm = None
-    if variant == "mxu":
-        from .mxu_kernels import window_matrices
-
-        # window_matrices reads block.placement: a sharded block's set is
-        # committed mesh-replicated at build, so no per-dispatch broadcast
-        wm = window_matrices(
-            block, start_off, params.step_ms, j_pad, params.window_ms
-        )
-    elif variant == "jitter":
-        from .mxu_jitter import jitter_window_matrices
-
-        wm = jitter_window_matrices(
-            block, start_off, params.step_ms, j_pad, params.window_ms
-        )
-        if not wm.ok:  # window not wider than the deviation band
-            variant, reason = "general", "grid_jitter"
-    elif variant == "masked":
-        from .mxu_jitter import masked_window_matrices
-
-        wm = masked_window_matrices(
-            block, start_off, params.step_ms, j_pad, params.window_ms
-        )
-        if not wm.ok:
-            variant, reason = "general", "grid_holes"
-    if (variant in ("jitter", "masked")
-            and func in ("min_over_time", "max_over_time")):
-        # min/max tile/edge structures build lazily on the memoized window
-        # structure (only these two functions read them) — still host-side
-        # build work, so it stays outside the timed span
-        wm.ensure_minmax()
-    if variant == "general" and reason is None and _pallas_variant(
-        block, func, mesh
-    ):
-        variant = "pallas"
-    if reason is not None:
-        # degraded-kernel taxonomy: the dispatch STAYS one fused program
-        # (the general kernel), it just lost the jitter-tolerant fast
-        # variant — reserved for truly unsupported shapes (doc/perf.md)
-        record_fused_fallback(reason)
-    t0 = _time.perf_counter()
-    if mesh is not None:
-        name = "mesh_" + name
-    if variant == "mxu":
-        from .mxu_kernels import fetch_strategy
-
-        if mesh is not None:
-            fn = _fused_sharded_mxu_jit
-            args = (
-                mesh, func, epilogue, block.vals, raw, block.baseline,
-                wm.dW, wm.dF, wm.dL, wm.dL2, wm.d_count, wm.d_tf, wm.d_tl,
-                wm.d_tl2, wm.d_out_t, np.float32(params.window_ms), wm.d_idx,
-                gids_padded, n_real, qv, num_groups, is_counter, is_delta,
-                fetch_strategy(),
-            )
-        else:
-            fn = _fused_mxu_jit
-            args = (
-                func, epilogue, block.vals, raw, block.baseline,
-                wm.dW, wm.dF, wm.dL, wm.dL2, wm.d_count, wm.d_tf, wm.d_tl,
-                wm.d_tl2, wm.d_out_t, np.float32(params.window_ms), wm.d_idx,
-                gids_padded, n_real, qv, num_groups, is_counter, is_delta,
-                fetch_strategy(),
-            )
-    elif variant == "jitter":
-        from .mxu_kernels import fetch_strategy
-
-        if func in ("min_over_time", "max_over_time"):
-            common = (
-                func, epilogue, block.vals, block.ts_dev, _jmm_args(wm),
-                gids_padded, n_real, qv, num_groups,
-                int(np.asarray(block.lens)[0]), fetch_strategy(),
-            )
-            if mesh is not None:
-                fn, args = _fused_sharded_jitter_minmax_jit, (mesh,) + common
-            else:
-                fn, args = _fused_jitter_minmax_jit, common
-        else:
-            common = (
-                func, epilogue, block.vals, block.ts_dev, raw, _jwm_args(wm),
-                np.float32(params.window_ms), gids_padded, n_real, qv,
-                num_groups, is_counter, is_delta, fetch_strategy(),
-            )
-            if mesh is not None:
-                fn, args = _fused_sharded_jitter_jit, (mesh,) + common
-            else:
-                fn, args = _fused_jitter_jit, common
-    elif variant == "masked":
-        from .mxu_kernels import fetch_strategy
-
-        if func in ("min_over_time", "max_over_time"):
-            g = block.mgrid
-            common = (
-                func, epilogue, g.vals, g.dev, g.valid, g.cc, _mmm_args(wm),
-                gids_padded, n_real, qv, num_groups, fetch_strategy(),
-            )
-            if mesh is not None:
-                fn, args = _fused_sharded_masked_minmax_jit, (mesh,) + common
-            else:
-                fn, args = _fused_masked_minmax_jit, common
-        else:
-            common = (
-                func, epilogue, _mgrid_args(block.mgrid), _mwm_args(wm),
-                np.float32(params.window_ms),
-                np.float32(block.mgrid.maxdev_ms), gids_padded, n_real, qv,
-                num_groups, is_counter, is_delta, fetch_strategy(),
-            )
-            if mesh is not None:
-                fn, args = _fused_sharded_masked_jit, (mesh,) + common
-            else:
-                fn, args = _fused_masked_jit, common
-    elif variant == "pallas":
-        from .pallas_kernels import interpret_mode
-
-        fn = _fused_pallas_jit
-        args = (
-            func, epilogue, block.ts, block.vals, raw, block.lens,
-            gids_padded, n_real, qv, np.int32(start_off),
-            np.int32(params.step_ms), np.int32(params.window_ms), j_pad,
-            num_groups, is_counter, is_delta, interpret_mode(),
-        )
-    elif mesh is not None:
-        fn = _fused_sharded_general_jit
-        args = (
-            mesh, func, epilogue, block.ts, block.vals, block.lens,
-            block.baseline, raw, gids_padded, n_real, qv,
-            np.int32(start_off), np.int32(params.step_ms),
-            np.int32(params.window_ms), j_pad, num_groups, is_counter,
-            is_delta,
-        )
-    else:
-        fn = _fused_general_jit
-        args = (
-            func, epilogue, block.ts, block.vals, block.lens, block.baseline,
-            raw, gids_padded, n_real, qv, np.int32(start_off),
-            np.int32(params.step_ms), np.int32(params.window_ms), j_pad,
-            num_groups, is_counter, is_delta,
-        )
-    before = fn._cache_size()
-    out = fn(*args)
-    record_kernel_dispatch(
-        name, _time.perf_counter() - t0, compiled=fn._cache_size() > before,
-        key=_exec_key_parts(variant, epilogue, block, j_pad, num_groups,
-                            mesh),
-    )
-    return out
-
-
-def fused_range_aggregate(func: str, op: str, block, gids_padded,
-                          num_groups: int, params, is_counter: bool = False,
-                          is_delta: bool = False, mesh=None):
-    """One device dispatch for ``op by (...) (func(selector[w]))`` over a
-    staged (super)block: returns the [G, J_pad] group partials on device.
-
-    ``gids_padded`` is [S_padded] int32 with padded rows assigned the trash
-    group ``num_groups``. Regular shared grids ride the MXU window-matrix
-    kernel (matrices cached device-resident on the block); everything else
-    runs the general compare-and-reduce kernel. With ``mesh`` (the block's
-    series-sharded placement) the body runs under shard_map with a
-    psum-combined [G, J] — ONE dispatch across the whole mesh. Instrumented
-    like every other kernel entry (per-dispatch latency + JIT hit/miss)."""
-    return _fused_dispatch(
-        func, ("agg", op), block, gids_padded, num_groups, params,
-        np.float32(0.0), is_counter, is_delta, name=f"fused_{op}_{func}",
-        mesh=mesh,
-    )
-
-
-def zero_gids(block):
-    """All-zeros trash-group vector for epilogues that need no label
-    grouping (global topk/bottomk): unused by the epilogue math but part of
-    the shared jit signature. Memoized device-resident per block (co-placed
-    with a sharded block's series axis); also handed to the cross-query
-    batcher so identical-lane dedup keys on ONE object per block."""
-    from ..singleflight import memo_on
-    from .staging import series_put
-
-    s_pad = np.asarray(block.lens).shape[0]
-    return memo_on(
-        block, "_zero_gids", s_pad,
-        lambda: series_put(getattr(block, "placement", None))(
-            np.zeros(s_pad, dtype=np.int32)
-        ),
-    )
-
-
-def fused_topk(func: str, block, k: int, bottom: bool, params,
-               is_counter: bool = False, is_delta: bool = False, mesh=None):
-    """One device dispatch for global ``topk(k, func(selector[w]))``:
-    returns ([k, J_pad] values, [k, J_pad] i32 series indices) on device —
-    the compact per-step winner set, O(k*J) on the wire instead of the
-    [S, J] grid AggregatePresentExec gathers. Needs no label grouping at
-    all (global top-k), so the O(S) group pass is skipped too. With
-    ``mesh`` the per-device winner state combines across devices inside
-    the same program (all_gather of [k, J] candidates + re-reduce)."""
-    gids = zero_gids(block)
-    return _fused_dispatch(
-        func, ("topk", int(k), bool(bottom)), block, gids, 1, params,
-        np.float32(0.0), is_counter, is_delta,
-        name=f"fused_{'bottomk' if bottom else 'topk'}_{func}", mesh=mesh,
-    )
-
-
-def fused_quantile(func: str, block, gids_padded, num_groups: int, q: float,
-                   params, is_counter: bool = False, is_delta: bool = False,
-                   mesh=None):
-    """One device dispatch for ``quantile(q, func(selector[w])) by (...)``:
-    range kernel -> segment_quantile inside one compiled program; only the
-    [G, J_pad] quantile grid reaches the host. ``q`` rides as a dynamic
-    argument so dashboards sweeping quantiles share one executable. With
-    ``mesh`` the exact per-group multiset is all_gather'd across devices
-    inside the same program before the sort (see _sharded_epilogue)."""
-    return _fused_dispatch(
-        func, ("quantile",), block, gids_padded, num_groups, params,
-        np.float32(q), is_counter, is_delta, name=f"fused_quantile_{func}",
-        mesh=mesh,
-    )
-
-
-def _hist_jwm_args(wm) -> tuple:
-    """Jitter window structure in hist_kernels._hist_range_jitter's order:
-    shared certain-range boundaries + the uncertain-slot selections."""
-    return (wm.d_clo, wm.d_chi, wm.d_idx, wm.d_count0, wm.d_c0pos,
-            wm.d_has_klo, wm.d_has_khi, wm.d_F0_rel, wm.d_L0_rel,
-            wm.d_Klo_rel, wm.d_Khi_rel, wm.d_blo_rel, wm.d_ehi_rel)
-
-
-def _hist_shared_windows(block, params, j_pad: int, mesh):
-    """Host-precomputed [J] searchsorted window-boundary vectors for a
-    shared-regular-grid histogram (super)block, memoized device-resident on
-    the block (the O(S*J*T) per-series boundary compare never runs for
-    scraped histograms). ONE definition shared by the single-query fused
-    hist path and the cross-query batched dispatch — both must index the
-    block identically or batched-vs-sequential parity breaks."""
-    from ..singleflight import memo_on
-    from .staging import replicated_put
-
-    start_off = int(params.start_ms - block.base_ms)
-    key = (start_off, int(params.step_ms), j_pad, int(params.window_ms),
-           mesh is not None)
-
-    def build_windows():
-        m = int(np.asarray(block.lens)[0])
-        tsv = np.asarray(block.regular_ts)[:m].astype(np.int64)
-        out_t = start_off + np.arange(j_pad, dtype=np.int64) * int(
-            params.step_ms
-        )
-        hi = np.searchsorted(tsv, out_t, side="right").astype(np.int32)
-        lo = np.searchsorted(
-            tsv, out_t - int(params.window_ms), side="right"
-        ).astype(np.int32)
-        t_first = tsv[np.minimum(lo, m - 1)].astype(np.int32)
-        t_last = tsv[np.minimum(hi - 1, m - 1)].astype(np.int32)
-        put = replicated_put(mesh)
-        return (put(lo), put(hi), put(t_first), put(t_last),
-                put(out_t.astype(np.int32)))
-
-    return memo_on(block, "_hist_win_cache", key, build_windows)
-
-
-def fused_hist_range_aggregate(func: str, block, gids_padded,
-                               num_groups: int, params, les,
-                               q: float | None = None,
-                               is_delta: bool = False, mesh=None):
-    """One device dispatch for ``sum by (...) (hist_fn(selector[w]))`` over
-    a 3-D histogram (super)block — optionally with the device-side
-    ``histogram_quantile`` interpolation epilogue fused into the same
-    program (q != None). Returns [G, J_pad, B] group bucket partials, or
-    [G, J_pad] quantiles. ``les`` is the (unified) [B] bound vector.
-
-    Shared regular grids (the overwhelmingly common scraped-histogram case)
-    use the shared-window variant: [J] boundary vectors precomputed
-    host-side and memoized device-resident on the block, skipping the
-    O(S*J*T) per-series boundary compare entirely.
-
-    With ``mesh`` (the block's [ΣS, T, B] series-sharded placement) the
-    hist range_fn -> per-bucket segment-sum -> psum -> (quantile) body
-    runs under shard_map — one dispatch across the mesh, with the quantile
-    interpolation evaluated on the replicated [G, J, B] partials inside
-    the same program."""
-    import time as _time
-
-    from ..metrics import record_fused_fallback, record_kernel_dispatch
-    from .hist_kernels import (
-        _fused_hist_jit,
-        _fused_hist_jitter_jit,
-        _fused_hist_jitter_sharded_jit,
-        _fused_hist_sharded_jit,
-        _fused_hist_shared_jit,
-        _fused_hist_shared_sharded_jit,
-    )
-    from .kernels import pad_steps
-
-    j_pad = pad_steps(params.num_steps)
-    qv = np.float32(q if q is not None else 0.0)
-    start_off = int(params.start_ms - block.base_ms)
-    name = f"fused_hist_{'quantile_' if q is not None else ''}sum_{func}"
-    if mesh is not None:
-        name = "mesh_" + name
-    # near-regular (jittered scrape) grids ride the shared-boundary jitter
-    # variant; a grid failing the window safety bound degrades to the
-    # general per-series kernel (still one dispatch), counted grid_jitter
-    jwm = None
-    if block.regular_ts is None and block.nominal_ts is not None:
-        from .mxu_jitter import jitter_window_matrices
-
-        jwm = jitter_window_matrices(
-            block, start_off, params.step_ms, j_pad, params.window_ms
-        )
-        if not jwm.ok:
-            jwm = None
-            record_fused_fallback("grid_jitter")
-    # window-boundary structures build (memoized) before the timed span,
-    # like every other fused variant
-    shared_win = (
-        _hist_shared_windows(block, params, j_pad, mesh)
-        if block.regular_ts is not None else None
-    )
-    t0 = _time.perf_counter()
-    if block.regular_ts is not None:
-        lo, hi, t_first, t_last, out_t = shared_win
-        if mesh is not None:
-            before = _fused_hist_shared_sharded_jit._cache_size()
-            out = _fused_hist_shared_sharded_jit(
-                mesh, func, block.vals, lo, hi, t_first, t_last, out_t,
-                np.int32(params.window_ms), gids_padded, les, qv,
-                num_groups, is_delta, q is not None,
-            )
-            compiled = _fused_hist_shared_sharded_jit._cache_size() > before
-        else:
-            before = _fused_hist_shared_jit._cache_size()
-            out = _fused_hist_shared_jit(
-                func, block.vals, lo, hi, t_first, t_last, out_t,
-                np.int32(params.window_ms), gids_padded, les, qv,
-                num_groups, is_delta, q is not None,
-            )
-            compiled = _fused_hist_shared_jit._cache_size() > before
-    elif jwm is not None:
-        hwa = _hist_jwm_args(jwm)
-        if mesh is not None:
-            before = _fused_hist_jitter_sharded_jit._cache_size()
-            out = _fused_hist_jitter_sharded_jit(
-                mesh, func, block.vals, block.ts_dev, hwa,
-                np.int32(params.window_ms), gids_padded, les, qv,
-                num_groups, is_delta, q is not None,
-            )
-            compiled = _fused_hist_jitter_sharded_jit._cache_size() > before
-        else:
-            before = _fused_hist_jitter_jit._cache_size()
-            out = _fused_hist_jitter_jit(
-                func, block.vals, block.ts_dev, hwa,
-                np.int32(params.window_ms), gids_padded, les, qv,
-                num_groups, is_delta, q is not None,
-            )
-            compiled = _fused_hist_jitter_jit._cache_size() > before
-    elif mesh is not None:
-        before = _fused_hist_sharded_jit._cache_size()
-        out = _fused_hist_sharded_jit(
-            mesh, func, block.ts, block.vals, block.lens, gids_padded, les,
-            qv, np.int32(start_off), np.int32(params.step_ms),
-            np.int32(params.window_ms), j_pad, num_groups, is_delta,
-            q is not None,
-        )
-        compiled = _fused_hist_sharded_jit._cache_size() > before
-    else:
-        before = _fused_hist_jit._cache_size()
-        out = _fused_hist_jit(
-            func, block.ts, block.vals, block.lens, gids_padded, les, qv,
-            np.int32(start_off), np.int32(params.step_ms),
-            np.int32(params.window_ms), j_pad, num_groups, is_delta,
-            q is not None,
-        )
-        compiled = _fused_hist_jit._cache_size() > before
-    hist_variant = ("hist_shared" if block.regular_ts is not None
-                    else "hist_jitter" if jwm is not None else "hist_general")
-    record_kernel_dispatch(
-        name, _time.perf_counter() - t0, compiled=compiled,
-        key=_exec_key_parts(
-            hist_variant, ("hist", "quantile" if q is not None else "sum"),
-            block, j_pad, num_groups, mesh,
-        ),
-    )
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -1315,322 +930,30 @@ def _unique_windows(lanes, base_ms: int):
     return u_idx, ukeys
 
 
-# The batched programs UNROLL over lanes (static lane count + static
-# lane->unique-window map) instead of vmapping: each lane's subgraph is the
-# EXACT single-query computation — bit-equality is structural, not a
-# property of vmap batching rules — while XLA CSEs the work lanes share
-# (the unique-window range grids, and the NaN-validity masks lanes with the
-# same grid recompute). vmap was measured 3-10x slower here: its
-# segment-reduce batching rules materialize per-lane [S, J] operand copies.
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "func", "epilogue", "u_map", "num_steps", "num_groups", "is_counter",
-    "is_delta"
-))
-def _batched_general_jit(func, epilogue, ts, vals, lens, baseline, raw,
-                         gids_q, n_real, qv_q, so_u, sm_u, w_u,
-                         u_map: tuple, num_steps: int, num_groups: int,
-                         is_counter: bool, is_delta: bool):
-    from .kernels import range_kernel
-
-    sj_u = [
-        range_kernel(
-            func, ts, vals, lens, baseline, raw, so_u[u], sm_u[u], w_u[u],
-            num_steps, is_counter=is_counter, is_delta=is_delta,
-        )
-        for u in range(max(u_map) + 1)
-    ]
-    outs = [
-        _apply_epilogue(sj_u[u_map[i]], epilogue, gids_q[i], n_real,
-                        qv_q[i], num_groups)
-        for i in range(len(u_map))
-    ]
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "func", "epilogue", "u_map", "num_groups", "is_counter", "is_delta",
-    "fetch"
-))
-def _batched_mxu_jit(func, epilogue, vals, raw, baseline, W_u, F_u, L_u,
-                     L2_u, count_u, tf_u, tl_u, tl2_u, out_t_u, window_ms_u,
-                     idx_u, gids_q, n_real, qv_q, u_map: tuple,
-                     num_groups: int, is_counter: bool, is_delta: bool,
-                     fetch: str):
-    from .mxu_kernels import mxu_range_kernel
-
-    sj_u = [
-        mxu_range_kernel(
-            func, vals, raw, baseline, W_u[u], F_u[u], L_u[u], L2_u[u],
-            count_u[u], tf_u[u], tl_u[u], tl2_u[u], out_t_u[u],
-            window_ms_u[u], idx=idx_u[u], is_counter=is_counter,
-            is_delta=is_delta, fetch=fetch,
-        )
-        for u in range(max(u_map) + 1)
-    ]
-    outs = [
-        _apply_epilogue(sj_u[u_map[i]], epilogue, gids_q[i], n_real,
-                        qv_q[i], num_groups)
-        for i in range(len(u_map))
-    ]
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "func", "epilogue", "u_map", "num_groups", "is_counter", "is_delta",
-    "fetch"
-))
-def _batched_jitter_jit(func, epilogue, vals, dev, raw, wm_u, window_ms_u,
-                        gids_q, n_real, qv_q, u_map: tuple,
-                        num_groups: int, is_counter: bool, is_delta: bool,
-                        fetch: str):
-    """Batched twin of _fused_jitter_jit: the jitter kernel evaluates once
-    per UNIQUE window from the stacked window-structure tuple ``wm_u``
-    (each field [U, ...]; sliced per unrolled window), per-lane epilogues
-    as in _batched_general_jit — lane math identical to the single-query
-    jitter program, so batched lanes stay bit-equal to unbatched."""
-    from .mxu_jitter import jitter_range_kernel
-
-    sj_u = [
-        jitter_range_kernel(
-            func, vals, dev, raw, *(a[u] for a in wm_u), window_ms_u[u],
-            is_counter=is_counter, is_delta=is_delta, fetch=fetch,
-        )
-        for u in range(max(u_map) + 1)
-    ]
-    outs = [
-        _apply_epilogue(sj_u[u_map[i]], epilogue, gids_q[i], n_real,
-                        qv_q[i], num_groups)
-        for i in range(len(u_map))
-    ]
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "func", "epilogue", "u_map", "num_groups", "is_counter", "is_delta",
-    "fetch"
-))
-def _batched_masked_jit(func, epilogue, mba, wm_u, window_ms_u, maxdev,
-                        gids_q, n_real, qv_q, u_map: tuple, num_groups: int,
-                        is_counter: bool, is_delta: bool, fetch: str):
-    """Batched twin of _fused_masked_jit (masked sidecar shared across
-    windows, masked window structures stacked per unique window)."""
-    from .mxu_jitter import jitter_masked_kernel
-
-    sj_u = [
-        jitter_masked_kernel(
-            func, *mba, *(a[u] for a in wm_u), window_ms_u[u],
-            is_counter=is_counter, is_delta=is_delta, fetch=fetch,
-            maxdev=maxdev,
-        )
-        for u in range(max(u_map) + 1)
-    ]
-    outs = [
-        _apply_epilogue(sj_u[u_map[i]], epilogue, gids_q[i], n_real,
-                        qv_q[i], num_groups)
-        for i in range(len(u_map))
-    ]
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "mesh", "func", "epilogue", "u_map", "num_steps", "num_groups",
-    "is_counter", "is_delta"
-))
-def _batched_sharded_general_jit(mesh, func, epilogue, ts, vals, lens,
-                                 baseline, raw, gids_q, n_real, qv_q,
-                                 so_u, sm_u, w_u, u_map: tuple,
-                                 num_steps: int, num_groups: int,
-                                 is_counter: bool, is_delta: bool):
-    """Series-sharded twin of _batched_general_jit: the unique-window range
-    grids and the unrolled per-lane epilogues run INSIDE the shard_map
-    body, so one multi-device program serves every lane."""
-    from jax.sharding import PartitionSpec as P
-
-    from .kernels import range_kernel
-
-    axis = mesh.axis_names[0]
-
-    def local(ts_l, vals_l, lens_l, base_l, raw_l, gids_ql):
-        sj_u = [
-            range_kernel(
-                func, ts_l, vals_l, lens_l, base_l, raw_l, so_u[u],
-                sm_u[u], w_u[u], num_steps, is_counter=is_counter,
-                is_delta=is_delta,
-            )
-            for u in range(max(u_map) + 1)
-        ]
-        outs = [
-            _sharded_epilogue(sj_u[u_map[i]], epilogue, gids_ql[i], n_real,
-                              qv_q[i], num_groups, axis)
-            for i in range(len(u_map))
-        ]
-        return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
-
-    row, vec = P(axis, None), P(axis)
-    return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(row, row, vec, vec, row, P(None, axis)),
-        out_specs=_sharded_out_specs(epilogue),
-        check_vma=False,
-    )(ts, vals, lens, baseline, raw, gids_q)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "mesh", "func", "epilogue", "u_map", "num_groups", "is_counter",
-    "is_delta", "fetch"
-))
-def _batched_sharded_mxu_jit(mesh, func, epilogue, vals, raw, baseline, W_u,
-                             F_u, L_u, L2_u, count_u, tf_u, tl_u, tl2_u,
-                             out_t_u, window_ms_u, idx_u, gids_q, n_real,
-                             qv_q, u_map: tuple, num_groups: int,
-                             is_counter: bool, is_delta: bool, fetch: str):
-    from jax.sharding import PartitionSpec as P
-
-    from .mxu_kernels import mxu_range_kernel
-
-    axis = mesh.axis_names[0]
-
-    def local(vals_l, raw_l, base_l, gids_ql):
-        sj_u = [
-            mxu_range_kernel(
-                func, vals_l, raw_l, base_l, W_u[u], F_u[u], L_u[u],
-                L2_u[u], count_u[u], tf_u[u], tl_u[u], tl2_u[u],
-                out_t_u[u], window_ms_u[u], idx=idx_u[u],
-                is_counter=is_counter, is_delta=is_delta, fetch=fetch,
-            )
-            for u in range(max(u_map) + 1)
-        ]
-        outs = [
-            _sharded_epilogue(sj_u[u_map[i]], epilogue, gids_ql[i], n_real,
-                              qv_q[i], num_groups, axis)
-            for i in range(len(u_map))
-        ]
-        return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
-
-    row, vec = P(axis, None), P(axis)
-    return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(row, row, vec, P(None, axis)),
-        out_specs=_sharded_out_specs(epilogue),
-        check_vma=False,
-    )(vals, raw, baseline, gids_q)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "mesh", "func", "epilogue", "u_map", "num_groups", "is_counter",
-    "is_delta", "fetch"
-))
-def _batched_sharded_jitter_jit(mesh, func, epilogue, vals, dev, raw, wm_u,
-                                window_ms_u, gids_q, n_real, qv_q,
-                                u_map: tuple, num_groups: int,
-                                is_counter: bool, is_delta: bool,
-                                fetch: str):
-    """Series-sharded twin of _batched_jitter_jit: the replicated stacked
-    window structures ride the closure and the unrolled per-lane epilogues
-    combine over the mesh inside ONE multi-device program — mesh + jitter
-    lanes coalesce instead of dropping to per-lane dispatch."""
-    from jax.sharding import PartitionSpec as P
-
-    from .mxu_jitter import jitter_range_kernel
-
-    axis = mesh.axis_names[0]
-
-    def local(vals_l, dev_l, raw_l, gids_ql):
-        sj_u = [
-            jitter_range_kernel(
-                func, vals_l, dev_l, raw_l, *(a[u] for a in wm_u),
-                window_ms_u[u], is_counter=is_counter, is_delta=is_delta,
-                fetch=fetch,
-            )
-            for u in range(max(u_map) + 1)
-        ]
-        outs = [
-            _sharded_epilogue(sj_u[u_map[i]], epilogue, gids_ql[i], n_real,
-                              qv_q[i], num_groups, axis)
-            for i in range(len(u_map))
-        ]
-        return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
-
-    row = P(axis, None)
-    return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(row, row, row, P(None, axis)),
-        out_specs=_sharded_out_specs(epilogue),
-        check_vma=False,
-    )(vals, dev, raw, gids_q)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "mesh", "func", "epilogue", "u_map", "num_groups", "is_counter",
-    "is_delta", "fetch"
-))
-def _batched_sharded_masked_jit(mesh, func, epilogue, mba, wm_u,
-                                window_ms_u, maxdev, gids_q, n_real, qv_q,
-                                u_map: tuple, num_groups: int,
-                                is_counter: bool, is_delta: bool,
-                                fetch: str):
-    """Series-sharded twin of _batched_masked_jit (row-band sidecar
-    arrays, replicated stacked masked window structures in the closure)."""
-    from jax.sharding import PartitionSpec as P
-
-    from .mxu_jitter import jitter_masked_kernel
-
-    axis = mesh.axis_names[0]
-
-    def local(mba_l, gids_ql):
-        sj_u = [
-            jitter_masked_kernel(
-                func, *mba_l, *(a[u] for a in wm_u), window_ms_u[u],
-                is_counter=is_counter, is_delta=is_delta, fetch=fetch,
-                maxdev=maxdev,
-            )
-            for u in range(max(u_map) + 1)
-        ]
-        outs = [
-            _sharded_epilogue(sj_u[u_map[i]], epilogue, gids_ql[i], n_real,
-                              qv_q[i], num_groups, axis)
-            for i in range(len(u_map))
-        ]
-        return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
-
-    row = P(axis, None)
-    return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(tuple(row for _ in mba), P(None, axis)),
-        out_specs=_sharded_out_specs(epilogue),
-        check_vma=False,
-    )(mba, gids_q)
-
-
 _BATCH_STACK_MEMO_MAX = 64
 
 
-def _batched_stacks(block, lanes, j_pad: int, variant: str, hist: bool,
-                    mesh):
+def _batched_stacks(block, lanes, j_pad: int, body_name: str, mesh):
     """Device-resident stacked batch inputs, memoized on the block per
-    (sorted) batch composition: group-id stack [Q_pad, S], lane->unique
-    window index vector, and the unique windows' parameter vectors (or MXU
-    window-matrix / jitter-structure / hist boundary stacks). A recurring
-    dashboard round — the steady state the batcher exists for — pays ZERO
-    host->device copies after its first occurrence. qv is NOT part of the
-    memo (built per call): quantile sweeps must reuse the same stacks.
+    (sorted) batch composition: the group-id stack [Q_pad, S] and, field by
+    field, the stack over the unique windows of what the body's window hook
+    returns for one. A recurring dashboard round — the steady state the
+    batcher exists for — pays ZERO host->device copies after its first
+    occurrence. qv is NOT part of the memo (built per call): quantile
+    sweeps must reuse the same stacks.
 
-    The memo key embeds the kernel ``variant`` (mxu|jitter|masked|general —
-    the grid metadata half of the cache identity: a jittered block's
-    stacks can never serve a regular-grid program shape or vice versa) and
-    id(gids_dev) per lane; those arrays are themselves memoized on the
-    block (group_ids_memo / zero_gids), so ids are stable for the block's
-    lifetime and the key can never alias across variants."""
-    from ..singleflight import memo_on
-
+    The memo key embeds the body (the grid metadata half of the cache
+    identity: a jittered block's stacks can never serve a regular-grid
+    program shape or vice versa) and id(gids_dev) per lane; those arrays
+    are themselves memoized on the block (group_ids_memo / zero_gids), so
+    ids are stable for the block's lifetime and the key can never alias
+    across bodies."""
     sig = tuple(
         (int(l[2].start_ms - block.base_ms), int(l[2].step_ms),
          int(l[2].window_ms), id(l[0]))
         for l in lanes
     )
-    key = (variant, hist, j_pad, mesh is not None, sig)
+    key = (body_name, j_pad, mesh is not None, sig)
     cache = block.__dict__.get("_batch_stacks")
     if cache is not None and len(cache) > _BATCH_STACK_MEMO_MAX:
         cache.clear()  # bounded: stacks rebuild in one call
@@ -1638,89 +961,205 @@ def _batched_stacks(block, lanes, j_pad: int, variant: str, hist: bool,
     def build():
         padded = _pad_lanes(lanes)
         _u_idx, ukeys = _unique_windows(padded, block.base_ms)
-        st = {
-            "gids_q": jnp.stack([l[0] for l in padded]),
-        }
-        if hist and block.regular_ts is not None:
-            from .kernels import RangeParams
-
-            wins = [
-                _hist_shared_windows(
-                    block,
-                    RangeParams(so + block.base_ms, sm, j_pad, w),
-                    j_pad, mesh,
-                )
-                for so, sm, w in ukeys
-            ]
-            st.update(
-                lo_u=jnp.stack([w[0] for w in wins]),
-                hi_u=jnp.stack([w[1] for w in wins]),
-                tf_u=jnp.stack([w[2] for w in wins]),
-                tl_u=jnp.stack([w[3] for w in wins]),
-                out_t_u=jnp.stack([w[4] for w in wins]),
-                w_u=jnp.asarray(np.asarray(
-                    [w for _, _, w in ukeys], np.int32)),
+        hook = FUSED_BODIES[body_name].windows
+        wins = [hook(block, so, sm, j_pad, w, mesh) for so, sm, w in ukeys]
+        if any(w is None for w in wins):
+            # a merged window not wider than the deviation band: the
+            # per-lane dispatch degrades to the general kernel, which the
+            # batched program shape here does not model — raise so the
+            # scheduler falls back to per-lane unbatched execution
+            raise RuntimeError(
+                f"{body_name} window bound fails for a batched window"
             )
-        elif variant == "mxu":
-            from .mxu_kernels import window_matrices
-
-            wms = [
-                window_matrices(block, so, sm, j_pad, w)
-                for so, sm, w in ukeys
-            ]
-
-            def stk(attr):
-                return jnp.stack([getattr(w, attr) for w in wms])
-
-            st.update(
-                W_u=stk("dW"), F_u=stk("dF"), L_u=stk("dL"),
-                L2_u=stk("dL2"), count_u=stk("d_count"), tf_u=stk("d_tf"),
-                tl_u=stk("d_tl"), tl2_u=stk("d_tl2"),
-                out_t_u=stk("d_out_t"),
-                window_ms_u=jnp.asarray(np.asarray(
-                    [w for _, _, w in ukeys], np.float32)),
-                idx_u=stk("d_idx"),
-            )
-        elif variant in ("jitter", "masked"):
-            from .mxu_jitter import (
-                jitter_window_matrices,
-                masked_window_matrices,
-            )
-
-            build_wm = (jitter_window_matrices if variant == "jitter"
-                        else masked_window_matrices)
-            wms = [
-                build_wm(block, so, sm, j_pad, w) for so, sm, w in ukeys
-            ]
-            if not all(w.ok for w in wms):
-                # a merged window not wider than the deviation band: the
-                # per-lane dispatch degrades to the general kernel, which
-                # the batched program shape here does not model — raise so
-                # the scheduler falls back to per-lane unbatched execution
-                raise RuntimeError(
-                    f"{variant} window bound fails for a batched window"
-                )
-            take = _jwm_args if variant == "jitter" else _mwm_args
-            st.update(
-                wm_u=tuple(
-                    jnp.stack([take(w)[k] for w in wms])
-                    for k in range(len(take(wms[0])))
-                ),
-                window_ms_u=jnp.asarray(np.asarray(
-                    [w for _, _, w in ukeys], np.float32)),
-            )
-        else:
-            st.update(
-                so_u=jnp.asarray(np.asarray(
-                    [s for s, _, _ in ukeys], np.int32)),
-                sm_u=jnp.asarray(np.asarray(
-                    [s for _, s, _ in ukeys], np.int32)),
-                w_u=jnp.asarray(np.asarray(
-                    [w for _, _, w in ukeys], np.int32)),
-            )
-        return st
+        return jnp.stack([l[0] for l in padded]), tuple(
+            jnp.asarray(np.asarray(col)) if isinstance(col[0], np.generic)
+            else jnp.stack(col)
+            for col in zip(*wins)
+        )
 
     return memo_on(block, "_batch_stacks", key, build)
+
+
+def _fused_dispatch(func: str, epilogue: tuple, block, num_groups: int,
+                    is_counter: bool, is_delta: bool, name: str, mesh=None,
+                    *, gids=None, qv=None, params=None, lanes=None,
+                    j_pad=None, les=None):
+    """The ONE host-side dispatch of every fused entry point: body
+    selection (_fused_body), the degrade-and-count rules, the reduction's
+    form, the window operands, then one launch of _fused_program_jit with
+    one latency observation and one JIT hit/miss account.
+
+    Unbatched (``gids``, ``qv``, ``params``): a jitter / masked window
+    failing its safety bound degrades to the general body — the dispatch
+    STAYS one fused program, counted grid_jitter / grid_holes. Batched
+    (``lanes`` of ``(gids_padded_dev, qv, params)``, ``j_pad``): selection
+    matches the unbatched dispatch exactly, so a lane computes through the
+    same body its unbatched execution would; combinations the batched form
+    does not model — and a merged window failing the safety bound — RAISE,
+    which the scheduler turns into per-lane unbatched execution (batching
+    is an optimization, never a correctness risk).
+
+    With ``mesh`` (a 1-D device mesh matching the block's series-sharded
+    placement) the same program dispatches ONCE across every device."""
+    hist = epilogue[0] == "hist"
+    body_name, reason = _fused_body(hist, block, func, is_delta, mesh)
+    body = FUSED_BODIES[body_name]
+    if lanes is not None and not body.lanes:
+        # defensive backstop — the scheduler consults the same predicate
+        # (batch_variant_supported) before grouping, so this fires only for
+        # requests that bypassed it
+        raise RuntimeError(
+            f"batched programs do not model the {body_name} body here: "
+            "per-lane dispatch"
+        )
+    if not hist:
+        epilogue = _with_reduce_form(func, epilogue, num_groups)
+    # window structures build (memoized per block) BEFORE the timed span,
+    # for every body alike — the dispatch-latency observation must compare
+    # kernel cost across grid classes, not host-side build placement
+    u_map = batch = None
+    if lanes is not None:
+        gids, windows = _batched_stacks(block, lanes, j_pad, body_name, mesh)
+        padded = _pad_lanes(lanes)
+        u_idx, ukeys = _unique_windows(padded, block.base_ms)
+        u_map = tuple(u_idx)
+        qv = jnp.asarray(np.asarray([l[1] for l in padded], np.float32))
+        batch = f"Q{len(padded)}xU{len(ukeys)}"
+    else:
+        j_pad = pad_steps(params.num_steps)
+        window = (int(params.start_ms - block.base_ms), params.step_ms,
+                  j_pad, params.window_ms, mesh)
+        windows = body.windows(block, *window)
+        if windows is None:
+            reason = body.degrade
+            body_name = "hist_general" if hist else "general"
+            body = FUSED_BODIES[body_name]
+            windows = body.windows(block, *window)
+    if reason is not None:
+        # degraded-kernel taxonomy: the dispatch STAYS one fused program
+        # (the general kernel), it just lost the jitter-tolerant fast
+        # variant — reserved for truly unsupported shapes (doc/perf.md).
+        # Batched lanes degrade exactly like their unbatched executions
+        # would, counted once per launch
+        record_fused_fallback(reason)
+    t0 = time.perf_counter()
+    spec = FusedSpec(
+        body_name, func, epilogue, num_groups,
+        body.statics(block, j_pad, is_counter, is_delta), mesh, u_map,
+    )
+    before = _fused_program_jit._cache_size()
+    out = _fused_program_jit(
+        spec, body.rows(block), windows, gids,
+        les if hist else np.int32(block.n_series), qv,
+    )
+    record_kernel_dispatch(
+        ("batch_" if lanes is not None else "")
+        + ("mesh_" if mesh is not None else "") + name,
+        time.perf_counter() - t0,
+        compiled=_fused_program_jit._cache_size() > before,
+        key=_exec_key_parts(body.variant, epilogue, block, j_pad, num_groups,
+                            mesh, batch),
+    )
+    return out
+
+
+def fused_range_aggregate(func: str, op: str, block, gids_padded,
+                          num_groups: int, params, is_counter: bool = False,
+                          is_delta: bool = False, mesh=None):
+    """One device dispatch for ``op by (...) (func(selector[w]))`` over a
+    staged (super)block: returns the [G, J_pad] group partials on device.
+
+    ``gids_padded`` is [S_padded] int32 with padded rows assigned the trash
+    group ``num_groups``. Regular shared grids ride the MXU window-matrix
+    kernel (matrices cached device-resident on the block); everything else
+    runs the general compare-and-reduce kernel. With ``mesh`` (the block's
+    series-sharded placement) the body runs under shard_map with a
+    psum-combined [G, J] — ONE dispatch across the whole mesh. Instrumented
+    like every other kernel entry (per-dispatch latency + JIT hit/miss)."""
+    return _fused_dispatch(
+        func, ("agg", op), block, num_groups, is_counter, is_delta,
+        f"fused_{op}_{func}", mesh, gids=gids_padded, qv=np.float32(0.0),
+        params=params,
+    )
+
+
+def zero_gids(block):
+    """All-zeros trash-group vector for epilogues that need no label
+    grouping (global topk/bottomk): unused by the epilogue math but part of
+    the shared jit signature. Memoized device-resident per block (co-placed
+    with a sharded block's series axis); also handed to the cross-query
+    batcher so identical-lane dedup keys on ONE object per block."""
+    s_pad = np.asarray(block.lens).shape[0]
+    return memo_on(
+        block, "_zero_gids", s_pad,
+        lambda: series_put(getattr(block, "placement", None))(
+            np.zeros(s_pad, dtype=np.int32)
+        ),
+    )
+
+
+def fused_topk(func: str, block, k: int, bottom: bool, params,
+               is_counter: bool = False, is_delta: bool = False, mesh=None):
+    """One device dispatch for global ``topk(k, func(selector[w]))``:
+    returns ([k, J_pad] values, [k, J_pad] i32 series indices) on device —
+    the compact per-step winner set, O(k*J) on the wire instead of the
+    [S, J] grid AggregatePresentExec gathers. Needs no label grouping at
+    all (global top-k), so the O(S) group pass is skipped too. With
+    ``mesh`` the per-device winner state combines across devices inside
+    the same program (all_gather of [k, J] candidates + re-reduce)."""
+    return _fused_dispatch(
+        func, ("topk", int(k), bool(bottom)), block, 1, is_counter, is_delta,
+        f"fused_{'bottomk' if bottom else 'topk'}_{func}", mesh,
+        gids=zero_gids(block), qv=np.float32(0.0), params=params,
+    )
+
+
+def fused_quantile(func: str, block, gids_padded, num_groups: int, q: float,
+                   params, is_counter: bool = False, is_delta: bool = False,
+                   mesh=None):
+    """One device dispatch for ``quantile(q, func(selector[w])) by (...)``:
+    range kernel -> segment_quantile inside one compiled program; only the
+    [G, J_pad] quantile grid reaches the host. ``q`` rides as a dynamic
+    argument so dashboards sweeping quantiles share one executable. With
+    ``mesh`` the exact per-group multiset is all_gather'd across devices
+    inside the same program before the sort (see _sharded_epilogue)."""
+    return _fused_dispatch(
+        func, ("quantile",), block, num_groups, is_counter, is_delta,
+        f"fused_quantile_{func}", mesh, gids=gids_padded, qv=np.float32(q),
+        params=params,
+    )
+
+
+def fused_hist_range_aggregate(func: str, block, gids_padded,
+                               num_groups: int, params, les,
+                               q: float | None = None,
+                               is_delta: bool = False, mesh=None):
+    """One device dispatch for ``sum by (...) (hist_fn(selector[w]))`` over
+    a 3-D histogram (super)block — optionally with the device-side
+    ``histogram_quantile`` interpolation epilogue fused into the same
+    program (q != None). Returns [G, J_pad, B] group bucket partials, or
+    [G, J_pad] quantiles. ``les`` is the (unified) [B] bound vector.
+
+    Shared regular grids (the overwhelmingly common scraped-histogram case)
+    use the shared-window body: [J] boundary vectors precomputed host-side
+    and memoized device-resident on the block, skipping the O(S*J*T)
+    per-series boundary compare entirely. Near-regular (jittered scrape)
+    grids ride the shared-boundary jitter body; a grid failing the window
+    safety bound degrades to the general per-series kernel (still one
+    dispatch), counted grid_jitter.
+
+    With ``mesh`` (the block's [ΣS, T, B] series-sharded placement) the
+    hist range_fn -> per-bucket segment-sum -> psum -> (quantile) body
+    runs under shard_map — one dispatch across the mesh, with the quantile
+    interpolation evaluated on the replicated [G, J, B] partials inside
+    the same program."""
+    return _fused_dispatch(
+        func, ("hist", "quantile" if q is not None else "sum"), block,
+        num_groups, False, is_delta,
+        f"fused_hist_{'quantile_' if q is not None else ''}sum_{func}", mesh,
+        gids=gids_padded, qv=np.float32(q if q is not None else 0.0),
+        params=params, les=les,
+    )
 
 
 def fused_batched_scalar(func: str, epilogue: tuple, block, lanes,
@@ -1729,101 +1168,16 @@ def fused_batched_scalar(func: str, epilogue: tuple, block, lanes,
     """ONE device dispatch serving Q concurrent scalar fused queries over
     the SAME (super)block. ``lanes`` is a sequence of
     ``(gids_padded_dev, qv, params)`` triples — the per-query dynamics;
-    everything else (func, epilogue statics, kernel variant, j_pad) is
-    uniform across the group by construction of the coalescing key
-    (query/scheduler.py). Returns the stacked [Q_pad, ...] outputs; callers
-    take lane i's ``[:G_i]`` rows (or its [k, J] winner pair). Kernel
-    variant selection matches _fused_dispatch exactly (_grid_variant) so a
-    batched lane computes through the same kernel variant as its unbatched
-    execution would. Combinations the batched program set does not model —
-    min/max_over_time on jitter/masked grids (dedicated fused minmax
-    programs), pallas-promoted irregular grids, a merged window failing
-    the jitter safety bound — RAISE, which the scheduler turns into
-    per-lane unbatched execution (batching is an optimization, never a
-    correctness risk)."""
-    import time as _time
-
-    from ..metrics import record_kernel_dispatch
-
-    raw = block.raw if block.raw is not None else block.vals
-    n_real = np.int32(block.n_series)
-    variant, _reason = _grid_variant(block, func, is_delta)
-    if not batch_variant_supported(block, func, epilogue[0], is_delta, mesh):
-        # defensive backstop — the scheduler consults the same predicate
-        # before grouping, so this fires only for requests that bypassed it
-        raise RuntimeError(
-            f"batched programs do not model the {variant} variant here: "
-            "per-lane dispatch"
-        )
-    if _reason is not None:
-        # batched lanes degrade to the general kernel exactly like their
-        # unbatched executions would — keep the grid_* taxonomy counting
-        # (once per launch) so batched deployments don't undercount it
-        from ..metrics import record_fused_fallback
-
-        record_fused_fallback(_reason)
-    epilogue = _with_reduce_form(func, epilogue, num_groups)
-    st = _batched_stacks(block, lanes, j_pad, variant, False, mesh)
-    padded = _pad_lanes(lanes)
-    u_idx, _ukeys = _unique_windows(padded, block.base_ms)
-    u_map = tuple(u_idx)
-    qv_q = jnp.asarray(np.asarray([l[1] for l in padded], np.float32))
+    everything else (func, epilogue statics, body, j_pad) is uniform across
+    the group by construction of the coalescing key (query/scheduler.py).
+    Returns the stacked [Q_pad, ...] outputs; callers take lane i's
+    ``[:G_i]`` rows (or its [k, J] winner pair). Raises where the batched
+    form does not model the dispatch (see _fused_dispatch)."""
     kind = epilogue[1] if epilogue[0] == "agg" else epilogue[0]
-    name = f"batch_{'mesh_' if mesh is not None else ''}fused_{kind}_{func}"
-    t0 = _time.perf_counter()
-    if variant == "mxu":
-        from .mxu_kernels import fetch_strategy
-
-        args = (
-            func, epilogue, block.vals, raw, block.baseline, st["W_u"],
-            st["F_u"], st["L_u"], st["L2_u"], st["count_u"], st["tf_u"],
-            st["tl_u"], st["tl2_u"], st["out_t_u"], st["window_ms_u"],
-            st["idx_u"], st["gids_q"], n_real, qv_q, u_map,
-            num_groups, is_counter, is_delta, fetch_strategy(),
-        )
-        fn = _batched_sharded_mxu_jit if mesh is not None else _batched_mxu_jit
-    elif variant == "jitter":
-        from .mxu_kernels import fetch_strategy
-
-        args = (
-            func, epilogue, block.vals, block.ts_dev, raw, st["wm_u"],
-            st["window_ms_u"], st["gids_q"], n_real, qv_q, u_map,
-            num_groups, is_counter, is_delta, fetch_strategy(),
-        )
-        fn = (_batched_sharded_jitter_jit if mesh is not None
-              else _batched_jitter_jit)
-    elif variant == "masked":
-        from .mxu_kernels import fetch_strategy
-
-        args = (
-            func, epilogue, _mgrid_args(block.mgrid), st["wm_u"],
-            st["window_ms_u"], np.float32(block.mgrid.maxdev_ms),
-            st["gids_q"], n_real, qv_q, u_map,
-            num_groups, is_counter, is_delta, fetch_strategy(),
-        )
-        fn = (_batched_sharded_masked_jit if mesh is not None
-              else _batched_masked_jit)
-    else:
-        args = (
-            func, epilogue, block.ts, block.vals, block.lens, block.baseline,
-            raw, st["gids_q"], n_real, qv_q, st["so_u"],
-            st["sm_u"], st["w_u"], u_map, j_pad, num_groups, is_counter,
-            is_delta,
-        )
-        fn = (_batched_sharded_general_jit if mesh is not None
-              else _batched_general_jit)
-    if mesh is not None:
-        args = (mesh,) + args
-    before = fn._cache_size()
-    out = fn(*args)
-    record_kernel_dispatch(
-        name, _time.perf_counter() - t0, compiled=fn._cache_size() > before,
-        key=_exec_key_parts(
-            variant, epilogue, block, j_pad, num_groups, mesh,
-            batch=f"Q{len(padded)}xU{len(_ukeys)}",
-        ),
+    return _fused_dispatch(
+        func, epilogue, block, num_groups, is_counter, is_delta,
+        f"fused_{kind}_{func}", mesh, lanes=lanes, j_pad=j_pad,
     )
-    return out
 
 
 def fused_batched_hist(func: str, block, lanes, num_groups: int, j_pad: int,
@@ -1836,56 +1190,12 @@ def fused_batched_hist(func: str, block, lanes, num_groups: int, j_pad: int,
     from the _hist_shared_windows memo; per-lane q rides the dynamic qv
     axis so dashboards sweeping quantiles share one program AND one range
     grid."""
-    import time as _time
-
-    from ..metrics import record_kernel_dispatch
-    from .hist_kernels import (
-        _batched_hist_jit,
-        _batched_hist_shared_jit,
-        _batched_hist_shared_sharded_jit,
-        _batched_hist_sharded_jit,
+    return _fused_dispatch(
+        func, ("hist", "quantile" if quantile else "sum"), block, num_groups,
+        False, is_delta,
+        f"fused_hist_{'quantile_' if quantile else ''}sum_{func}", mesh,
+        lanes=lanes, j_pad=j_pad, les=les,
     )
-
-    shared = block.regular_ts is not None
-    if not batch_variant_supported(block, func, "hist", is_delta, mesh):
-        # unbatched hist dispatch takes the jitter shared-boundary variant
-        # on near-regular grids (fused_hist_range_aggregate); the batched
-        # program set does not model it — defensive backstop behind the
-        # scheduler's pre-grouping check (same predicate)
-        raise RuntimeError("jittered hist grid: per-lane dispatch")
-    st = _batched_stacks(block, lanes, j_pad, "general", True, mesh)
-    padded = _pad_lanes(lanes)
-    u_idx, _ukeys = _unique_windows(padded, block.base_ms)
-    u_map = tuple(u_idx)
-    qv_q = jnp.asarray(np.asarray([l[1] for l in padded], np.float32))
-    name = (f"batch_{'mesh_' if mesh is not None else ''}fused_hist_"
-            f"{'quantile_' if quantile else ''}sum_{func}")
-    t0 = _time.perf_counter()
-    if shared:
-        args = (func, block.vals, st["lo_u"], st["hi_u"], st["tf_u"],
-                st["tl_u"], st["out_t_u"], st["w_u"], st["gids_q"], les,
-                qv_q, u_map, num_groups, is_delta, quantile)
-        fn = (_batched_hist_shared_sharded_jit if mesh is not None
-              else _batched_hist_shared_jit)
-    else:
-        args = (func, block.ts, block.vals, block.lens, st["gids_q"], les,
-                qv_q, st["so_u"], st["sm_u"], st["w_u"], u_map,
-                j_pad, num_groups, is_delta, quantile)
-        fn = (_batched_hist_sharded_jit if mesh is not None
-              else _batched_hist_jit)
-    if mesh is not None:
-        args = (mesh,) + args
-    before = fn._cache_size()
-    out = fn(*args)
-    record_kernel_dispatch(
-        name, _time.perf_counter() - t0, compiled=fn._cache_size() > before,
-        key=_exec_key_parts(
-            "hist_shared" if shared else "hist_general",
-            ("hist", "quantile" if quantile else "sum"), block, j_pad,
-            num_groups, mesh, batch=f"Q{len(padded)}xU{len(_ukeys)}",
-        ),
-    )
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -1982,8 +1292,6 @@ def group_ids_memo(block, series_labels, by, without,
     contract). Misses build through the shared keyed single-flight
     (filodb_tpu/singleflight.memo_on): concurrent same-key queries must not
     each pay the O(S) regroup + device upload, nor clobber the memo dict."""
-    from ..singleflight import memo_on
-
     key = (
         tuple(by) if by else None,
         tuple(without) if without else None,
@@ -1991,8 +1299,6 @@ def group_ids_memo(block, series_labels, by, without,
     )
 
     def build():
-        from .staging import series_put
-
         labels = series_labels
         if strip_metric:
             from ..core.schemas import METRIC_TAG
@@ -2118,34 +1424,15 @@ def group_ids_for(series_labels: list[dict], by: list[str] | None, without: list
 # every jit wrapper in this module registers with the executable registry so
 # the observatory can report live in-process cache sizes per wrapper and
 # tools/check_metrics.py can lint that no jit entry point dispatches outside
-# the observatory (a new kernel added without registration fails the lint)
+# the observatory (a new kernel added without registration fails the lint).
+# The whole fused family is ONE wrapper: its cache holds every composition.
 def _register_kernel_observatory() -> None:
     from ..obs.kernels import KERNELS
 
     KERNELS.register_jits(
         "ops.aggregations",
         _segment_aggregate_jit=_segment_aggregate_jit,
-        _fused_general_jit=_fused_general_jit,
-        _fused_mxu_jit=_fused_mxu_jit,
-        _fused_jitter_jit=_fused_jitter_jit,
-        _fused_masked_jit=_fused_masked_jit,
-        _fused_jitter_minmax_jit=_fused_jitter_minmax_jit,
-        _fused_masked_minmax_jit=_fused_masked_minmax_jit,
-        _fused_pallas_jit=_fused_pallas_jit,
-        _fused_sharded_general_jit=_fused_sharded_general_jit,
-        _fused_sharded_mxu_jit=_fused_sharded_mxu_jit,
-        _fused_sharded_jitter_jit=_fused_sharded_jitter_jit,
-        _fused_sharded_masked_jit=_fused_sharded_masked_jit,
-        _fused_sharded_jitter_minmax_jit=_fused_sharded_jitter_minmax_jit,
-        _fused_sharded_masked_minmax_jit=_fused_sharded_masked_minmax_jit,
-        _batched_general_jit=_batched_general_jit,
-        _batched_mxu_jit=_batched_mxu_jit,
-        _batched_jitter_jit=_batched_jitter_jit,
-        _batched_masked_jit=_batched_masked_jit,
-        _batched_sharded_general_jit=_batched_sharded_general_jit,
-        _batched_sharded_mxu_jit=_batched_sharded_mxu_jit,
-        _batched_sharded_jitter_jit=_batched_sharded_jitter_jit,
-        _batched_sharded_masked_jit=_batched_sharded_masked_jit,
+        _fused_program_jit=_fused_program_jit,
         topk_mask=topk_mask,
         segment_quantile=segment_quantile,
     )

@@ -281,316 +281,6 @@ def _hist_range_jitter(func, vals, dev, hwa, window, is_delta: bool):
     raise ValueError(f"unknown histogram range function {func}")
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "func", "num_groups", "is_delta", "quantile"
-))
-def _fused_hist_jitter_jit(func, vals, dev, hwa, window, gids, les, qv,
-                           num_groups: int, is_delta: bool, quantile: bool):
-    """Jitter-grid twin of _fused_hist_shared_jit: shared certain-range
-    boundaries + per-series one-slot corrections, epilogue in-program."""
-    from .aggregations import _segment_aggregate_jit
-
-    sjb = _hist_range_jitter(func, vals, dev, hwa, window, is_delta)
-    S, J, B = sjb.shape
-    gjb = _segment_aggregate_jit(
-        "sum", sjb.reshape(S, J * B), gids, num_groups + 1
-    )[:num_groups].reshape(num_groups, J, B)
-    if quantile:
-        return histogram_quantile(qv, gjb, les)
-    return gjb
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "mesh", "func", "num_groups", "is_delta", "quantile"
-))
-def _fused_hist_jitter_sharded_jit(mesh, func, vals, dev, hwa, window, gids,
-                                   les, qv, num_groups: int, is_delta: bool,
-                                   quantile: bool):
-    """Series-sharded twin of _fused_hist_jitter_jit (replicated window
-    structure rides the closure; [S, T, B] vals and [S, T] dev row bands)."""
-    from jax.sharding import PartitionSpec as P
-
-    axis = mesh.axis_names[0]
-
-    def local(vals_l, dev_l, gids_l):
-        sjb = _hist_range_jitter(func, vals_l, dev_l, hwa, window, is_delta)
-        return _hist_sharded_combine(
-            sjb, gids_l, les, qv, num_groups, quantile, axis
-        )
-
-    return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(P(axis, None, None), P(axis, None), P(axis)),
-        out_specs=P(), check_vma=False,
-    )(vals, dev, gids)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "func", "num_groups", "is_delta", "quantile"
-))
-def _fused_hist_shared_jit(func, vals, lo, hi, t_first, t_last, out_t,
-                           window, gids, les, qv, num_groups: int,
-                           is_delta: bool, quantile: bool):
-    """Shared-grid twin of _fused_hist_jit (same program shape, cheaper
-    window machinery)."""
-    from .aggregations import _segment_aggregate_jit
-
-    sjb = _hist_range_shared(
-        func, vals, lo, hi, t_first, t_last, out_t, window, is_delta
-    )
-    S, J, B = sjb.shape
-    gjb = _segment_aggregate_jit(
-        "sum", sjb.reshape(S, J * B), gids, num_groups + 1
-    )[:num_groups].reshape(num_groups, J, B)
-    if quantile:
-        return histogram_quantile(qv, gjb, les)
-    return gjb
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "func", "num_steps", "num_groups", "is_delta", "quantile"
-))
-def _fused_hist_jit(func, ts, vals, lens, gids, les, qv, start_off, step_ms,
-                    window, num_steps: int, num_groups: int, is_delta: bool,
-                    quantile: bool):
-    """hist range_fn -> per-bucket segment-sum -> (optional) device-side
-    histogram_quantile interpolation as ONE compiled program: only the
-    [G, J, B] group partials — or just the [G, J] quantile grid — exist as
-    program outputs; no [S, J, B] grid ever reaches the host. ``gids``
-    follows the trash-group contract (padded rows -> group ``num_groups``);
-    per-bucket summation is the flattened [S, J*B] form of the same segment
-    reduce the reference partial-merge path runs, so the two paths agree
-    bit-for-bit on identical schemes."""
-    from .aggregations import _segment_aggregate_jit
-
-    sjb = hist_range_kernel(
-        func, ts, vals, lens, start_off, step_ms, window, num_steps,
-        is_delta=is_delta,
-    )
-    S, J, B = sjb.shape
-    gjb = _segment_aggregate_jit(
-        "sum", sjb.reshape(S, J * B), gids, num_groups + 1
-    )[:num_groups].reshape(num_groups, J, B)
-    if quantile:
-        return histogram_quantile(qv, gjb, les)
-    return gjb
-
-
-def _hist_sharded_combine(sjb, gids_l, les, qv, num_groups: int,
-                          quantile: bool, axis: str):
-    """Local per-bucket segment-sum + psum over the mesh axis, then the
-    (optional) histogram_quantile interpolation on the REPLICATED [G, J, B]
-    partials — all inside the shard_map body, so the whole hist pipeline
-    stays one multi-device program. NaN-absence semantics match
-    _segment_aggregate_jit's "sum" (a group with no members anywhere is
-    NaN), via psum'd validity counts."""
-    S, J, B = sjb.shape
-    with jax.named_scope("group_reduce"):
-        flat = sjb.reshape(S, J * B)
-        valid = ~jnp.isnan(flat)
-        s = jax.ops.segment_sum(
-            jnp.where(valid, flat, 0.0), gids_l, num_groups + 1
-        )
-        c = jax.ops.segment_sum(
-            valid.astype(flat.dtype), gids_l, num_groups + 1)
-        s = jax.lax.psum(s, axis)
-        c = jax.lax.psum(c, axis)
-        gjb = jnp.where(c > 0, s, jnp.nan)[:num_groups].reshape(
-            num_groups, J, B
-        )
-    if quantile:
-        return histogram_quantile(qv, gjb, les)
-    return gjb
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "mesh", "func", "num_groups", "is_delta", "quantile"
-))
-def _fused_hist_shared_sharded_jit(mesh, func, vals, lo, hi, t_first, t_last,
-                                   out_t, window, gids, les, qv,
-                                   num_groups: int, is_delta: bool,
-                                   quantile: bool):
-    """Series-sharded twin of _fused_hist_shared_jit: the shared-grid hist
-    range kernel runs on each device's [S_l, T, B] row band (the [J]
-    boundary vectors are replicated closures) and the per-bucket partials
-    psum across the mesh inside the same program."""
-    from jax.sharding import PartitionSpec as P
-
-    axis = mesh.axis_names[0]
-
-    def local(vals_l, gids_l):
-        sjb = _hist_range_shared(
-            func, vals_l, lo, hi, t_first, t_last, out_t, window, is_delta
-        )
-        return _hist_sharded_combine(
-            sjb, gids_l, les, qv, num_groups, quantile, axis
-        )
-
-    return jax.shard_map(
-        local, mesh=mesh, in_specs=(P(axis, None, None), P(axis)),
-        out_specs=P(), check_vma=False,
-    )(vals, gids)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "mesh", "func", "num_steps", "num_groups", "is_delta", "quantile"
-))
-def _fused_hist_sharded_jit(mesh, func, ts, vals, lens, gids, les, qv,
-                            start_off, step_ms, window, num_steps: int,
-                            num_groups: int, is_delta: bool, quantile: bool):
-    """Series-sharded twin of _fused_hist_jit (general per-series window
-    boundaries)."""
-    from jax.sharding import PartitionSpec as P
-
-    axis = mesh.axis_names[0]
-
-    def local(ts_l, vals_l, lens_l, gids_l):
-        sjb = hist_range_kernel(
-            func, ts_l, vals_l, lens_l, start_off, step_ms, window,
-            num_steps, is_delta=is_delta,
-        )
-        return _hist_sharded_combine(
-            sjb, gids_l, les, qv, num_groups, quantile, axis
-        )
-
-    return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(P(axis, None), P(axis, None, None), P(axis), P(axis)),
-        out_specs=P(), check_vma=False,
-    )(ts, vals, lens, gids)
-
-
-# -- cross-query batched twins (query/scheduler.py; see the batched-dispatch
-# -- contract in ops/aggregations.py: lanes UNROLL with the exact
-# -- single-query math, range grids computed once per unique window,
-# -- num_groups = the group's shared pow2 bucket) ---------------------------
-
-
-def _hist_epilogue(sjb, gids, les, qv, num_groups: int, quantile: bool):
-    """One lane's per-bucket segment-sum (+ optional quantile
-    interpolation) — the identical computation _fused_hist_jit runs."""
-    from .aggregations import _segment_aggregate_jit
-
-    S, J, B = sjb.shape
-    gjb = _segment_aggregate_jit(
-        "sum", sjb.reshape(S, J * B), gids, num_groups + 1
-    )[:num_groups].reshape(num_groups, J, B)
-    if quantile:
-        return histogram_quantile(qv, gjb, les)
-    return gjb
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "func", "u_map", "num_groups", "is_delta", "quantile"
-))
-def _batched_hist_shared_jit(func, vals, lo_u, hi_u, tf_u, tl_u, out_t_u,
-                             w_u, gids_q, les, qv_q, u_map: tuple,
-                             num_groups: int, is_delta: bool,
-                             quantile: bool):
-    sjb_u = [
-        _hist_range_shared(
-            func, vals, lo_u[u], hi_u[u], tf_u[u], tl_u[u], out_t_u[u],
-            w_u[u], is_delta
-        )
-        for u in range(max(u_map) + 1)
-    ]
-    return jnp.stack([
-        _hist_epilogue(sjb_u[u_map[i]], gids_q[i], les, qv_q[i],
-                       num_groups, quantile)
-        for i in range(len(u_map))
-    ])
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "func", "u_map", "num_steps", "num_groups", "is_delta", "quantile"
-))
-def _batched_hist_jit(func, ts, vals, lens, gids_q, les, qv_q, so_u, sm_u,
-                      w_u, u_map: tuple, num_steps: int, num_groups: int,
-                      is_delta: bool, quantile: bool):
-    sjb_u = [
-        hist_range_kernel(
-            func, ts, vals, lens, so_u[u], sm_u[u], w_u[u], num_steps,
-            is_delta=is_delta,
-        )
-        for u in range(max(u_map) + 1)
-    ]
-    return jnp.stack([
-        _hist_epilogue(sjb_u[u_map[i]], gids_q[i], les, qv_q[i],
-                       num_groups, quantile)
-        for i in range(len(u_map))
-    ])
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "mesh", "func", "u_map", "num_groups", "is_delta", "quantile"
-))
-def _batched_hist_shared_sharded_jit(mesh, func, vals, lo_u, hi_u, tf_u,
-                                     tl_u, out_t_u, w_u, gids_q, les, qv_q,
-                                     u_map: tuple, num_groups: int,
-                                     is_delta: bool, quantile: bool):
-    from jax.sharding import PartitionSpec as P
-
-    axis = mesh.axis_names[0]
-
-    def local(vals_l, gids_ql):
-        sjb_u = [
-            _hist_range_shared(
-                func, vals_l, lo_u[u], hi_u[u], tf_u[u], tl_u[u],
-                out_t_u[u], w_u[u], is_delta
-            )
-            for u in range(max(u_map) + 1)
-        ]
-        return jnp.stack([
-            _hist_sharded_combine(
-                sjb_u[u_map[i]], gids_ql[i], les, qv_q[i], num_groups,
-                quantile, axis
-            )
-            for i in range(len(u_map))
-        ])
-
-    return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(P(axis, None, None), P(None, axis)),
-        out_specs=P(), check_vma=False,
-    )(vals, gids_q)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "mesh", "func", "u_map", "num_steps", "num_groups", "is_delta",
-    "quantile"
-))
-def _batched_hist_sharded_jit(mesh, func, ts, vals, lens, gids_q, les, qv_q,
-                              so_u, sm_u, w_u, u_map: tuple,
-                              num_steps: int, num_groups: int,
-                              is_delta: bool, quantile: bool):
-    from jax.sharding import PartitionSpec as P
-
-    axis = mesh.axis_names[0]
-
-    def local(ts_l, vals_l, lens_l, gids_ql):
-        sjb_u = [
-            hist_range_kernel(
-                func, ts_l, vals_l, lens_l, so_u[u], sm_u[u], w_u[u],
-                num_steps, is_delta=is_delta,
-            )
-            for u in range(max(u_map) + 1)
-        ]
-        return jnp.stack([
-            _hist_sharded_combine(
-                sjb_u[u_map[i]], gids_ql[i], les, qv_q[i], num_groups,
-                quantile, axis
-            )
-            for i in range(len(u_map))
-        ])
-
-    return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(P(axis, None), P(axis, None, None), P(axis),
-                  P(None, axis)),
-        out_specs=P(), check_vma=False,
-    )(ts, vals, lens, gids_q)
-
-
 def run_hist_range_function(
     func: str, block: StagedBlock, params: RangeParams, is_delta: bool = False
 ):
@@ -610,7 +300,8 @@ def run_hist_range_function(
 
 
 # kernel-observatory registration (obs/kernels.py; linted by
-# tools/check_metrics.py — every jit wrapper here must register)
+# tools/check_metrics.py — every jit wrapper here must register). The fused
+# hist programs are compositions of ops/aggregations._fused_program_jit.
 def _register_kernel_observatory() -> None:
     from ..obs.kernels import KERNELS
 
@@ -619,16 +310,6 @@ def _register_kernel_observatory() -> None:
         hist_range_kernel=hist_range_kernel,
         histogram_quantile=histogram_quantile,
         histogram_fraction=histogram_fraction,
-        _fused_hist_jit=_fused_hist_jit,
-        _fused_hist_shared_jit=_fused_hist_shared_jit,
-        _fused_hist_jitter_jit=_fused_hist_jitter_jit,
-        _fused_hist_jitter_sharded_jit=_fused_hist_jitter_sharded_jit,
-        _fused_hist_shared_sharded_jit=_fused_hist_shared_sharded_jit,
-        _fused_hist_sharded_jit=_fused_hist_sharded_jit,
-        _batched_hist_jit=_batched_hist_jit,
-        _batched_hist_shared_jit=_batched_hist_shared_jit,
-        _batched_hist_shared_sharded_jit=_batched_hist_shared_sharded_jit,
-        _batched_hist_sharded_jit=_batched_hist_sharded_jit,
     )
 
 
