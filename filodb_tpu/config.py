@@ -170,7 +170,9 @@ DEFAULTS: dict = {
     # partials are DELTA-maintained on ingest append and served by push
     # (SSE fan-out) — plus the recording-rules API. Promotion needs
     # promote_min_count recurrences inside promote_window_s from a query
-    # whose grid end trails wall clock by at most promote_live_lag_ms;
+    # whose grid end trails wall clock by at most promote_live_lag_ms and
+    # has been seen to advance since the key's first sighting (an end that
+    # stands still is a fixed range, not a dashboard at the live edge);
     # auto-promoted queries demote after demote_idle_s of no recurrence
     # and no subscribers (hysteresis). max_subscribers bounds SSE fan-out
     # per standing query; key_ring_max bounds the scheduler's retained

@@ -162,6 +162,7 @@ HELP_TEXTS: dict[str, str] = {
     "filodb_stage_part_seconds": "Where the stage phase went, per execution (lookup|gather|assemble|h2d_shard|readback|concat|h2d_super); the parts sum to at most the stage phase.",
     "filodb_stage_h2d_bytes": "Bytes a cold stage uploaded to the device, by part (h2d_shard = per-shard blocks, h2d_super = a host-assembled superblock, a masked sidecar, the le vector).",
     "filodb_stage_d2h_bytes": "Bytes a cold stage read back from device-resident staged arrays that have no host mirror (the first np.asarray of each).",
+    "filodb_stage_mirror_bytes": "Bytes of host mirrors (kept for in-place append repairs) by site (shard = a shard's staged block, super = a superblock) and what became of them (aliased = the staged arrays themselves, no copy; copied = explicit copies, CPU backend; deferred = not made at a device assembly; materialized = made at a deferred mirror's first extension).",
     "filodb_superblock_assembled": "Superblocks built, by where their arrays were concatenated (device = from the shards' device-resident blocks, nothing uploaded again; host = concatenated on the host and uploaded).",
     "filodb_query_wait_seconds": "Per-caller wait for work another caller runs, by kind (coalesced = a follower of an identical in-flight query).",
     "filodb_http_request_seconds": "Handler wall of a query route, entry to return, per caller (route = query_range|query).",
@@ -470,12 +471,16 @@ QUERY_PHASES = (
 # - lookup     — part-key index lookups, per shard
 # - gather     — the per-partition ``samples_in_range`` loop (chunk decode)
 # - assemble   — pad into [S, T(, B)] blocks, bucket-scheme unify, labels
-# - h2d_shard  — per-shard block: host mirror copies + ``device_put``
+# - h2d_shard  — per-shard block: ``device_put`` (and, on the CPU backend
+#                only, the host mirror copies: elsewhere the staged arrays
+#                are the mirrors)
 # - readback   — taking staged arrays on the host (``ST.read_back``): a
 #                mirror is an attribute away; an array without one is a D2H
 #                copy the first time, and waits for the upload it reads
 # - concat     — row-concatenate the shard blocks on the host: the whole
-#                superblock, or only its mirrors when the device assembles it
+#                superblock, or only what the grid classification reads
+#                when the device assembles it; and the deferred mirrors'
+#                host copy at a superblock's first extension
 # - h2d_super  — the superblock onto the device: assembled there from the
 #                shards' blocks, or uploaded; and the ``le`` vector's upload
 STAGE_PARTS = (

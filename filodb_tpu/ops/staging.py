@@ -29,6 +29,7 @@ from __future__ import annotations
 import functools
 import threading
 import time
+import weakref
 from collections import OrderedDict
 from dataclasses import dataclass
 
@@ -76,6 +77,15 @@ def series_put(mesh):
 
     sharding = NamedSharding(mesh, PartitionSpec(mesh.axis_names[0]))
     return lambda a: jax.device_put(a, sharding)
+
+
+def _put_may_alias(placement) -> bool:
+    """Whether ``device_put`` onto a block placement (a mesh, or None for
+    the default device) may hand back the numpy memory it was given: the CPU
+    backend's zero-copy. A TPU's memory is its own."""
+    devices = (placement.devices.flat if placement is not None
+               else jax.devices()[:1])
+    return any(d.platform == "cpu" for d in devices)
 
 
 def replicated_put(mesh):
@@ -452,7 +462,15 @@ class StagedBlock:
         windows staged to HBM'); returns self for chaining. ``keep_host``
         retains mutable host mirrors so cached blocks can be incrementally
         APPENDED to when live samples arrive (append_to_block) instead of
-        fully restaged.
+        fully restaged. A mirror costs nothing where ``device_put`` cannot
+        alias host memory (:func:`_put_may_alias`: any backend but the
+        CPU's): the numpy arrays the device arrays are about to replace ARE
+        the mirrors, untouched (``aliased``). On the CPU backend they are
+        explicit copies (``copied``), since the upload may be the same
+        memory and a repair writes the mirrors while older device arrays
+        are still read. ``self.mirrored`` says which, in bytes, for the
+        caller to book (:func:`book_mirrors`). The first repair waits for
+        the upload before it writes (:func:`_append_to_parts`).
 
         ``mesh`` partitions the SERIES axis across a device mesh
         (``NamedSharding``, ``PartitionSpec(axis)`` on the leading dim of
@@ -462,16 +480,21 @@ class StagedBlock:
         if mesh is not None:
             self.placement = mesh
         if keep_host:
-            # explicit copies: jax.device_put on the CPU backend can alias
-            # numpy memory, and the mirrors get mutated by append repairs
-            # while older device arrays may still be in flight
-            self.h_ts = np.array(self.ts, copy=True)
-            self.h_vals = np.array(self.vals, copy=True)
-            self.h_lens = np.array(self.lens, copy=True)
-            self.h_raw = (np.array(self.raw, copy=True)
-                          if self.raw is not None else None)
-            self.h_dev = (np.array(self.ts_dev, copy=True)
-                          if self.ts_dev is not None else None)
+            alias = not _put_may_alias(self.placement)
+            self.mirrored = kept = {"aliased": 0, "copied": 0}
+
+            def mirror(a):
+                if a is None:
+                    return None
+                own = alias and isinstance(a, np.ndarray) and a.flags.writeable
+                kept["aliased" if own else "copied"] += int(a.nbytes)
+                return a if own else np.array(a, copy=True)
+
+            self.h_ts = mirror(self.ts)
+            self.h_vals = mirror(self.vals)
+            self.h_lens = mirror(self.lens)
+            self.h_raw = mirror(self.raw)
+            self.h_dev = mirror(self.ts_dev)
             # no repair writes a baseline: the array itself is its mirror
             self.h_base = self.baseline
         put = series_put(self.placement)
@@ -506,7 +529,9 @@ def detect_shared_grid(out_ts: np.ndarray, lens: np.ndarray, n: int,
     if n <= 0 or not (lens[:n] == lens[0]).all() or lens[0] == 0:
         return None, None, None, 0
     if not (out_ts[:n] != out_ts[0]).any():
-        return out_ts[0], None, None, 0
+        # a copy: ``out_ts`` may become a mirror that a repair writes in
+        # place, and the grid of the block it was staged for must not move
+        return out_ts[0].copy(), None, None, 0
     if lens[0] < 2:
         return None, None, None, 0
     m = int(lens[0])
@@ -720,7 +745,10 @@ def _append_to_parts(parts, block: StagedBlock, column: str,
     Mutates the big [n, T] HOST mirrors in place but only at columns >= the
     old head; the small per-series state (h_lens, cont) is copy-on-write,
     so a reader holding the OLD block — an in-flight concat_blocks as much
-    as a device-array consumer — keeps a consistent head-m view. Returns a
+    as a device-array consumer — keeps a consistent head-m view. The first
+    write is where a mirror is paid for (:func:`_writable_mirrors`): every
+    check below reads shapes and ``h_lens`` only, so a repair that declines
+    costs a deferred mirror nothing. Returns a
     NEW
     StagedBlock carrying the refreshed device arrays and extended shared
     grid — the caller swaps it into the cache entry atomically, so a
@@ -729,7 +757,9 @@ def _append_to_parts(parts, block: StagedBlock, column: str,
     caller restages from scratch:
 
     - mode must be raw/shifted/corrected (diff continuation needs state the
-      block doesn't carry) and the block host-mirrored, on a REGULAR or
+      block doesn't carry) and the block host-mirrored (``h_lens``: a
+      superblock assembled on the device has its big mirrors deferred, not
+      absent), on a REGULAR or
       NEAR-REGULAR (jittered) shared grid — the common live cases;
       masked/irregular blocks restage. Scalar [S, T] blocks support all
       three modes; histogram [S, T, B] blocks (raw cumulative bucket
@@ -746,7 +776,7 @@ def _append_to_parts(parts, block: StagedBlock, column: str,
     if mode in ("corrected", "shifted") and getattr(block, "base64", None) is None:
         return None  # exact f64 baselines required (f32 rounds +-64 at 1e9)
     jittered = block.regular_ts is None and block.nominal_ts is not None
-    if getattr(block, "h_ts", None) is None:
+    if getattr(block, "h_lens", None) is None:
         return None
     if block.regular_ts is None and not jittered:
         return None
@@ -754,10 +784,10 @@ def _append_to_parts(parts, block: StagedBlock, column: str,
         return None
     if block.n_series == 0:
         return None
-    is_hist = block.h_vals.ndim == 3
+    is_hist = block.vals.ndim == 3
     if is_hist and (mode != "raw" or jittered):
         return None
-    if not is_hist and block.h_vals.ndim != 2:
+    if not is_hist and block.vals.ndim != 2:
         return None
     n = block.n_series
     lens = block.h_lens
@@ -807,7 +837,7 @@ def _append_to_parts(parts, block: StagedBlock, column: str,
         if V0.ndim != (3 if is_hist else 2):
             uniform = False
             V0 = None
-        elif is_hist and V0.shape[2] != block.h_vals.shape[2]:
+        elif is_hist and V0.shape[2] != block.vals.shape[2]:
             return None  # bucket scheme width changed: restage
         elif not is_hist and np.isnan(V0).any():
             uniform = False  # staleness markers: per-series filtering
@@ -826,7 +856,7 @@ def _append_to_parts(parts, block: StagedBlock, column: str,
             if getattr(vals, "ndim", 1) != (2 if is_hist else 1):
                 return None
             if is_hist:
-                if vals.shape[1] != block.h_vals.shape[2]:
+                if vals.shape[1] != block.vals.shape[2]:
                     return None  # bucket scheme width changed: restage
             else:
                 keep = ~np.isnan(vals)
@@ -844,7 +874,7 @@ def _append_to_parts(parts, block: StagedBlock, column: str,
     if k == 0:
         return block  # nothing new in this block's range: still clean
     new_ts = per_ts[0]
-    T = block.h_ts.shape[1]
+    T = block.ts.shape[1]
     if m + k > T:
         return None  # padded width exhausted: restage with a bigger T
     if jittered:
@@ -870,6 +900,7 @@ def _append_to_parts(parts, block: StagedBlock, column: str,
     # repair a handful of [n, k] array ops, not n small python loops
     V = (V0 if V0 is not None else np.stack(per_vals)).astype(np.float64)
     # [n, k] ([n, k, B] hist)
+    _writable_mirrors(block)
     if jittered:
         block.h_ts[:n, m : m + k] = (OFF).astype(np.int32)
         block.h_dev[:n, m : m + k] = dev_new.astype(np.float32)
@@ -1302,7 +1333,7 @@ def concat_blocks(blocks, force_raw: bool = False,
     rows inert.
 
     ``full=False`` is for a caller that assembles the arrays on the device
-    (:func:`build_superblock`) and keeps no mirror: only what the grid
+    (:func:`build_superblock`) and defers the mirrors: only what the grid
     classification reads is concatenated — ``lens`` always, ``ts`` unless
     the members share a regular grid, ``vals`` / ``raw`` for a masked build
     — and the other arrays of the result are ``None``."""
@@ -1411,21 +1442,28 @@ def _concat_rows(real, host, rows: int, fields, T: int,
     staged block holds padding, and its mirror may hold what a later repair
     wrote (:func:`_append_to_parts`), which is not this block's. The arrays
     are written once and belong to the caller: they become the superblock's
-    arrays on the host path and its mirrors on the device path."""
+    arrays on the host path, and on the device path the mirrors it has from
+    the start (the others wait: :func:`materialize_mirrors`)."""
     want = dict.fromkeys(tuple(fields) + ("lens",) + (
         ("vals",) if "raw" in fields else ()))
     missing = [f for f in want if f not in host[0]]
     if missing:
         for have, got in zip(host, read_back(real, *missing)):
             have.update(zip(missing, got))
+    return _write_bands([(b.n_series, have) for b, have in zip(real, host)],
+                        rows, fields, T, buckets)
+
+
+def _write_bands(bands, rows: int, fields, T: int, buckets: tuple) -> list:
+    """:func:`_concat_rows`' one write: ``bands`` are, per member, its series
+    count and its host arrays by field (``lens`` among them)."""
     shapes = {"ts": (rows, T), "vals": (rows, T) + buckets, "raw": (rows, T),
               "lens": (rows,), "baseline": (rows,) + buckets}
     out = [np.full(shapes[f], TS_PAD, np.int32) if f == "ts"
            else np.zeros(shapes[f], np.int32 if f == "lens" else np.float32)
            for f in fields]
     o = 0
-    for b, have in zip(real, host):
-        k = b.n_series
+    for k, have in bands:
         w = int(have["lens"][:k].max()) if k else 0
         for f, dst in zip(fields, out):
             src = have[f] if have[f] is not None else have["vals"]
@@ -1508,8 +1546,7 @@ def _one_device(real) -> bool:
     return len(devices) == 1
 
 
-def build_superblock(blocks, mesh=None,
-                     keep_host: bool = True) -> tuple[StagedBlock, int]:
+def build_superblock(blocks, mesh=None) -> tuple[StagedBlock, int]:
     """The fused aggregate's superblock, resident where its kernels run:
     ``(block, bytes uploaded for it)``. One algorithm — row-concatenate, pad,
     classify the grid (:func:`concat_blocks`) — whose copy of the big arrays
@@ -1518,16 +1555,19 @@ def build_superblock(blocks, mesh=None,
     - every member on ONE device and no mesh (blocks straight out of the
       stage cache): :func:`assemble_rows` builds the device arrays from the
       members' device arrays, bit for bit what the host concatenation and a
-      ``device_put`` give. The host concatenates, once and from the members'
-      mirrors, only what it keeps: the superblock's own mirrors
-      (``keep_host``, for :func:`extend_superblock`) and what the grid
-      classification reads. Nothing but a masked sidecar is uploaded;
+      ``device_put`` give. The host concatenates only what the grid
+      classification reads (``lens`` always), and that is all the mirror the
+      block has: the big ones are ``deferred`` until an extension first
+      writes them (:func:`materialize_mirrors`), which a historical panel
+      never does. Nothing but a masked sidecar is uploaded;
     - otherwise (a member made on the host after staging — a remapped bucket
       scheme, a ``le=`` slice — or a mesh placement): concatenate on the
-      host and upload the whole.
+      host and upload the whole; what was concatenated is the mirror
+      (:meth:`StagedBlock.to_device`).
 
     Books ``stage:concat`` (the host's part) and ``stage:h2d_super`` (the
-    device's), and one ``filodb_superblock_assembled_total{where}``."""
+    device's), one ``filodb_superblock_assembled_total{where}``, and what
+    became of the mirrors (:func:`book_mirrors`, ``site="super"``)."""
     real = _members(blocks)
     multiple = mesh.devices.size if mesh is not None else 1
     rows = _padded_rows(real, multiple)
@@ -1535,24 +1575,29 @@ def build_superblock(blocks, mesh=None,
                  and max(b.ts.shape[0] for b in real) <= rows)
     with span("stage:concat", part="concat"):
         out = concat_blocks(blocks, series_multiple=multiple,
-                            full=keep_host or not on_device)
+                            full=not on_device)
     with span("stage:h2d_super", part="h2d_super"):
         if on_device:
-            uploaded = _assemble_on_device(out, real, rows, keep_host)
+            uploaded = _assemble_on_device(out, real, rows)
         else:
-            out.to_device(keep_host=keep_host, mesh=mesh)
+            out.to_device(keep_host=True, mesh=mesh)
             uploaded = staged_nbytes(out)
+    book_mirrors("super", out.mirrored)
     REGISTRY.counter("filodb_superblock_assembled",
                      where="device" if on_device else "host").inc()
     return out, uploaded
 
 
-def _assemble_on_device(out: StagedBlock, real, rows: int,
-                        keep_host: bool) -> int:
+# the big arrays a device-assembled superblock may lack a mirror of
+_DEFERRED = ("ts", "vals", "raw")
+
+
+def _assemble_on_device(out: StagedBlock, real, rows: int) -> int:
     """Give the host-concatenated ``out`` its device arrays, assembled from
-    the members'; what the host concatenated becomes its mirrors, or goes.
-    Returns the bytes uploaded (the masked sidecar's; the program's
-    arguments are not counted, as for any dispatch)."""
+    the members'. What the host concatenated is a mirror already; for the
+    rest ``out`` remembers, weakly, the members' mirrors it can be copied
+    from while they live. Returns the bytes uploaded (the masked sidecar's;
+    the program's arguments are not counted, as for any dispatch)."""
     any_raw = any(b.raw is not None for b in real) and real[0].vals.ndim == 2
     members = tuple(
         (b.ts, b.vals,
@@ -1577,14 +1622,87 @@ def _assemble_on_device(out: StagedBlock, real, rows: int,
                  ["S%d" % rows] + ["%s%d" % p for p in zip("TB", vals.shape[1:])]),
              "batch": len(real)},
     )
-    if keep_host:
-        out.h_ts, out.h_vals, out.h_lens = out.ts, out.vals, out.lens
-        out.h_raw, out.h_dev, out.h_base = out.raw, out.ts_dev, out.baseline
+    out.h_ts, out.h_vals, out.h_lens = out.ts, out.vals, out.lens
+    out.h_raw, out.h_dev, out.h_base = out.raw, out.ts_dev, out.baseline
     out.ts, out.vals, out.raw, out.lens = ts, vals, raw, lens
     out.baseline, out.ts_dev = baseline, ts_dev
+    out.mirror_sources = [
+        (b.n_series, {f: weakref.ref(a) for f in _DEFERRED
+                      if (a := getattr(b, _MIRRORS[f], None)) is not None})
+        for b in real]
+    out.mirrored = {"deferred": sum(
+        int(getattr(out, f).nbytes) for f in _deferred(out))}
     if out.mgrid is not None:
         out.mgrid.to_device()
     return _mgrid_nbytes(out.mgrid)
+
+
+def _deferred(block: StagedBlock) -> list[str]:
+    return [f for f in _DEFERRED if getattr(block, f) is not None
+            and getattr(block, _MIRRORS[f], None) is None]
+
+
+def materialize_mirrors(block: StagedBlock) -> None:
+    """Make the mirrors a device-assembled superblock deferred, when an
+    extension first writes them; a block that has them is left as it is.
+    From the members' mirrors while every one of them is still alive (the
+    shards' stage caches hold them; repairs since have written only past the
+    columns copied here): the host copy ``concat`` did not do at build, and
+    booked there. Else from the superblock's own device arrays
+    (:func:`read_back`: a D2H copy, booked under ``readback`` and in
+    ``filodb_stage_d2h_bytes_total``). Either way ``materialized``, once per
+    cache entry: the extended block carries them on."""
+    todo = _deferred(block)
+    if not todo:
+        return
+    bands = _member_bands(block)
+    if bands:
+        with span("stage:concat", part="concat"):
+            made = _write_bands(bands, block.ts.shape[0], todo,
+                                block.ts.shape[1], tuple(block.vals.shape[2:]))
+    else:
+        # np.asarray of a device array is jax's own cached, read-only copy
+        made = [np.array(a, copy=True) for a in read_back([block], *todo)[0]]
+    for f, a in zip(todo, made):
+        setattr(block, _MIRRORS[f], a)
+    book_mirrors("super", {"materialized": sum(int(a.nbytes) for a in made)})
+
+
+def _member_bands(block: StagedBlock) -> list | None:
+    """:func:`_write_bands`' input from the members' mirrors a
+    device-assembled ``block`` remembers, cut to the heads it was built at
+    (its own ``h_lens``); None when any of them has gone."""
+    bands, o = [], 0
+    for k, refs in block.__dict__.pop("mirror_sources", ()):
+        have = {f: ref() for f, ref in refs.items()}
+        if not {"ts", "vals"} <= have.keys() or any(
+                a is None for a in have.values()):
+            return None
+        have.setdefault("raw", None)  # no sidecar: the member's vals serve
+        bands.append((k, dict(have, lens=block.h_lens[o : o + k])))
+        o += k
+    return bands
+
+
+def _writable_mirrors(block: StagedBlock) -> None:
+    """Before a repair's first in-place write: the mirrors exist, and the
+    upload that may still be reading the same host memory (an ``aliased``
+    mirror, :meth:`StagedBlock.to_device`) has finished. By the time a
+    scrape arrives the device arrays are long ready, so the wait is free."""
+    materialize_mirrors(block)
+    jax.block_until_ready((block.ts, block.vals, block.raw, block.ts_dev))
+
+
+def book_mirrors(site: str, kept: dict) -> None:
+    """``filodb_stage_mirror_bytes_total{site, how}``: what became of the
+    host mirrors of a shard's staged block (``site="shard"``) or of a
+    superblock (``"super"``), in bytes by ``how`` — ``aliased`` | ``copied``
+    at an upload, ``deferred`` at a device assembly, ``materialized`` at a
+    deferred mirror's first extension."""
+    for how, nbytes in kept.items():
+        if nbytes:
+            REGISTRY.counter("filodb_stage_mirror_bytes",
+                             site=site, how=how).inc(nbytes)
 
 
 def _superblock_cache_walker(cache) -> int:

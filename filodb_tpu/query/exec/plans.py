@@ -322,6 +322,7 @@ def staged_block_for(ctx: "QueryContext", shard, ids, cache_key, col_name: str,
     ctx.stats.bump(bytes_staged=nbytes, cache_misses=1)
     with span("stage:h2d_shard", part="h2d_shard"):
         block.to_device(keep_host=True)  # mirrors enable append repair
+    ST.book_mirrors("shard", block.mirrored)
     REGISTRY.counter("filodb_stage_h2d_bytes", part="h2d_shard").inc(nbytes)
     # byte-budgeted eviction, oldest entry first (the staging analog of
     # BlockManager reclaim under memory pressure). All cache mutations run
@@ -1174,8 +1175,9 @@ def _key_mode(hint, stage_mode: str) -> str:
 
 
 # incremental superblock extension under live ingest (escape hatch: set
-# FILODB_SUPERBLOCK_EXTEND=0 to restore invalidate-and-rebuild; also skips
-# the superblock's host mirrors, halving its host-memory footprint)
+# FILODB_SUPERBLOCK_EXTEND=0 to restore invalidate-and-rebuild). It decides
+# nothing at build: a superblock's mirrors are made when an extension first
+# writes them (ST.materialize_mirrors), so without extensions there are none
 _SUPERBLOCK_EXTEND = os.environ.get("FILODB_SUPERBLOCK_EXTEND", "1") != "0"
 
 # aggregation ops the fused single-dispatch path computes exactly as one
@@ -1728,17 +1730,22 @@ class FusedAggregateExec(ExecPlan):
         if is_hist:
             with span("stage:assemble", part="assemble"):
                 blocks, les = _unify_hist_blocks(blocks, block_les)
-        # host mirrors ride along so live-edge ingest can EXTEND the
-        # superblock in place (ST.extend_superblock) instead of paying
-        # concat + full re-upload per append — the delta-summation move.
+        # live-edge ingest EXTENDS the superblock in place
+        # (ST.extend_superblock) instead of paying concat + full re-upload
+        # per append — the delta-summation move — through host mirrors that
+        # cost nothing until that first extension writes them: a shard
+        # block's staged arrays are its mirrors (copies on the CPU backend,
+        # whose device_put may alias them), and a superblock's are made at
+        # its first extension, from the members' while the stage caches
+        # hold them, else by one read-back. A historical panel never pays.
         # With a mesh, the series axis pads to a mesh-divisible ΣS (the
         # existing trash-group masking keeps the extra rows inert) and the
         # arrays pin SHARDED (PartitionSpec(axis) row bands) so the fused
-        # program spans every device without a gather. Without one, blocks
-        # straight out of the stage cache are concatenated on the device
-        # that holds them and nothing is uploaded again.
-        super_block, uploaded = ST.build_superblock(
-            blocks, mesh=self.mesh, keep_host=_SUPERBLOCK_EXTEND)
+        # program spans every device without a gather; that build is whole
+        # on the host, and what it concatenated is its mirror. Without one,
+        # blocks straight out of the stage cache are concatenated on the
+        # device that holds them and nothing is uploaded again.
+        super_block, uploaded = ST.build_superblock(blocks, mesh=self.mesh)
         les_dev = None
         if les is not None:
             with span("stage:h2d_super", part="h2d_super"):
@@ -1881,7 +1888,9 @@ class FusedAggregateExec(ExecPlan):
         ONE recurring key): dataset + the root span's PromQL + grid shape.
         The descriptor carries what the standing promoter needs to
         re-register the query; ``end_lag_ms`` (wall clock minus the grid
-        end) distinguishes live-edge dashboards from historical scans."""
+        end) distinguishes live-edge dashboards from historical scans, and
+        ``end_ms`` against the key's first sighting one that follows the
+        clock from a fixed range that merely ended a moment ago."""
         import time as _time
 
         if getattr(ctx, "standing_refresh", False):
@@ -1913,6 +1922,7 @@ class FusedAggregateExec(ExecPlan):
             "window_ms": self.window_ms,
             "span_ms": self.end_ms - self.start_ms,
             "end_lag_ms": now_ms - float(self.end_ms),
+            "end_ms": float(self.end_ms),
         })
 
     def do_execute(self, ctx: QueryContext) -> QueryResult:

@@ -418,9 +418,11 @@ class KeyStatsRing:
     short deque of recent observation times (the promotion-burst window)
     and the latest descriptor (promql, grid shape, live-edge lag) the
     standing-query promoter needs to re-register the query
-    (standing/registry.py). Observed on EVERY fused dispatch — batching
-    enabled or not — so promotion works on latency-critical deployments
-    that keep ``batch_window_ms`` at 0. Exposed at ``/debug/standing``
+    (standing/registry.py), beside the FIRST one: whether the end has moved
+    since tells a range that follows the clock from one that stands still.
+    Observed on EVERY fused dispatch — batching enabled or not — so
+    promotion works on latency-critical deployments that keep
+    ``batch_window_ms`` at 0. Exposed at ``/debug/standing``
     alongside the promoted/demoted registry state."""
 
     RECENT_MAX = 32  # per-entry burst window (>= any sane promote_min_count)
@@ -446,12 +448,15 @@ class KeyStatsRing:
                     "first_s": now,
                     "recent": deque(maxlen=self.RECENT_MAX),
                     "desc": None,
+                    "first_desc": None,
                 }
             e["count"] += 1
             e["last_s"] = now
             e["recent"].append(now)
             if desc is not None:
                 e["desc"] = desc
+                if e["first_desc"] is None:
+                    e["first_desc"] = desc
             self._entries[key] = e  # move-to-back = most recent
             while len(self._entries) > self.max_entries:
                 self._entries.pop(next(iter(self._entries)))
@@ -467,6 +472,7 @@ class KeyStatsRing:
             "last_s": e["last_s"],
             "recent": tuple(e["recent"]),
             "desc": e.get("desc"),
+            "first_desc": e.get("first_desc"),
         }
 
     def entries(self) -> list[tuple[Any, dict]]:

@@ -8,7 +8,9 @@ The engine between the dispatch scheduler and the fused engine (ROADMAP
   per-key recurrence ring (:class:`~filodb_tpu.query.scheduler.KeyStatsRing`)
   and promotes hot live-edge keys into registered standing queries, with
   hysteresis: promotion needs a BURST (``promote_min_count`` recurrences
-  inside ``promote_window_s``), demotion needs a long idle
+  inside ``promote_window_s``) from an end that FOLLOWS the clock (within
+  ``promote_live_lag_ms`` of it, and seen to advance since the key was
+  first sighted), demotion needs a long idle
   (``demote_idle_s``) with zero subscribers — the two thresholds never
   chase each other. Nondecomposable epilogues are remembered as demoted so
   the promoter never flaps on them.
@@ -756,6 +758,13 @@ class StandingEngine:
                 cfg["promote_live_lag_ms"]
             ):
                 continue  # historical scan, not a live-edge dashboard
+            first = e.get("first_desc") or desc
+            if not desc.get("end_ms", 0) > first.get("end_ms", 0):
+                # near the edge, but never seen to move: a fixed range
+                # looked at soon after it ended (a pinned panel, a load
+                # generator over the newest scrape). No append will reach
+                # what a standing query would keep for it
+                continue
             if not AGG.standing_delta_eligible(
                 desc.get("op", ""), desc.get("params", ()),
                 desc.get("hist_quantile"),

@@ -375,8 +375,16 @@ def test_promotion_hysteresis():
         "demote_idle_s": 600.0, "default_span_ms": 600_000,
     })
     q = "sum by (instance) (rate(http_requests_total[5m]))"
-    for _ in range(3):
-        eng.query_range(q, (now_ms - 600_000) / 1e3, now_ms / 1e3, 15)
+
+    def dashboard(promql):
+        # three polls of one panel, the end a step later each time: an end
+        # that stands still is a fixed range and never promotes
+        # (tests/test_standing_promotion.py)
+        for i in (2, 1, 0):
+            end_ms = now_ms - i * 15_000
+            eng.query_range(promql, (end_ms - 600_000) / 1e3, end_ms / 1e3, 15)
+
+    dashboard(q)
     assert se.promote_tick() == 1
     sqs = se.registry.list()
     assert len(sqs) == 1 and sqs[0].source == "promoted"
@@ -384,8 +392,7 @@ def test_promotion_hysteresis():
     assert se.promote_tick() == 0  # already registered: no re-promotion
     # nondecomposable keys are declined and remembered
     qt = "topk(2, rate(http_requests_total[5m]))"
-    for _ in range(3):
-        eng.query_range(qt, (now_ms - 600_000) / 1e3, now_ms / 1e3, 15)
+    dashboard(qt)
     assert se.promote_tick() == 0
     reasons = {d["reason"] for d in se.registry.snapshot()["demoted"]}
     assert "standing_nondecomposable" in reasons

@@ -1,8 +1,10 @@
 """The superblock is assembled where its rows already are (PERF.md 6, PR 29).
 
 ``ST.build_superblock`` concatenates the shards' staged blocks on the device
-that holds them (``ST.assemble_rows``) and the host builds only the mirrors,
-from the members' mirrors. What is held here:
+that holds them (``ST.assemble_rows``); the host concatenates only what the
+grid classification reads, and the big mirrors wait for the first extension
+(``ST.materialize_mirrors``, PR 32; ``tests/test_mirror_life.py``). What is
+held here:
 
 - the device-assembled superblock is, bit for bit and field for field, what
   the host concatenation followed by ``device_put`` gave before — against a
@@ -15,7 +17,7 @@ from the members' mirrors. What is held here:
 - a cold fused query reads nothing back (``filodb_stage_d2h_bytes_total``);
 - row offsets and counts are values of the program: selections of other
   series counts and the same padded shapes share one compile;
-- the guarantee: the mirrors the build leaves are what ``extend_superblock``
+- the guarantee: the mirrors an extension makes are what ``extend_superblock``
   mutates — after a device-assembled build, one more acknowledged scrape
   makes the next live-edge query an ``extend`` that equals a fresh restage.
 
@@ -24,6 +26,7 @@ CPU backend, small shapes. Times nothing.
 
 from __future__ import annotations
 
+import gc
 import json
 import urllib.parse
 import urllib.request
@@ -172,22 +175,55 @@ LAYOUTS = {
 }
 
 
+def _mirror_bytes(how: str) -> float:
+    return _counter("filodb_stage_mirror_bytes", site="super", how=how)
+
+
+@pytest.mark.parametrize("mirrors", ["from_members", "read_back"])
 @pytest.mark.parametrize("case", sorted(LAYOUTS))
-def test_device_assembly_is_the_host_concatenation_bit_for_bit(case):
+def test_device_assembly_is_the_host_concatenation_bit_for_bit(case, mirrors):
     kind, layout, grid = LAYOUTS[case]
     blocks = _members(kind, layout)
     before = _counter("filodb_superblock_assembled", where="device")
-    uploaded_before = _counter("filodb_stage_d2h_bytes")
+    d2h = _counter("filodb_stage_d2h_bytes")
+    deferred, made = _mirror_bytes("deferred"), _mirror_bytes("materialized")
     got, uploaded = ST.build_superblock(blocks)
     assert _counter("filodb_superblock_assembled", where="device") == before + 1
-    assert _counter("filodb_stage_d2h_bytes") == uploaded_before  # mirrors only
+    assert _counter("filodb_stage_d2h_bytes") == d2h
     assert ST.grid_class(got) == grid
     for f in ARRAYS:
         a = getattr(got, f)
         assert a is None or isinstance(a, jax.Array), f
     want = ST.concat_blocks(blocks).to_device(keep_host=True)
+    as_before = _concat_as_before(blocks)
+    # the build wrote no mirror it did not need for the classification
+    # (a masked build reads them all), and said how many bytes it spared
+    waiting = ST._deferred(got)
+    if case in ("scalar_raw_sidecar", "histogram", "empty_member",
+                "padding_passes_Sp"):  # one grid, advertised by every member
+        assert waiting == [f for f in ("ts", "vals", "raw")
+                           if getattr(got, f) is not None]
+    assert ("vals" in waiting) == (case != "unequal_lengths" and grid != "holes")
+    spared = sum(int(getattr(got, f).nbytes) for f in waiting)
+    assert _mirror_bytes("deferred") == deferred + spared
+    assert _mirror_bytes("materialized") == made
+    # ... and they are made when asked for: from the members' mirrors while
+    # those live, else read back from the superblock's own device arrays
+    if mirrors == "read_back":
+        del blocks
+        gc.collect()
+    ST.materialize_mirrors(got)
+    assert ST._deferred(got) == []
+    assert _mirror_bytes("materialized") == made + spared
+    assert _counter("filodb_stage_d2h_bytes") == d2h + (
+        spared if mirrors == "read_back" else 0)
+    ST.materialize_mirrors(got)  # once
+    assert _mirror_bytes("materialized") == made + spared
     _same_block(got, want)
-    for f, ref in _concat_as_before(blocks).items():
+    for f in MIRRORS:
+        m = getattr(got, f)
+        assert m is None or (isinstance(m, np.ndarray) and m.flags.writeable), f
+    for f, ref in as_before.items():
         _same_bits(getattr(got, f), ref, f"{f} against the loop as it was")
     # nothing but a masked sidecar crosses for a device-assembled block
     assert uploaded == ST.staged_nbytes(got) - sum(
@@ -196,16 +232,17 @@ def test_device_assembly_is_the_host_concatenation_bit_for_bit(case):
 
 
 def test_without_mirrors_no_values_are_concatenated_on_the_host(monkeypatch):
-    """``keep_host=False`` (FILODB_SUPERBLOCK_EXTEND=0): members on a shared
-    regular grid need no host copy of ts or vals at all."""
+    """Members on a shared regular grid need no host copy of ts or vals at
+    all: of the mirrors the build leaves only ``h_lens``."""
     blocks = _members("hist", LAYOUTS["histogram"][1])
     asked = []
     concat_rows = ST._concat_rows
     monkeypatch.setattr(ST, "_concat_rows", lambda real, host, rows, fields, *a: (
         asked.extend(fields), concat_rows(real, host, rows, fields, *a))[1])
-    got, _ = ST.build_superblock(blocks, keep_host=False)
+    got, _ = ST.build_superblock(blocks)
     assert asked == ["lens"]
-    assert all(getattr(got, f, None) is None for f in MIRRORS)
+    assert all(getattr(got, f, None) is None for f in MIRRORS if f != "h_lens")
+    assert isinstance(got.h_lens, np.ndarray)
     for f, ref in _concat_as_before(blocks).items():
         _same_bits(getattr(got, f), ref, f)
 

@@ -636,6 +636,10 @@ PLANNED_READS = {
     **{f"stage_{p}_ms": ("filodb_stage_part_seconds_sum", {"part": p})
        for p in STAGE_PARTS},
     "stage_h2d_mb": ("filodb_stage_h2d_bytes_total", {}),
+    # PR 32: a mirror's life (aliased|copied at a shard's block, deferred at a
+    # device-assembled superblock, materialized at its first extension)
+    "stage_mirror_mb": ("filodb_stage_mirror_bytes_total", {"site": "super"}),
+    "superblocks_assembled": ("filodb_superblock_assembled_total", {"where": "device"}),
     "coalesce_wait_ms": ("filodb_query_wait_seconds_sum", {"kind": "coalesced"}),
     "handler_ms": ("filodb_http_request_seconds_sum", {"route": "query_range"}),
     "device_ready_ms": ("filodb_transfer_ready_seconds_sum", {}),
