@@ -8,7 +8,7 @@ all: native
 
 # one build definition: filodb_tpu/native/__init__.py (NativeLib) compiles
 # each lib<stem>.so from its .cpp on first load and stamps it for this
-# machine; this target just forces the four loads up front. Without g++ the
+# machine; this target just forces the five loads up front. Without g++ the
 # runtime takes the numpy / pure-Python tiers and says so (native.tiers()).
 native:
 	python -c "from filodb_tpu import native; [print(k, '->', v) for k, v in native.tiers().items()]"

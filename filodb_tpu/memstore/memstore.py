@@ -11,6 +11,7 @@ from typing import Mapping, Sequence
 
 import numpy as np
 
+from .. import native
 from ..core.filters import ColumnFilter
 from ..core.records import RecordBatch
 from ..core.schemas import Dataset
@@ -23,6 +24,10 @@ class TimeSeriesMemStore:
         self._dataset_meta: dict[str, Dataset] = {}
         self._total_shards: dict[str, int] = {}
         self.store_config = store_config or StoreConfig()
+        # the cold stage's native pass reads this store's chunks in place
+        # (ops/staging): built (g++, once a machine) and loaded with the
+        # store, so that it is set-up and never a query
+        native.stage_lib()
 
     # -- lifecycle -----------------------------------------------------------
 

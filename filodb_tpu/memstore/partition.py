@@ -20,6 +20,37 @@ from ..core.encodings import Encoded, decode, encode_double, encode_hist, encode
 from ..core.schemas import Column, ColumnType, Schema
 
 DEFAULT_MAX_CHUNK_SIZE = 400  # samples per chunk (reference store config default)
+_NO_CLAMP = -(2**62)  # below every timestamp: a segment with no earlier one
+
+
+class ColumnArrays(dict):
+    """{column: array} of one chunk or one write buffer, with the addresses
+    the native stage pass needs kept beside the arrays (native/stage.cpp
+    reads them in place). ``segments[column] = (timestamp address, value
+    address, rows, width, ints)`` for each [rows, width] column, when both
+    arrays have the layout that pass reads: C-contiguous, int64 timestamps,
+    int64 (``ints``) or float64 values. Any other histogram column has no
+    entry, and a stage that meets one takes the Python tier. Made where the
+    arrays are made: they are immutable (a sealed chunk) or append-only (a
+    write buffer), so an address cannot go stale while this dict, which
+    holds them, lives."""
+
+    __slots__ = ("segments",)
+
+    def __init__(self, arrays: Mapping[str, np.ndarray]):
+        super().__init__(arrays)
+        self.segments = {}
+        ts = self.get("timestamp")
+        if ts is None or ts.dtype != np.int64 or ts.ndim != 1 \
+                or not ts.flags.c_contiguous:
+            return
+        for name, a in self.items():
+            ints = a.dtype == np.int64
+            if (a.ndim == 2 and len(a) == len(ts) and a.flags.c_contiguous
+                    and (ints or a.dtype == np.float64)):
+                self.segments[name] = (
+                    ts.__array_interface__["data"][0],
+                    a.__array_interface__["data"][0], len(a), a.shape[1], ints)
 
 
 @dataclass
@@ -29,10 +60,15 @@ class Chunk:
     start_ts: int
     end_ts: int
     n: int
-    # decoded columns (None if evicted to encoded-only form)
-    arrays: dict[str, np.ndarray] | None
+    # decoded columns (None if evicted to encoded-only form): set once, only
+    # ever dropped, so a reader that took the dict holds the chunk's rows
+    arrays: ColumnArrays | None
     # encoded columns (populated at seal when encode=True, or at flush)
     encoded: dict[str, Encoded] | None = None
+
+    def __post_init__(self):
+        if self.arrays is not None and not isinstance(self.arrays, ColumnArrays):
+            self.arrays = ColumnArrays(self.arrays)
 
     def column(self, name: str) -> np.ndarray:
         if self.arrays is not None:
@@ -107,7 +143,7 @@ class TimeSeriesPartition:
         self.schema = schema
         self.partkey = partkey
         self.chunks: list[Chunk] = []
-        self._buf: dict[str, np.ndarray] | None = None
+        self._buf: ColumnArrays | None = None
         self._buf_len = 0
         self.max_chunk_size = max_chunk_size
         self.encode_on_seal = encode_on_seal
@@ -134,7 +170,7 @@ class TimeSeriesPartition:
                 buf[name] = np.empty((cap, arr.shape[1]), dtype=arr.dtype)
             else:
                 buf[name] = np.empty(cap, dtype=arr.dtype)
-        self._buf = buf
+        self._buf = ColumnArrays(buf)
         self._buf_len = 0
 
     def ingest(self, timestamps: np.ndarray, values: Mapping[str, np.ndarray]) -> int:
@@ -261,6 +297,36 @@ class TimeSeriesPartition:
             empty_v = np.empty((0, ncol)) if ncol else np.empty(0)
             return np.empty(0, dtype=np.int64), empty_v
         return np.concatenate(ts_parts), np.concatenate(val_parts)
+
+    def segments_in_range(self, t0: int, t1: int, col: str) -> list[tuple]:
+        """``samples_in_range`` without touching an array: the snapshot it
+        takes, in its order, as ``(arrays, buffer length, lower clamp)`` per
+        sealed chunk that overlaps [t0, t1] and for the write buffer. The
+        caller (ops/staging's native histogram pass) searches, slices and
+        copies in one call over all series, and holds every ``arrays``
+        until that call has returned. A sealed chunk is read whole (length
+        None); the buffer comes with its snapshotted length and is gated
+        on its first and last timestamp by the reader, as above. Rows
+        before the clamp belong to an earlier segment (``sealed_end + 1``
+        for the buffer: a seal that races the read counts no row twice and
+        loses none). An encoded-only chunk is decoded here, as
+        ``Chunk.column`` does it."""
+        n = self._buf_len
+        buf = self._buf
+        chunk_list = list(self.chunks)  # real copy: no mid-iteration appends
+        out = []
+        for c in chunk_list:
+            if c.end_ts < t0 or c.start_ts > t1:
+                continue
+            arrays = c.arrays
+            if arrays is None:
+                arrays = ColumnArrays(
+                    {"timestamp": c.column("timestamp"), col: c.column(col)})
+            out.append((arrays, None, _NO_CLAMP))
+        if buf is not None and n:
+            out.append((buf, n, chunk_list[-1].end_ts + 1 if chunk_list
+                        else _NO_CLAMP))
+        return out
 
     def tail_samples(self, t0: int, t1: int, col: str) -> tuple[np.ndarray, np.ndarray]:
         """Lean ``samples_in_range`` for the live-edge append window

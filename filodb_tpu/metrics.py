@@ -163,6 +163,7 @@ HELP_TEXTS: dict[str, str] = {
     "filodb_stage_h2d_bytes": "Bytes a cold stage uploaded to the device, by part (h2d_shard = per-shard blocks, h2d_super = a host-assembled superblock, a masked sidecar, the le vector).",
     "filodb_stage_d2h_bytes": "Bytes a cold stage read back from device-resident staged arrays that have no host mirror (the first np.asarray of each).",
     "filodb_stage_mirror_bytes": "Bytes of host mirrors (kept for in-place append repairs) by site (shard = a shard's staged block, super = a superblock) and what became of them (aliased = the staged arrays themselves, no copy; copied = explicit copies, CPU backend; deferred = not made at a device assembly; materialized = made at a deferred mirror's first extension).",
+    "filodb_stage_gather_series": "Series a cold stage gathered from a shard, by the path that staged them (native = a histogram selection, one table of chunk segments and one pass of libfilodbstage; python = samples_in_range a series and the numpy pad: scalar columns, no library, arrays the pass cannot read in place, an empty selection).",
     "filodb_superblock_assembled": "Superblocks built, by where their arrays were concatenated (device = from the shards' device-resident blocks, nothing uploaded again; host = concatenated on the host and uploaded).",
     "filodb_query_wait_seconds": "Per-caller wait for work another caller runs, by kind (coalesced = a follower of an identical in-flight query).",
     "filodb_http_request_seconds": "Handler wall of a query route, entry to return, per caller (route = query_range|query).",
@@ -469,8 +470,14 @@ QUERY_PHASES = (
 # sum(parts) <= stage; what is left is bookkeeping between the parts.
 #
 # - lookup     — part-key index lookups, per shard
-# - gather     — the per-partition ``samples_in_range`` loop (chunk decode)
-# - assemble   — pad into [S, T(, B)] blocks, bucket-scheme unify, labels
+# - gather     — a histogram column: the table of chunk segments, one row a
+#                chunk or write buffer in range (addresses and bounds; no
+#                array is read). Any other stage: the per-partition
+#                ``samples_in_range`` loop (chunk decode)
+# - assemble   — a histogram column: the native pass over that table
+#                (search, cast, pad: every element of [S, T, B] written
+#                once) and the grid classification. Any other stage: the
+#                numpy pad into [S, T(, B)]. And bucket-scheme unify, labels
 # - h2d_shard  — per-shard block: ``device_put`` (and, on the CPU backend
 #                only, the host mirror copies: elsewhere the staged arrays
 #                are the mirrors)

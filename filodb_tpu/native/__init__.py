@@ -194,6 +194,73 @@ def nan_count(values: np.ndarray) -> int:
 
 
 # ---------------------------------------------------------------------------
+# Cold histogram staging (stage.cpp -> libfilodbstage.so)
+# ---------------------------------------------------------------------------
+
+# one int64 row a segment; must mirror struct Seg in stage.cpp. The last two
+# columns are stage_measure's, the rest the caller's.
+STAGE_SEG_COLS = ("row", "ts", "vals", "n", "clamp", "flags", "lo", "k")
+STAGE_INT_VALUES = 1  # the value array is int64 (a decoded chunk), else f64
+STAGE_GATED = 2       # a write buffer: skipped unless first/last ts overlap
+
+
+def _bind_stage(L) -> None:
+    L.fdb_stage_measure.restype = ctypes.c_long
+    L.fdb_stage_measure.argtypes = [
+        ctypes.c_void_p, ctypes.c_long, ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_void_p, ctypes.c_long,
+    ]
+    L.fdb_stage_fill.restype = ctypes.c_long
+    L.fdb_stage_fill.argtypes = [
+        ctypes.c_void_p, ctypes.c_long, ctypes.c_long, ctypes.c_long,
+        ctypes.c_long, ctypes.c_int64, ctypes.c_int, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+    ]
+
+
+_STAGE = NativeLib("filodbstage", "stage.cpp", ("-O3", "-march=native"),
+                   _bind_stage)
+
+
+def stage_lib():
+    """The loaded staging library, or None when unavailable. A
+    ``TimeSeriesMemStore`` forces the load where it is made, so a stage
+    finds the handle and a query never builds it."""
+    return _STAGE.load()
+
+
+def stage_measure(L, table: np.ndarray, t0: int, t1: int, S: int):
+    """Pass 1 over a [segments, 8] int64 table (``STAGE_SEG_COLS``): fills
+    its ``lo`` / ``k`` columns and returns (lens int32 [S], longest row)."""
+    if (table.dtype != np.int64 or table.ndim != 2 or not table.flags.c_contiguous
+            or table.shape[1] != len(STAGE_SEG_COLS)):
+        raise ValueError(f"stage table is not int64 [n, {len(STAGE_SEG_COLS)}]")
+    lens = np.empty(S, dtype=np.int32)
+    longest = L.fdb_stage_measure(table.ctypes.data, len(table), t0, t1,
+                                  lens.ctypes.data, S)
+    if longest < 0:
+        raise ValueError("stage table refused: rows out of order or range")
+    return lens, longest
+
+
+def stage_fill(L, table: np.ndarray, lens: np.ndarray, T: int, B: int,
+               base_ms: int, subtract: bool):
+    """Pass 2: (ts int32 [S, T], vals f32 [S, T, B], baseline f32 [S, B]),
+    every element written once by the call (the interpreter lock is released
+    for it), so nothing is pre-filled."""
+    S = len(lens)
+    out_ts = np.empty((S, T), dtype=np.int32)
+    out_vals = np.empty((S, T, B), dtype=np.float32)
+    baseline = np.empty((S, B), dtype=np.float32)
+    rc = L.fdb_stage_fill(table.ctypes.data, len(table), S, T, B, base_ms,
+                          int(subtract), lens.ctypes.data, out_ts.ctypes.data,
+                          out_vals.ctypes.data, baseline.ctypes.data)
+    if rc < 0:
+        raise ValueError("stage table changed between its two passes")
+    return out_ts, out_vals, baseline
+
+
+# ---------------------------------------------------------------------------
 # Prometheus text-exposition scanner (promparse.cpp -> libfilodbprom.so)
 # ---------------------------------------------------------------------------
 

@@ -38,6 +38,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .. import native
+from ..core.schemas import ColumnType
 from ..metrics import REGISTRY, record_kernel_dispatch, span
 
 # S pads to the next bucket; T pads to a multiple of 128 (TPU lane width)
@@ -2001,6 +2003,82 @@ class SuperblockCache:
             return len(self._d)
 
 
+def _segment_table(shard, part_ids, column: str, t0: int, t1: int):
+    """One row a chunk segment of the selection (``native.STAGE_SEG_COLS``),
+    in block-row then time order, for ``native/stage.cpp``: per series only
+    the partition's snapshot and the overlap test on each chunk's bounds; no
+    array is read. Returns ``(table, bucket width, held)``, where ``held``
+    keeps every array the table names alive (a chunk evicted or a buffer
+    sealed meanwhile must not free memory the call reads), or None for a
+    selection the pass does not take: arrays it cannot read in place
+    (``ColumnArrays.segments`` has no entry for them), or a bucket width
+    that differs between series."""
+    tab, held = [], []
+    width = 0
+    for row, pid in enumerate(part_ids):
+        for arrays, buf_len, clamp in shard.partition(int(pid)) \
+                .segments_in_range(t0, t1, column):
+            seg = arrays.segments.get(column)
+            if seg is None:
+                return None
+            ts_addr, v_addr, rows, v_width, ints = seg
+            if v_width != width:
+                if width:
+                    return None
+                width = v_width
+            flags = native.STAGE_INT_VALUES if ints else 0
+            if buf_len is not None:  # the write buffer, up to its snapshot
+                rows = min(buf_len, rows)
+                flags |= native.STAGE_GATED
+            tab.append((row, ts_addr, v_addr, rows, clamp, flags, 0, 0))
+            held.append(arrays)
+    if not tab:
+        return None
+    return np.array(tab, dtype=np.int64), width, held
+
+
+def _stage_histograms(shard, part_ids, column: str, start_ms: int,
+                      end_ms: int, mode: str, dtype):
+    """The block ``stage_histogram_series`` makes of a histogram selection,
+    bit for bit, in one pass over the shard's chunks: a table of segments
+    (``stage:gather``) and one native call that searches, casts and pads
+    (``stage:assemble``), instead of a ``(ts, vals)`` pair a series and a
+    second loop over them. None where the pass does not apply (a scalar
+    column, no library, arrays it does not read in place, nothing in
+    range): the caller's Python tier stages those."""
+    L = native.stage_lib()
+    if L is None or np.dtype(dtype) != np.float32:
+        return None
+    try:
+        ctype = shard.partition(int(part_ids[0])).schema.column(column).ctype
+    except KeyError:
+        return None
+    if ctype != ColumnType.HISTOGRAM:
+        return None
+    with span("stage:gather", part="gather"):
+        made = _segment_table(shard, part_ids, column, start_ms, end_ms)
+    if made is None:
+        return None
+    table, n_buckets, held = made
+    n = len(part_ids)
+    S = pad_series(n)
+    with span("stage:assemble", part="assemble"):
+        lens, longest = native.stage_measure(L, table, start_ms, end_ms, S)
+        if longest == 0:
+            return None  # the Python tier knows an empty selection's width
+        T = pad_time(longest)
+        out_ts, out_vals, baseline = native.stage_fill(
+            L, table, lens, T, n_buckets, start_ms,
+            mode in ("corrected", "shifted"))
+        del held  # the call has returned
+        regular, nominal, ts_dev, maxdev = detect_shared_grid(
+            out_ts, lens, n, T, S)
+        refs = [(shard.shard_num, int(pid)) for pid in part_ids]
+        return StagedBlock(out_ts, out_vals, lens, start_ms, baseline, n,
+                           refs, regular_ts=regular, nominal_ts=nominal,
+                           ts_dev=ts_dev, maxdev_ms=maxdev)
+
+
 def stage_from_shard(
     shard,
     part_ids,
@@ -2030,6 +2108,14 @@ def stage_from_shard(
     """
     if mode is None:
         mode = "corrected" if is_counter else "raw"
+    if len(part_ids):
+        block = _stage_histograms(shard, part_ids, column, start_ms, end_ms,
+                                  mode, dtype)
+        REGISTRY.counter(
+            "filodb_stage_gather_series",
+            how="python" if block is None else "native").inc(len(part_ids))
+        if block is not None:
+            return block
     series = []
     refs = []
     hist_width = None

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from filodb_tpu import metrics
+from filodb_tpu import metrics, native
 from filodb_tpu.api.http import serve_background
 from filodb_tpu.coordinator.planner import PlannerParams, QueryEngine
 from filodb_tpu.coordinator.scheduler import SingleFlight
@@ -639,6 +639,9 @@ PLANNED_READS = {
     # PR 32: a mirror's life (aliased|copied at a shard's block, deferred at a
     # device-assembled superblock, materialized at its first extension)
     "stage_mirror_mb": ("filodb_stage_mirror_bytes_total", {"site": "super"}),
+    # PR 35: series a cold stage took through the native pass, of all it staged
+    "stage_native_pct": ("filodb_stage_gather_series_total",
+                         {"how": "native" if native.stage_lib() else "python"}),
     "superblocks_assembled": ("filodb_superblock_assembled_total", {"where": "device"}),
     "coalesce_wait_ms": ("filodb_query_wait_seconds_sum", {"kind": "coalesced"}),
     "handler_ms": ("filodb_http_request_seconds_sum", {"route": "query_range"}),
