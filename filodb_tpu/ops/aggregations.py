@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..metrics import record_fused_fallback, record_kernel_dispatch
+from ..metrics import REGISTRY, record_fused_fallback, record_kernel_dispatch
 from ..singleflight import memo_on
 from .hist_kernels import (
     _hist_range_jitter,
@@ -40,7 +40,7 @@ from .mxu_jitter import (
     masked_window_matrices,
 )
 from .mxu_kernels import fetch_strategy, mxu_range_kernel, window_matrices
-from .staging import replicated_put, series_put
+from .staging import grid_class, replicated_put, series_put
 
 SIMPLE_AGG_OPS = ("sum", "count", "avg", "min", "max", "stddev", "stdvar", "group")
 
@@ -114,8 +114,6 @@ def _with_reduce_form(func: str, epilogue: tuple, num_groups: int) -> tuple:
     form is part of the executable's identity (a static jit argument and
     the kernel observatory's ``epilogue`` key, ``agg:avg:wide``). Every
     fused dispatch counts its form: filodb_group_reduce_total{form}."""
-    from ..metrics import REGISTRY
-
     form = reduce_form(func, epilogue, num_groups)
     REGISTRY.counter("filodb_group_reduce", form=form).inc()
     return epilogue + ("wide",) if form == "wide" else epilogue
@@ -1042,6 +1040,10 @@ def _fused_dispatch(func: str, epilogue: tuple, block, num_groups: int,
         # Batched lanes degrade exactly like their unbatched executions
         # would, counted once per launch
         record_fused_fallback(reason)
+    # the body that runs (after any degradation) on the grid class it met:
+    # a dispatch that fell off the ladder (mxu > jitter > masked) says so
+    REGISTRY.counter("filodb_fused_dispatch", body=body_name,
+                     grid=grid_class(block)).inc()
     t0 = time.perf_counter()
     spec = FusedSpec(
         body_name, func, epilogue, num_groups,

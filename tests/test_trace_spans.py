@@ -643,6 +643,9 @@ PLANNED_READS = {
     "stage_native_pct": ("filodb_stage_gather_series_total",
                          {"how": "native" if native.stage_lib() else "python"}),
     "superblocks_assembled": ("filodb_superblock_assembled_total", {"where": "device"}),
+    # PR 36: the body a fused launch ran and the grid class it met; read per
+    # grid by scraped_off_ladder_pct (grid="irregular"), here on a shared grid
+    "fused_on_ladder_pct": ("filodb_fused_dispatch_total", {"grid": "regular"}),
     "coalesce_wait_ms": ("filodb_query_wait_seconds_sum", {"kind": "coalesced"}),
     "handler_ms": ("filodb_http_request_seconds_sum", {"route": "query_range"}),
     "device_ready_ms": ("filodb_transfer_ready_seconds_sum", {}),
