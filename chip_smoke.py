@@ -730,11 +730,17 @@ class Smoke:
 
         self.run_query(q, topk, fused_variant=None)
 
+        off_ladder = "pallas" if tpu else "general"
         for kind, variant in (("jitter", "jitter"), ("holes", "masked"),
-                              ("irregular", "pallas" if tpu else "general")):
+                              ("irregular", off_ladder)):
             q = f"sum(rate({m[kind]}[5m]))"
             want = nansum0(o_rate(self.sets[kind], out_t, t0))[0]
             self.run_query(q, one(want, q), fused_variant=variant)
+        # off the ladder irate has a finisher of its own (the last pair's
+        # interval taken in int32 inside the kernel)
+        q = f"sum(irate({m['irregular']}[5m]))"
+        want = nansum0(o_irate(self.sets["irregular"], out_t, t0))[0]
+        self.run_query(q, one(want, q), fused_variant=off_ladder)
 
         ts, hist, _total, les, _tags_ = self.hist
         q = (f"histogram_quantile(0.99, sum by (le) "

@@ -651,9 +651,10 @@ def _pallas_grid(func, rows, win, j_pad, is_counter, is_delta, interpret):
     its finisher. The Pallas grid pads S/J up to its tile sizes; slice back
     to the block's own padding before the epilogue so the trash-group/gids
     contract is unchanged."""
-    from .pallas_kernels import finish, window_aggregates
+    from .pallas_kernels import finish, stat_set, window_aggregates
 
-    agg = window_aggregates(*rows, *win, j_pad, interpret=interpret)
+    agg = window_aggregates(*rows, *win, j_pad, interpret=interpret,
+                            stats=stat_set(func, is_counter, is_delta))
     sj = finish(func, agg, *win, is_counter=is_counter, is_delta=is_delta)
     return sj[: rows[1].shape[0], :j_pad]
 

@@ -2,16 +2,23 @@
 
 The general (non-shared-grid) path in kernels.py makes several passes over
 the staged ``[S, T]`` block (bounds, prefix sums, boundary gathers). This
-Pallas kernel computes ALL per-window statistics — count, sum, min, max,
-first/last timestamp, first/last value, first raw value — in ONE pass with
-the block resident in VMEM, tiled ``(BS series x BJ steps)`` over a grid
-that reuses the series block across step tiles (the block index map keeps
-ts/vals constant along the step axis, so Pallas skips the re-fetch DMA).
+Pallas kernel computes the per-window statistics of ONE range function —
+its ``stat_set`` out of count, sum, min, max, first/last timestamp,
+first/last value, first raw value and the last pair's interval and
+difference — in ONE pass with the block resident in VMEM, tiled ``(BS
+series x BJ steps)`` over a grid that reuses the series block across step
+tiles (the block index map keeps ts/vals constant along the step axis, so
+Pallas skips the re-fetch DMA). The kernel is VPU-bound (a ``[BS, T]``
+mask and two operations a statistic for each of BJ steps), so it is built
+for the statistics its function's finisher reads and no other: the set is
+static, chosen while tracing (``FUNC_STATS``, ``stat_set``).
 
-A small jit finisher then derives any range function from these statistics
-(Prometheus extrapolation for rate/increase/delta). Compiled by Mosaic on
-a TPU; interpret mode exists for the CPU backend only (``interpret_mode``
-— the one place that decides, from the platform jax reports).
+A small jit finisher then derives the range function from these statistics
+(Prometheus extrapolation for rate/increase/delta; irate/idelta from the
+last two samples of the window, their interval taken in int32 inside the
+kernel). Compiled by Mosaic on a TPU; interpret mode exists for the CPU
+backend only (``interpret_mode`` — the one place that decides, from the
+platform jax reports).
 """
 
 from __future__ import annotations
@@ -31,16 +38,64 @@ NEG = -3.0e38  # python literals: jnp scalars would be captured consts
 POS = 3.0e38
 
 
-def _window_agg_kernel(params_ref, ts_ref, vals_ref, raw_ref, lens_ref,
-                       cnt_ref, sum_ref, min_ref, max_ref,
-                       tf_ref, tl_ref, vf_ref, vl_ref, rf_ref):
+# Every statistic the kernel can write. ``dt_last`` /
+# ``dv_last`` are the last sample's distance from the one before it inside
+# the window (irate / idelta), taken in the kernel: dt in int32, because the
+# f32 ``t_first`` / ``t_last`` round to 2 ms past 2^24 ms of block offset.
+STATS = ("count", "sum", "min", "max", "t_first", "t_last",
+         "v_first", "v_last", "raw_first", "dt_last", "dv_last")
+
+_EXTRAPOLATED = ("count", "t_first", "t_last", "v_first", "v_last")
+
+# function -> the statistics its finisher reads: the kernel is built for
+# exactly this set (stat_set: the rate family's reads depend on the schema)
+FUNC_STATS = {
+    "sum_over_time": ("count", "sum"),
+    "count_over_time": ("count",),
+    "avg_over_time": ("count", "sum"),
+    "min_over_time": ("count", "min"),
+    "max_over_time": ("count", "max"),
+    "last": ("count", "v_last"),
+    "last_over_time": ("count", "v_last"),
+    "first_over_time": ("count", "v_first"),
+    "present_over_time": ("count",),
+    "absent_over_time": ("count",),
+    "rate": _EXTRAPOLATED,
+    "increase": _EXTRAPOLATED,
+    "delta": _EXTRAPOLATED,
+    "irate": ("count", "dt_last", "dv_last"),
+    "idelta": ("count", "dv_last"),
+}
+
+PALLAS_FUNCS = set(FUNC_STATS)
+
+
+def stat_set(func: str, is_counter: bool = False, is_delta: bool = False) -> tuple:
+    """The window statistics ``finish(func, ...)`` reads."""
+    if is_delta and func in ("rate", "increase"):
+        return ("count", "sum")  # each sample IS the increase
+    if func == "idelta" and is_counter and not is_delta:
+        return ("count", "v_last")  # the staged diff of the last pair
+    stats = FUNC_STATS[func]
+    if is_counter and func in ("rate", "increase"):
+        stats += ("raw_first",)  # the zero cap of the extrapolation
+    return stats
+
+
+def _window_agg_kernel(stats, params_ref, ts_ref, vals_ref, *refs):
+    """One (BS series x BJ steps) tile: the statistics named in ``stats``
+    (static: a Python-level choice while tracing, no runtime branch), one
+    output ref each, after the ``raw`` operand where ``raw_first`` is read."""
+    want = frozenset(stats)
+    refs = list(refs)
+    raw_ref = refs.pop(0) if "raw_first" in want else None
+    lens_ref, *out_refs = refs
     start = params_ref[0]
     step = params_ref[1]
     window = params_ref[2]
     j0 = pl.program_id(1) * BJ
     ts = ts_ref[:]  # [BS, T] i32
     vals = vals_ref[:]
-    raw = raw_ref[:]
     lens = lens_ref[:]  # [BS, 1]
     T = ts.shape[1]
     lane = jax.lax.broadcasted_iota(jnp.int32, (ts.shape[0], T), 1)
@@ -56,46 +111,61 @@ def _window_agg_kernel(params_ref, ts_ref, vals_ref, raw_ref, lens_ref,
     def body(jj, accs):
         t_j = start + (j0 + jj) * step
         m = (ts <= t_j) & (ts > t_j - window) & valid
-        mf = m.astype(jnp.float32)
-        cnt = mf.sum(axis=1)
-        s = jnp.where(m, vals, 0.0).sum(axis=1)
-        mn = jnp.where(m, vals, POS).min(axis=1)
-        mx = jnp.where(m, vals, NEG).max(axis=1)
+        new = {"count": m.astype(jnp.float32).sum(axis=1)}
+        if "sum" in want:
+            new["sum"] = jnp.where(m, vals, 0.0).sum(axis=1)
+        if "min" in want:
+            new["min"] = jnp.where(m, vals, POS).min(axis=1)
+        if "max" in want:
+            new["max"] = jnp.where(m, vals, NEG).max(axis=1)
         # boundary selection in exact int32 time (f32 would round >2^24 ms)
-        tmin = jnp.where(m, ts, IMAX).min(axis=1)
-        tmax = jnp.where(m, ts, IMIN).max(axis=1)
-        first_m = m & (ts == tmin[:, None])
-        last_m = m & (ts == tmax[:, None])
-        vf = jnp.where(first_m, vals, 0.0).sum(axis=1)
-        vl = jnp.where(last_m, vals, 0.0).sum(axis=1)
-        rf = jnp.where(first_m, raw, 0.0).sum(axis=1)
+        if want & {"t_first", "v_first", "raw_first"}:
+            tmin = jnp.where(m, ts, IMAX).min(axis=1)
+            first_m = m & (ts == tmin[:, None])
+            new["t_first"] = tmin.astype(jnp.float32)
+            new["v_first"] = jnp.where(first_m, vals, 0.0).sum(axis=1)
+            if raw_ref is not None:
+                new["raw_first"] = jnp.where(first_m, raw_ref[:], 0.0).sum(axis=1)
+        if want & {"t_last", "v_last", "dt_last", "dv_last"}:
+            tmax = jnp.where(m, ts, IMIN).max(axis=1)
+            last_m = m & (ts == tmax[:, None])
+            new["t_last"] = tmax.astype(jnp.float32)
+            new["v_last"] = jnp.where(last_m, vals, 0.0).sum(axis=1)
+        if want & {"dt_last", "dv_last"}:
+            # the sample before the last one in the window; a window of
+            # fewer than two leaves trash the finisher masks (count < 2)
+            before = m & (ts < tmax[:, None])
+            tprev = jnp.where(before, ts, IMIN).max(axis=1)
+            prev_m = before & (ts == tprev[:, None])
+            new["dt_last"] = (tmax - tprev).astype(jnp.float32)
+            new["dv_last"] = new["v_last"] - jnp.where(prev_m, vals, 0.0).sum(axis=1)
         hot = col == jj  # [1, BJ] bool
-        new = (cnt, s, mn, mx, tmin.astype(jnp.float32), tmax.astype(jnp.float32), vf, vl, rf)
         # select, don't multiply: NaN stats (stale markers, parsed 'NaN'
-        # samples) must stay confined to their own step (NaN * 0 == NaN)
-        return tuple(a + jnp.where(hot, v[:, None], 0.0) for a, v in zip(accs, new))
+        # samples) must stay confined to their own step (NaN * 0 == NaN).
+        # An entry of ``new`` that ``stats`` does not name is a [BS] cast
+        # or subtraction at most, and dead code.
+        return tuple(a + jnp.where(hot, new[k][:, None], 0.0) for a, k in zip(accs, stats))
 
     zero = jnp.zeros((ts.shape[0], BJ), jnp.float32)
-    accs = jax.lax.fori_loop(0, BJ, body, (zero,) * 9)
-    for ref, acc in zip(
-        (cnt_ref, sum_ref, min_ref, max_ref, tf_ref, tl_ref, vf_ref, vl_ref, rf_ref), accs
-    ):
+    accs = jax.lax.fori_loop(0, BJ, body, (zero,) * len(stats))
+    for ref, acc in zip(out_refs, accs):
         ref[:] = acc
 
 
-@functools.partial(jax.jit, static_argnames=("num_steps", "interpret"))
+@functools.partial(jax.jit, static_argnames=("num_steps", "interpret", "stats"))
 @jax.named_scope("range_fn")
 def window_aggregates(ts, vals, raw, lens, start_off, step_ms, window_ms,
-                      num_steps: int, interpret: bool):
-    """[S, T] staged block -> dict of [S, num_steps] per-window statistics."""
+                      num_steps: int, interpret: bool, stats: tuple):
+    """[S, T] staged block -> dict of the [S, num_steps] per-window
+    statistics named in ``stats`` (a function's ``stat_set``)."""
     S, T = ts.shape
     S_pad = ((S + BS - 1) // BS) * BS
     J = ((num_steps + BJ - 1) // BJ) * BJ
+    rows = [ts, vals] + ([raw] if "raw_first" in stats else [])
     if S_pad != S:
         pad = ((0, S_pad - S), (0, 0))
-        ts = jnp.pad(ts, pad, constant_values=2**31 - 1)
-        vals = jnp.pad(vals, pad)
-        raw = jnp.pad(raw, pad)
+        rows[0] = jnp.pad(ts, pad, constant_values=2**31 - 1)
+        rows[1:] = [jnp.pad(r, pad) for r in rows[1:]]
         lens = jnp.pad(lens, ((0, S_pad - S),))
     from jax.experimental.pallas import tpu as pltpu
 
@@ -105,28 +175,20 @@ def window_aggregates(ts, vals, raw, lens, start_off, step_ms, window_ms,
     # index maps receive the scalar-prefetch ref as a trailing arg
     row_spec = pl.BlockSpec((BS, T), lambda i, j, *_: (i, 0))
     out_spec = pl.BlockSpec((BS, BJ), lambda i, j, *_: (i, j))
-    out_shape = [jax.ShapeDtypeStruct((S_pad, J), jnp.float32)] * 9
+    out_shape = [jax.ShapeDtypeStruct((S_pad, J), jnp.float32)] * len(stats)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,  # params land in SMEM before the pipeline
         grid=grid,
-        in_specs=[row_spec, row_spec, row_spec, pl.BlockSpec((BS, 1), lambda i, j, *_: (i, 0))],
-        out_specs=[out_spec] * 9,
+        in_specs=[row_spec] * len(rows) + [pl.BlockSpec((BS, 1), lambda i, j, *_: (i, 0))],
+        out_specs=[out_spec] * len(stats),
     )
     outs = pl.pallas_call(
-        _window_agg_kernel,
+        functools.partial(_window_agg_kernel, stats),
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(params, ts, vals, raw, lens2)
-    names = ("count", "sum", "min", "max", "t_first", "t_last", "v_first", "v_last", "raw_first")
-    return dict(zip(names, outs))
-
-
-PALLAS_FUNCS = {
-    "sum_over_time", "count_over_time", "avg_over_time", "min_over_time",
-    "max_over_time", "last", "last_over_time", "first_over_time",
-    "present_over_time", "absent_over_time", "rate", "increase", "delta",
-}
+    )(params, *rows, lens2)
+    return dict(zip(stats, outs))
 
 
 def interpret_mode() -> bool:
@@ -167,7 +229,8 @@ def pallas_enabled(t_pad: int) -> bool:
 @jax.named_scope("range_fn")
 def finish(func: str, agg: dict, start_off, step_ms, window_ms,
            is_counter: bool = False, is_delta: bool = False):
-    """Derive a range function from the fused window statistics."""
+    """Derive a range function from its ``stat_set`` of window statistics
+    (``agg`` need hold no other key)."""
     cnt = agg["count"]
     has = cnt > 0
     nan = jnp.nan
@@ -215,6 +278,16 @@ def finish(func: str, agg: dict, start_off, step_ms, window_ms,
         if func == "rate":
             res = res / w_s
         return jnp.where(cnt >= 2, res, nan)
+    if func in ("irate", "idelta"):
+        # kernels.range_kernel's irate / idelta line for line: corrected
+        # values make the difference across a reset the post-reset reading
+        if func == "irate":
+            r = agg["dv_last"] / jnp.maximum(agg["dt_last"] * 1e-3, 1e-30)
+        elif is_counter and not is_delta:
+            r = agg["v_last"]  # the staged f64-exact diff of the last pair
+        else:
+            r = agg["dv_last"]
+        return jnp.where(cnt >= 2, r, nan)
     raise ValueError(f"pallas path does not support {func}")
 
 
@@ -228,7 +301,7 @@ def run_pallas_range_function(func: str, block: StagedBlock, params,
     agg = window_aggregates(
         block.ts, block.vals, raw, block.lens,
         start_off, np.int32(params.step_ms), np.int32(params.window_ms), J,
-        interpret=interpret_mode(),
+        interpret=interpret_mode(), stats=stat_set(func, is_counter, is_delta),
     )
     return finish(func, agg, start_off, np.int32(params.step_ms), np.int32(params.window_ms),
                   is_counter=is_counter, is_delta=is_delta)

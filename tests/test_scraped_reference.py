@@ -278,7 +278,7 @@ def test_the_served_path_equals_the_reference_off_the_ladder(
     from filodb_tpu.ops.pallas_kernels import PALLAS_FUNCS
 
     monkeypatch.setenv("FILODB_PALLAS", "1" if body == "pallas" else "0")
-    assert {"rate", "avg_over_time"} <= PALLAS_FUNCS and "irate" not in PALLAS_FUNCS
+    assert {p["fn"] for p in PANELS} <= PALLAS_FUNCS
     for panel in PANELS:
         before = _launches(served)
         got = _ask(served, fleet, panel["query"])
@@ -288,8 +288,7 @@ def test_the_served_path_equals_the_reference_off_the_ladder(
         assert r["rel_err"] <= panel["rel_err_limit"], (panel["name"], r)
         grew = {k: v - before.get(k, 0) for k, v in _launches(served).items()
                 if v != before.get(k, 0)}
-        ran = body if panel["fn"] in PALLAS_FUNCS else "general"  # irate: no finisher
-        assert grew == {(ran, "irregular"): 1.0}, panel["name"]
+        assert grew == {(body, "irregular"): 1.0}, panel["name"]
 
 
 def test_a_late_scrape_read_at_its_slot_is_outside_the_limit(fleet, served):

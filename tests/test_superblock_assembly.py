@@ -510,3 +510,32 @@ def test_the_row_bands_are_written_in_place_at_hists_slide_size(one_chip):
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes >= 8192 * 256 * 12 * 4
     assert mem.temp_size_in_bytes < mem.output_size_in_bytes // 10
+
+
+def _stat_sets():
+    from filodb_tpu.ops import pallas_kernels as PK
+
+    return sorted({PK.stat_set(f, c, d) for f in PK.PALLAS_FUNCS
+                   for c, d in ((False, False), (True, False), (True, True))})
+
+
+@pytest.mark.parametrize("stats", _stat_sets(), ids="+".join)
+def test_the_pallas_kernel_of_every_statistic_set_compiles_for_the_chip(one_chip, stats):
+    """Interpret mode cannot say what Mosaic lowers: each set's kernel at
+    `scraped.repeat`'s shape (S131072 x T768 x J128) and at the widest block
+    the kernel is selected for (MAX_T)."""
+    import jax.numpy as jnp
+
+    from filodb_tpu.ops import pallas_kernels as PK
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    for s, t in ((131072, 768), (4096, PK.MAX_T)):
+        compiled = PK.window_aggregates.lower(
+            arr((s, t), jnp.int32), arr((s, t), jnp.float32), arr((s, t), jnp.float32),
+            arr((s,), jnp.int32), scalar, scalar, scalar,
+            num_steps=PK.BJ, interpret=False, stats=stats).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        assert compiled.memory_analysis().output_size_in_bytes >= len(stats) * s * PK.BJ * 4
