@@ -1638,8 +1638,17 @@ class QueryEngine:
         standing obligation, like maintainer refreshes), no querylog or
         recurrence-ring feedback (``standing_refresh`` flag), no batch
         window — so its executables and superblock are warm before the
-        first real poll pays the compile in its p99."""
+        first real poll pays the compile in its p99.
+
+        The execution has a PhaseRecorder of its own, booked to
+        ``filodb_prewarm_phase_seconds{phase}`` and nowhere else: what a
+        pre-warm stages behind live traffic must not read as the traffic's
+        ``stage`` (``filodb_query_phase_seconds``,
+        ``filodb_stage_part_seconds``)."""
         import time as _time
+
+        from ..metrics import REGISTRY
+        from ..obs.querylog import PhaseRecorder
 
         promql = desc.get("promql")
         step_ms = int(desc.get("step_ms") or 0)
@@ -1662,7 +1671,14 @@ class QueryEngine:
         # solo-path compile is the one a dashboard's first poll would pay:
         # don't route the warmup through the batch window it exists to dodge
         ctx.dispatch_scheduler = None
-        exec_plan.execute(ctx)
+        ctx.phases = rec = PhaseRecorder()
+        try:
+            exec_plan.execute(ctx)
+        finally:
+            for phase, seconds in rec.snapshot().items():
+                REGISTRY.histogram(
+                    "filodb_prewarm_phase_seconds", phase=phase
+                ).observe(seconds)
 
     def _run(self, exec_plan, ctx):
         """Execute on the shared bounded scheduler when configured, else
@@ -1675,7 +1691,9 @@ class QueryEngine:
             # whatever span the caller has open around the engine
             with activate(ctx.trace_root):
                 return exec_plan.execute(ctx)
-        return sched.run(lambda: exec_plan.execute(ctx), deadline_s=ctx.deadline_s)
+        return sched.run(lambda: exec_plan.execute(ctx),
+                         deadline_s=ctx.deadline_s,
+                         phases=getattr(ctx, "phases", None))
 
     def execute_plan(self, plan, deadline_s: float = 0.0, max_series: int = 0,
                      allow_partial_results: bool | None = None,

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from concurrent.futures import TimeoutError as FutureTimeout
 
@@ -103,21 +104,41 @@ class QueryScheduler:
         self._in_flight = 0
         self.peak_in_flight = 0
         self._lock = threading.Lock()
+        # the two hops' clocks, looked up once: every request books both
+        self._hops = {kind: REGISTRY.histogram("filodb_query_wait_seconds",
+                                               kind=kind)
+                      for kind in ("queued", "handback")}
 
     @property
     def in_flight(self) -> int:
         return self._in_flight
 
-    def run(self, fn, deadline_s: float):
+    def run(self, fn, deadline_s: float, phases=None):
         """Run ``fn()`` on the shared pool; wait at most ``deadline_s``.
-        Raises QueryRejected when saturated, QueryError on deadline."""
+        Raises QueryRejected when saturated, QueryError on deadline.
+
+        The two thread hops are clocked per caller into
+        ``filodb_query_wait_seconds``: ``kind="queued"`` from the submit
+        until a worker starts ``fn``, ``kind="handback"`` from the worker's
+        last statement until this caller runs again; both are also phase
+        ``queue`` of ``phases`` (the query's PhaseRecorder) when given. The
+        caller's whole wait is the span ``sched:run``."""
         if not self._slots.acquire(blocking=False):
             REGISTRY.counter("filodb_queries_rejected").inc()
             raise QueryRejected(
                 f"query rejected: {self.parallelism} running + {self.max_queued} queued"
             )
 
+        def hop(kind: str, since_ns: int) -> None:
+            seconds = (time.perf_counter_ns() - since_ns) / 1e9
+            self._hops[kind].observe(seconds)
+            if phases is not None:
+                phases.add("queue", seconds)
+
+        done_ns = []  # the worker's stamp as it finishes
+
         def _job():
+            hop("queued", submitted_ns)
             with self._lock:
                 self._in_flight += 1
                 self.peak_in_flight = max(self.peak_in_flight, self._in_flight)
@@ -127,10 +148,13 @@ class QueryScheduler:
                 with self._lock:
                     self._in_flight -= 1
                 self._slots.release()
+                done_ns.append(time.perf_counter_ns())
 
-        fut = self._pool.submit(_job)
         try:
-            return fut.result(timeout=deadline_s)
+            with span("sched:run"):
+                submitted_ns = time.perf_counter_ns()
+                fut = self._pool.submit(_job)
+                return fut.result(timeout=deadline_s)
         except FutureTimeout:
             # the worker aborts at its next check_deadline(); stop waiting now
             if fut.cancel():
@@ -140,6 +164,9 @@ class QueryScheduler:
             raise QueryDeadlineExceeded(
                 f"query exceeded deadline: {deadline_s:.1f}s"
             ) from None
+        finally:
+            if done_ns:  # the caller is running again
+                hop("handback", done_ns[0])
 
     def shutdown(self):
         self._pool.shutdown(wait=False, cancel_futures=True)

@@ -15,6 +15,7 @@ from .. import native
 from ..core.filters import ColumnFilter
 from ..core.records import RecordBatch
 from ..core.schemas import Dataset
+from ..metrics import REGISTRY, span
 from .shard import StoreConfig, TimeSeriesShard
 
 
@@ -78,11 +79,14 @@ class TimeSeriesMemStore:
         shards = self._datasets[dataset]
         options = self._dataset_meta[dataset].options
         n = 0
-        for snum, sub in batch.shard_split(
-            spread, self.total_shards(dataset), options
-        ).items():
-            if snum in shards:
-                n += shards[snum].ingest(sub)
+        with span("ingest:routed", dataset=dataset) as sp:
+            for snum, sub in batch.shard_split(
+                spread, self.total_shards(dataset), options
+            ).items():
+                if snum in shards:
+                    n += shards[snum].ingest(sub)
+        REGISTRY.histogram("filodb_ingest_seconds", dataset=dataset).observe(
+            sp.seconds)
         return n
 
     # -- query side ----------------------------------------------------------

@@ -126,7 +126,6 @@ HELP_TEXTS: dict[str, str] = {
     "filodb_superblock_maintenance": "Version-stale superblock maintenance outcomes (revalidate|extend|extend_abort|restage).",
     "filodb_downsample_claims": "Distributed-downsample claim lifecycle events.",
     "filodb_kernel_dispatch_seconds": "ops/ kernel dispatch latency, per kernel.",
-    "filodb_jit_cache": "JIT compile-cache hits/misses per kernel.",
     "filodb_shard_partitions": "Live partitions per shard.",
     "filodb_shard_rows_ingested": "Rows ingested per shard.",
     "filodb_shard_rows_skipped": "Rows skipped per shard.",
@@ -159,14 +158,14 @@ HELP_TEXTS: dict[str, str] = {
     "filodb_standing_pushes": "Per-subscriber payload deliveries (sent) and stall drops (dropped).",
     "filodb_standing_promotions": "Standing-query lifecycle events (register|promote|demote).",
     "filodb_standing_rule_samples": "Samples written back into the memstore by recording rules.",
-    "filodb_query_phase_seconds": "Per-phase query latency decomposition (parse_plan|admission|stage|dispatch|transfer|render|other).",
+    "filodb_query_phase_seconds": "Per-phase query latency decomposition (parse_plan|admission|queue|stage|group|dispatch|transfer|render|other).",
     "filodb_stage_part_seconds": "Where the stage phase went, per execution (lookup|gather|assemble|h2d_shard|readback|concat|h2d_super); the parts sum to at most the stage phase.",
     "filodb_stage_h2d_bytes": "Bytes a cold stage uploaded to the device, by part (h2d_shard = per-shard blocks, h2d_super = a host-assembled superblock, a masked sidecar, the le vector).",
     "filodb_stage_d2h_bytes": "Bytes a cold stage read back from device-resident staged arrays that have no host mirror (the first np.asarray of each).",
     "filodb_stage_mirror_bytes": "Bytes of host mirrors (kept for in-place append repairs) by site (shard = a shard's staged block, super = a superblock) and what became of them (aliased = the staged arrays themselves, no copy; copied = explicit copies, CPU backend; deferred = not made at a device assembly; materialized = made at a deferred mirror's first extension).",
     "filodb_stage_gather_series": "Series a cold stage gathered from a shard, by the path that staged them (native = a histogram selection, one table of chunk segments and one pass of libfilodbstage; python = samples_in_range a series and the numpy pad: scalar columns, no library, arrays the pass cannot read in place, an empty selection).",
     "filodb_superblock_assembled": "Superblocks built, by where their arrays were concatenated (device = from the shards' device-resident blocks, nothing uploaded again; host = concatenated on the host and uploaded).",
-    "filodb_query_wait_seconds": "Per-caller wait for work another caller runs, by kind (coalesced = a follower of an identical in-flight query).",
+    "filodb_query_wait_seconds": "Per-caller wait for another thread, by kind (coalesced = a follower of an identical in-flight query; queued = submitted to the query pool until a worker starts it; handback = the worker is done until the caller runs again).",
     "filodb_http_request_seconds": "Handler wall of a query route, entry to return, per caller (route = query_range|query).",
     "filodb_transfer_ready_seconds": "Per-caller wait for the device to finish the query's program at the serving edge; the transfer phase less this is the copy back.",
     "filodb_render_write_seconds": "Per-caller wall of writing a query response (status line, headers, body) to the socket; the render phase less this is encoding.",
@@ -184,7 +183,6 @@ HELP_TEXTS: dict[str, str] = {
     "filodb_xla_recompile_storms": "Recompile storms detected per kernel family (same family re-lowering past the threshold inside the window; /debug/kernels names the unstable dimension).",
     "filodb_xla_executables": "Live executables in the kernel observatory's registry.",
     "filodb_kernel_exec_dispatches": "Kernel dispatches accounted by the executable registry, per family.",
-    "filodb_kernel_exec_device_seconds": "Per-dispatch device cost of warm (non-compiling) dispatches, per kernel family (the host wall of the dispatch call).",
     "filodb_compile_cache_hits": "Compile-cache hits by tier (in_process = warm jit cache, persistent = compile deserialized from the on-disk XLA cache).",
     "filodb_compile_cache_misses": "Compile-cache misses by tier (in_process = a compile happened, persistent = a fresh trace wrote a new on-disk entry).",
     "filodb_index_postings_bytes": "Host posting-bitmap footprint of the part-key index, per shard.",
@@ -207,6 +205,11 @@ HELP_TEXTS: dict[str, str] = {
     "filodb_alert_notify": "Alert notification deliveries per receiver and outcome (ok|retry|error|breaker_open).",
     "filodb_costmodel_error_ratio": "Cost-model prediction quality per completed query: max(predicted/realized, realized/predicted) device-seconds.",
     "filodb_prewarm": "Executable pre-warm attempts by outcome (ok|error): recurrence-ring keys trace+compiled off the serving path.",
+    "filodb_prewarm_seconds": "Wall of one pre-warmed key (the prewarm:key span): a whole query executed off the serving path.",
+    "filodb_prewarm_phase_seconds": "Where a pre-warmed key's wall went, by query phase; a pre-warm books here and never into filodb_query_phase_seconds or filodb_stage_part_seconds.",
+    "filodb_ingest_seconds": "Wall of one routed ingest call (the ingest:routed span: shard split plus every owned shard's ingest), per dataset.",
+    "filodb_startup_seconds": "Process start to a serving server, by stage (import = process start until FiloServer is entered; backend = compile cache, distributed runtime and the first jax.devices(); store = memstore, engines and recovery; listen = until the port is bound and the background loops run). Set once a start.",
+    "process_start_time_seconds": "Start time of the process since the unix epoch, in seconds (from /proc/self/stat).",
 }
 
 
@@ -342,7 +345,11 @@ class Registry:
                 lines.append(f"{name}_total{lbl} {m.value:g}")
             elif isinstance(m, Gauge):
                 header(name, "gauge")
-                lines.append(f"{name}{lbl} {m.value:g}")
+                # six digits unless they lose the value (an epoch time)
+                text = f"{m.value:g}"
+                if float(text) != m.value:
+                    text = repr(float(m.value))
+                lines.append(f"{name}{lbl} {text}")
             elif isinstance(m, Histogram):
                 header(name, "histogram")
                 base = [f'{k}="{escape_label_value(v)}"' for k, v in labels]
@@ -453,15 +460,22 @@ def record_rebalance_standing_move() -> None:
 #
 # - parse_plan  — PromQL parse + logical-plan build + materialize
 # - admission   — admission-control gate + batch-window queue wait
+# - queue       — the two thread hops of the bounded query pool
+#                 (coordinator/scheduler.QueryScheduler.run): submitted
+#                 until a worker starts the plan, and the worker done until
+#                 the caller runs again
 # - stage       — superblock resolution (cache hit / extend / build+upload)
+# - group       — group ids of the block's series for the aggregation's
+#                 by/without (memoized on the block: ~0 on a hit; a miss
+#                 regroups every label set and uploads an [S] int32)
 # - dispatch    — the kernel launch itself (batched or solo)
 # - transfer    — device→host result pull at the serving edge
 # - render      — response encoding + write at the serving edge
 # - other       — engine residual (everything the named phases don't cover,
 #                 computed at query end so the phase sum equals wall time)
 QUERY_PHASES = (
-    "parse_plan", "admission", "stage", "dispatch", "transfer", "render",
-    "other",
+    "parse_plan", "admission", "queue", "stage", "group", "dispatch",
+    "transfer", "render", "other",
 )
 
 # the ONE canonical set of parts of the ``stage`` phase, in the order a cold
@@ -933,9 +947,9 @@ def current_stats():
 def record_kernel_dispatch(kernel: str, seconds: float,
                            compiled: bool | None = None,
                            key: dict | None = None) -> None:
-    """Latency histogram around an ops/ kernel entry point, plus JIT
-    compile-cache hit/miss accounting when the caller can observe its jit
-    cache (a grown cache across the call means this dispatch compiled).
+    """Latency histogram around an ops/ kernel entry point; ``compiled``
+    (a grown jit cache across the call means this dispatch compiled) goes
+    on to the kernel observatory, which books the compile-cache counters.
     Also attributes the dispatch seconds to the active query's QueryStats
     (kernel_ns) — the per-query/per-tenant device accounting feed. Pure
     host-side bookkeeping: no device sync is added around the (async)
@@ -950,11 +964,6 @@ def record_kernel_dispatch(kernel: str, seconds: float,
     st = current_stats()
     if st is not None:
         st.bump(kernel_ns=int(seconds * 1e9))
-    if compiled is not None:
-        REGISTRY.counter(
-            "filodb_jit_cache", kernel=kernel,
-            outcome="miss" if compiled else "hit",
-        ).inc()
     # kernel & compile observatory (obs/kernels.py): per-executable
     # compile/dispatch/device-cost attribution + recompile-storm detection
     from .obs.kernels import KERNELS

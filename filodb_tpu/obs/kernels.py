@@ -287,10 +287,6 @@ class ExecutableRegistry:
             REGISTRY.counter("filodb_xla_compiles", family=family).inc()
             REGISTRY.counter("filodb_xla_compile_seconds",
                              family=family).inc(float(seconds))
-        else:
-            REGISTRY.micro_histogram(
-                "filodb_kernel_exec_device_seconds", family=family
-            ).observe(device_s)
         self._local.last = {
             "executable_key": key,
             "compile_miss": is_compile,

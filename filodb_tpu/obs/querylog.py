@@ -53,7 +53,8 @@ _PART_SET = frozenset(STAGE_PARTS)
 # serving edge after the engine returns, and ``other`` is the computed
 # residual — the invariant (tests/test_querylog.py) is
 # sum(ENGINE_PHASES + other) == engine duration.
-ENGINE_PHASES = ("parse_plan", "admission", "stage", "dispatch")
+ENGINE_PHASES = ("parse_plan", "admission", "queue", "stage", "group",
+                 "dispatch")
 EDGE_PHASES = ("transfer", "render")
 
 

@@ -22,7 +22,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..metrics import REGISTRY, record_fused_fallback, record_kernel_dispatch
+from ..metrics import (
+    REGISTRY, current_span, record_fused_fallback, record_kernel_dispatch,
+)
 from ..singleflight import memo_on
 from .hist_kernels import (
     _hist_range_jitter,
@@ -1086,6 +1088,14 @@ def fused_range_aggregate(func: str, op: str, block, gids_padded,
     )
 
 
+def _tag_memo_miss() -> None:
+    """A group-id memo is being built: say so on the span that asked (the
+    exec nodes' ``fused:groups``, opened with ``memo="hit"``)."""
+    sp = current_span()
+    if sp is not None and "memo" in sp.tags:
+        sp.tags["memo"] = "miss"
+
+
 def zero_gids(block):
     """All-zeros trash-group vector for epilogues that need no label
     grouping (global topk/bottomk): unused by the epilogue math but part of
@@ -1093,12 +1103,14 @@ def zero_gids(block):
     with a sharded block's series axis); also handed to the cross-query
     batcher so identical-lane dedup keys on ONE object per block."""
     s_pad = np.asarray(block.lens).shape[0]
-    return memo_on(
-        block, "_zero_gids", s_pad,
-        lambda: series_put(getattr(block, "placement", None))(
+
+    def build():
+        _tag_memo_miss()
+        return series_put(getattr(block, "placement", None))(
             np.zeros(s_pad, dtype=np.int32)
-        ),
-    )
+        )
+
+    return memo_on(block, "_zero_gids", s_pad, build)
 
 
 def fused_topk(func: str, block, k: int, bottom: bool, params,
@@ -1302,6 +1314,7 @@ def group_ids_memo(block, series_labels, by, without,
     )
 
     def build():
+        _tag_memo_miss()
         labels = series_labels
         if strip_metric:
             from ..core.schemas import METRIC_TAG

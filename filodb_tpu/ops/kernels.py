@@ -451,7 +451,8 @@ def _host_timestamp(block: StagedBlock, params: RangeParams) -> np.ndarray:
 def _jit_cache_size() -> int:
     """Combined compile-cache size of the kernels run_range_function can
     dispatch to — a growth across one dispatch means a compile happened
-    (the hit/miss signal for filodb_jit_cache; SURVEY §7 calls
+    (the hit/miss signal of filodb_compile_cache_{hits,misses}_total and
+    filodb_xla_compiles_total; SURVEY §7 calls
     recompilation the #1 risk, so hits/misses must be observable in
     production).
 

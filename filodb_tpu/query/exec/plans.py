@@ -1802,6 +1802,22 @@ class FusedAggregateExec(ExecPlan):
         return (self.mesh.axis_names[0],
                 tuple(d.id for d in self.mesh.devices.flat))
 
+    def _group_ids(self, got, strip: bool):
+        """``(gids_dev, G, group_labels)`` of the superblock's series for
+        this aggregation, under phase ``group``: memoized on the block
+        object, so a query that built its block pays the regroup of every
+        label set and the [S] int32 upload (the builder tags the span
+        ``memo="miss"``). Global topk/bottomk group nothing: the all-zeros
+        vector of the shared signature, memoized alike."""
+        with span("fused:groups", phase="group", memo="hit",
+                  series=len(got.labels)):
+            if self.op in ("topk", "bottomk") and not got.is_hist:
+                return AGG.zero_gids(got.block), 1, None
+            return AGG.group_ids_memo(
+                got.block, got.labels, self.by, self.without,
+                strip_metric=strip,
+            )
+
     def _dispatch_fused(self, ctx: QueryContext, request) -> Any:
         """Route one fused kernel launch through the query dispatch
         scheduler (query/scheduler.py) when the context carries an enabled
@@ -1965,15 +1981,12 @@ class FusedAggregateExec(ExecPlan):
             self.window_ms,
         )
         strip = self.function is not None and self.function not in _DROP_NAME_KEEP
+        gids_dev, G, group_labels = self._group_ids(got, strip)
         if got.is_hist:
             # 3-D histogram superblock: per-bucket fused sum (+ optional
             # device-side histogram_quantile interpolation epilogue).
             # op/func support was already vetted (_unsupported_shape) before
             # the superblock's stats bump, on both the hit and build paths.
-            gids_dev, G, group_labels = AGG.group_ids_memo(
-                got.block, got.labels, self.by, self.without,
-                strip_metric=strip,
-            )
             with span(f"fused:dispatch:hist_{func}"):
                 out = self._dispatch_fused(ctx, FusedRequest(
                     block=got.block, func=func, kind="hist", epilogue=(),
@@ -2006,7 +2019,7 @@ class FusedAggregateExec(ExecPlan):
                 vals_dev, idx_dev = self._dispatch_fused(ctx, FusedRequest(
                     block=got.block, func=func, kind="topk",
                     epilogue=("topk", k, self.op == "bottomk"),
-                    gids_dev=AGG.zero_gids(got.block), G=1, qv=0.0,
+                    gids_dev=gids_dev, G=1, qv=0.0,
                     params=params, j_pad=pad_steps(nsteps),
                     is_counter=got.is_counter, is_delta=got.is_delta,
                     mesh=self.mesh, mesh_desc=self._mesh_desc(),
@@ -2020,9 +2033,6 @@ class FusedAggregateExec(ExecPlan):
                 np.asarray(vals_dev)[:, :nsteps],
                 np.asarray(idx_dev)[:, :nsteps], got.labels, strip, nsteps,
             )
-        gids_dev, G, group_labels = AGG.group_ids_memo(
-            got.block, got.labels, self.by, self.without, strip_metric=strip
-        )
         if self.op == "quantile":
             q = float(self.params[0])
             with span(f"fused:dispatch:quantile:{func}"):
@@ -2279,13 +2289,14 @@ class RollupServeExec(ExecPlan):
             return QueryResult(grids=[
                 Grid(out_labels, self.start_ms, self.step_ms, nsteps, out)
             ])
-        gids_np, group_labels = AGG.group_ids_for(
-            labels,
-            list(self.by) if self.by else None,
-            list(self.without) if self.without else None,
-        )
-        G = max(len(group_labels), 1)
-        gids = jnp.asarray(gids_np)
+        with span("rollup:groups", phase="group", memo="miss", series=S):
+            gids_np, group_labels = AGG.group_ids_for(
+                labels,
+                list(self.by) if self.by else None,
+                list(self.without) if self.without else None,
+            )
+            G = max(len(group_labels), 1)
+            gids = jnp.asarray(gids_np)
         if self.op == "quantile":
             q = float(self.params[0])
             mesh = self.mesh

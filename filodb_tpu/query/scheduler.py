@@ -60,7 +60,7 @@ from concurrent.futures import TimeoutError as FutureTimeout
 from dataclasses import dataclass
 from typing import Any, Callable
 
-from ..metrics import REGISTRY
+from ..metrics import REGISTRY, span
 from .exec.transformers import QueryDeadlineExceeded, QueryError
 
 # ---------------------------------------------------------------------------
@@ -843,14 +843,19 @@ class DispatchScheduler:
             self._prewarmed[key] = True
             while len(self._prewarmed) > 4 * self.key_ring.max_entries:
                 self._prewarmed.pop(next(iter(self._prewarmed)))
-            try:
-                self._prewarm_exec(desc)
-            except Exception:  # noqa: BLE001 — pre-warm is advisory
-                REGISTRY.counter("filodb_prewarm", outcome="error").inc()
-                continue
-            self.stats["prewarmed"] += 1
-            REGISTRY.counter("filodb_prewarm", outcome="ok").inc()
-            warmed.append(key)
+            # a root span: the key's whole query, on no request's trace
+            outcome = "ok"
+            with span("prewarm:key", promql=desc["promql"]) as sp:
+                try:
+                    self._prewarm_exec(desc)
+                except Exception:  # noqa: BLE001 — pre-warm is advisory
+                    outcome = "error"
+                sp.tags["outcome"] = outcome
+            REGISTRY.histogram("filodb_prewarm_seconds").observe(sp.seconds)
+            REGISTRY.counter("filodb_prewarm", outcome=outcome).inc()
+            if outcome == "ok":
+                self.stats["prewarmed"] += 1
+                warmed.append(key)
         return warmed
 
     def dispatch(self, request: FusedRequest):

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import json
 import logging
+import os
 import threading
 import time
 
@@ -29,14 +30,35 @@ from .coordinator.planner import QueryEngine
 from .core.schemas import Dataset
 from .memstore.memstore import TimeSeriesMemStore
 from .memstore.shard import StoreConfig
+from .metrics import REGISTRY
 from .store.columnstore import LocalColumnStore, NullColumnStore
 from .store.flush import FlushCoordinator, recover_shard
 
 log = logging.getLogger("filodb_tpu.server")
 
 
+def process_start_time() -> float | None:
+    """When the kernel started this process, unix seconds: field 22 of
+    ``/proc/self/stat`` (clock ticks after boot; the command in field 2 may
+    hold spaces, so count from its closing bracket) on ``btime`` of
+    ``/proc/stat``. None where there is no such file."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/stat") as f:
+            btime = next(int(ln.split()[1]) for ln in f
+                         if ln.startswith("btime "))
+        return btime + ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError, StopIteration):
+        return None
+
+
 class FiloServer:
     def __init__(self, config: dict | None = None):
+        # the restart-to-serving timeline (filodb_startup_seconds{stage}):
+        # each stage ends where the next begins, so what a slow start spent
+        # has one owner (doc/observability.md "Start-up and set-up")
+        entered, t_entered = time.time(), time.perf_counter()
         from .config import load_config
 
         cfg = load_config(overrides=config or {})
@@ -76,6 +98,10 @@ class FiloServer:
             dist_cfg.get("num_processes"),
             dist_cfg.get("process_id"),
         )
+        # the backend comes up here, under a stage of its own, and not
+        # inside whatever touches a device first
+        device_facts()
+        t_backend = time.perf_counter()
         if dist_cfg.get("owned_shards") is not None:
             owned = list(dist_cfg["owned_shards"])  # explicit (k8s static / tests)
         elif self.is_distributed:
@@ -430,6 +456,12 @@ class FiloServer:
         self.grpc_port = cfg.get("grpc_port")
         self.bootstrapper = None
         self.registry = None
+        self._startup = {"backend": t_backend - t_entered,
+                         "store": time.perf_counter() - t_backend}
+        started = process_start_time()
+        if started is not None:
+            REGISTRY.gauge("process_start_time_seconds").set(started)
+            self._startup["import"] = max(entered - started, 0.0)
 
     def _cluster_snapshot(self) -> dict:
         """GET /debug/cluster payload: the replication plane's snapshot when
@@ -462,7 +494,9 @@ class FiloServer:
         return offsets
 
     def start(self, port: int | None = None) -> int:
+        t_start = time.perf_counter()
         self.recover()
+        t_recovered = time.perf_counter()
         if self.profiler is not None:
             self.profiler.start()
         self._http, actual_port = serve_background(
@@ -576,6 +610,11 @@ class FiloServer:
                                   name="filodb-prewarm")
             tp.start()
             self._threads.append(tp)
+        startup = {**self._startup,
+                   "store": self._startup["store"] + t_recovered - t_start,
+                   "listen": time.perf_counter() - t_recovered}
+        for stage, seconds in startup.items():
+            REGISTRY.gauge("filodb_startup_seconds", stage=stage).set(seconds)
         log.info("filodb-tpu serving on :%d (%d shards)", actual_port, self.n_shards)
         log.info("kernels run on platform=%(platform)s "
                  "device_kind=%(device_kind)s device_count=%(device_count)d",

@@ -447,13 +447,13 @@ class TestKernelInstrumentation:
         text = REGISTRY.expose()
         # the fused path records ONE dispatch for the whole query
         assert 'filodb_kernel_dispatch_seconds_bucket{kernel="fused_sum_rate"' in text
-        assert 'filodb_jit_cache_total{kernel="fused_sum_rate"' in text
+        assert 'filodb_compile_cache_misses_total{tier="in_process"}' in text
         # a repeat of the same shape must record HITS, not new misses
-        before = REGISTRY.counter("filodb_jit_cache", kernel="fused_sum_rate", outcome="hit").value
+        hits = REGISTRY.counter("filodb_compile_cache_hits", tier="in_process")
+        before = hits.value
         engine.query_range("sum(rate(http_requests_total[5m]))",
                            (BASE + 630_000) / 1000, (BASE + 930_000) / 1000, 60)
-        after = REGISTRY.counter("filodb_jit_cache", kernel="fused_sum_rate", outcome="hit").value
-        assert after > before
+        assert hits.value > before
         # the reference tree still records per-kernel dispatches
         ref = QueryEngine(ms, "prometheus", PlannerParams(fused_aggregate=False))
         ref.query_range("sum(rate(http_requests_total[5m]))",

@@ -17,7 +17,11 @@ Contracts pinned here:
   three stage scopes, and a scope changes metadata only;
 - (f) every counter a benchmark metric file names is a documented family,
   and the sample each per-layer metric of ISSUE 26 would read is on
-  ``/metrics`` under the name and label the benchmark's own parser finds.
+  ``/metrics`` under the name and label the benchmark's own parser finds;
+- (g) ISSUE 38, every second has an owner: phase ``group`` around the group
+  ids and ``queue`` around the pool's two hops leave ``other`` a residual;
+  a pre-warm, a routed ingest and a server's start have clocks of their
+  own, and a pre-warm books nothing where the window's metrics read.
 """
 
 from __future__ import annotations
@@ -650,6 +654,13 @@ PLANNED_READS = {
     "handler_ms": ("filodb_http_request_seconds_sum", {"route": "query_range"}),
     "device_ready_ms": ("filodb_transfer_ready_seconds_sum", {}),
     "render_write_ms": ("filodb_render_write_seconds_sum", {}),
+    # PR 38: the files are there (layer_metrics/); what one served query books
+    "group_ms": ("filodb_query_phase_seconds_sum", {"phase": "group"}),
+    "other_ms": ("filodb_query_phase_seconds_sum", {"phase": "other"}),
+    "queue_wait_ms": ("filodb_query_wait_seconds_sum", {"kind": "queued"}),
+    "handback_ms": ("filodb_query_wait_seconds_sum", {"kind": "handback"}),
+    "setup_stage_s": ("filodb_query_phase_seconds_sum", {"phase": "stage"}),
+    "setup_query_s": ("filodb_http_request_seconds_sum", {"route": "query_range"}),
 }
 
 
@@ -660,6 +671,7 @@ def scraped(cold, served):
     _eng, port = served
     _one_flight()
     _get(port, QUERIES["hist"])
+    _pooled_query()  # the pool's two hops: the served engine runs inline
     deadline = time.monotonic() + 5  # the handler observes after the body
     while True:
         with urllib.request.urlopen(f"http://127.0.0.1:{port}/metrics") as r:
@@ -677,3 +689,252 @@ def test_the_benchmarks_parser_finds_what_a_planned_metric_reads(scraped, metric
 
     sample, labels = PLANNED_READS[metric]
     assert readers.total(scraped, sample, **labels) > 0, (sample, labels)
+
+
+# -- (g) group, queue, and the clocks of set-up -------------------------------
+
+BIG_SERIES = 2048
+
+
+@pytest.fixture(scope="module")
+def big():
+    """A cold fused query over BIG_SERIES counters and its repeat: per query
+    the caller's wall, the querylog record and the span tree."""
+    ms = TimeSeriesMemStore(StoreConfig())
+    ms.setup(Dataset("ds"), list(range(4)))
+    ms.ingest_routed("ds", counter_batch(n_series=BIG_SERIES,
+                                         n_samples=N_SAMPLES, start_ms=BASE),
+                     spread=2)
+    eng = QueryEngine(ms, "ds", PlannerParams())
+    out = {}
+    for which in ("cold", "repeat"):
+        t0 = time.perf_counter()
+        res = eng.query_range(QUERIES["counter"], START_S, END_S, 60)
+        out[which] = {"wall_ms": 1e3 * (time.perf_counter() - t0),
+                      "record": QUERY_LOG.get(res.query_log["id"]),
+                      "tree": res.trace.to_dict()}
+    return out
+
+
+def _spans_named(node: dict, name: str) -> list:
+    found = [node] if node["name"] == name else []
+    for c in node.get("children", []):
+        found += _spans_named(c, name)
+    return found
+
+
+@pytest.mark.parametrize("which", ["cold", "repeat"])
+def test_phases_sum_to_the_wall(big, which):
+    rec, wall = big[which]["record"], big[which]["wall_ms"]
+    assert rec["path"] == "fused"
+    booked = sum(rec["phases_ms"].values())
+    assert booked == pytest.approx(rec["duration_ms"], abs=0.02)
+    assert booked <= wall
+    if which == "cold":  # a second of staging: the engine's own edges are < 1 %
+        assert booked >= 0.99 * wall, (booked, wall)
+
+
+def test_group_is_booked_on_a_memo_miss_and_next_to_nothing_on_a_hit(big):
+    cold, repeat = (big[w]["record"]["phases_ms"] for w in ("cold", "repeat"))
+    assert cold["group"] > 0.2  # BIG_SERIES label sets regrouped, in Python
+    assert repeat["group"] < 0.1 * cold["group"]
+    for which, memo in (("cold", "miss"), ("repeat", "hit")):
+        (sp,) = _spans_named(big[which]["tree"], "fused:groups")
+        assert sp["tags"] == {"memo": memo, "series": BIG_SERIES}
+        assert sp["duration_ms"] == pytest.approx(
+            big[which]["record"]["phases_ms"]["group"], abs=0.02)
+
+
+def test_other_is_a_residual_again(big):
+    rec = big["cold"]["record"]
+    assert rec["phases_ms"].get("other", 0.0) <= 0.05 * rec["duration_ms"]
+
+
+def _wait_clocks() -> dict:
+    return {
+        "queued": _hist_sum("filodb_query_wait_seconds", kind="queued"),
+        "handback": _hist_sum("filodb_query_wait_seconds", kind="handback"),
+        "engine": _hist_sum("filodb_query_latency_seconds", dataset="ds"),
+    }
+
+
+def _pooled_query():
+    """One query through the bounded pool: (result, growth of the clocks)."""
+    from filodb_tpu.coordinator.scheduler import QueryScheduler
+
+    sched = QueryScheduler(parallelism=2)
+    try:
+        eng = _engine(scheduler=sched)
+        before = _wait_clocks()
+        res = eng.query_range(QUERIES["counter"], START_S, END_S, 60)
+        after = _wait_clocks()
+    finally:
+        sched.shutdown()
+    return res, {k: (after[k][0] - before[k][0], after[k][1] - before[k][1])
+                 for k in after}
+
+
+def test_a_pooled_query_books_its_two_hops_once_each():
+    res, grew = _pooled_query()
+    assert res.grids
+    assert grew["queued"][1] == grew["handback"][1] == grew["engine"][1] == 1
+    queued, handback, engine = (grew[k][0] for k in ("queued", "handback", "engine"))
+    assert queued >= 0 and handback >= 0
+    assert queued + handback <= engine  # inside engine:query_range's wall
+    rec = QUERY_LOG.get(res.query_log["id"])
+    assert rec["phases_ms"]["queue"] == pytest.approx(
+        1e3 * (queued + handback), abs=0.01)
+    assert sum(rec["phases_ms"].values()) == pytest.approx(
+        rec["duration_ms"], abs=0.02)
+
+
+def test_an_inline_query_books_no_hop(big):
+    assert "queue" not in big["cold"]["record"]["phases_ms"]
+
+
+def test_a_prewarm_has_clocks_of_its_own_and_leaks_into_no_query_metric():
+    from filodb_tpu.query.scheduler import DispatchScheduler
+
+    sched = DispatchScheduler(window_ms=0, prewarm_min_count=1)
+    _engine(dispatch_scheduler=sched)  # registers its _prewarm_key
+    desc = {"promql": QUERIES["counter"], "step_ms": 60_000,
+            "span_ms": int((END_S - START_S) * 1000),
+            "end_lag_ms": (time.time() - END_S) * 1000}
+    sched.key_ring.observe(("prewarm-clock", desc["promql"]), desc)
+
+    def clocks():
+        return {
+            "prewarm": _hist_sum("filodb_prewarm_seconds"),
+            "stage": _hist_sum("filodb_prewarm_phase_seconds", phase="stage"),
+            "group": _hist_sum("filodb_prewarm_phase_seconds", phase="group"),
+            "query_phases": _hist_sum("filodb_query_phase_seconds"),
+            "stage_parts": _hist_sum("filodb_stage_part_seconds"),
+            "ok": REGISTRY.counter("filodb_prewarm", outcome="ok").value,
+        }
+
+    before = clocks()
+    assert len(sched.prewarm_tick(storms={})) == 1
+    after = clocks()
+    assert after["ok"] == before["ok"] + 1
+    for clock in ("prewarm", "stage", "group"):  # one observation a key
+        assert after[clock][1] == before[clock][1] + 1, clock
+        assert after[clock][0] > before[clock][0], clock
+    assert (after["stage"][0] - before["stage"][0]
+            <= after["prewarm"][0] - before["prewarm"][0])
+    # nothing where the window's metrics read (PERF.md 7 (b))
+    assert after["query_phases"] == before["query_phases"]
+    assert after["stage_parts"] == before["stage_parts"]
+
+
+def test_a_failed_prewarm_is_clocked_and_counted_as_an_error():
+    from filodb_tpu.query.scheduler import DispatchScheduler
+
+    def boom(_desc):
+        raise RuntimeError("trace failed")
+
+    sched = DispatchScheduler(window_ms=0, prewarm_min_count=1)
+    sched.register_prewarmer(boom)
+    sched.key_ring.observe("k", {"promql": "up", "step_ms": 1, "span_ms": 1})
+    errors = REGISTRY.counter("filodb_prewarm", outcome="error")
+    n0, clock0 = errors.value, _hist_sum("filodb_prewarm_seconds")
+    assert sched.prewarm_tick(storms={}) == []
+    assert errors.value == n0 + 1
+    assert _hist_sum("filodb_prewarm_seconds")[1] == clock0[1] + 1
+
+
+def test_ingest_routed_books_one_observation_a_call():
+    ms = TimeSeriesMemStore(StoreConfig())
+    ms.setup(Dataset("ds"), list(range(4)))
+    before = _hist_sum("filodb_ingest_seconds", dataset="ds")
+    for call in range(3):
+        assert ms.ingest_routed("ds", counter_batch(
+            n_series=8, n_samples=20, start_ms=BASE + call * 200_000),
+            spread=2) == 160
+    after = _hist_sum("filodb_ingest_seconds", dataset="ds")
+    assert after[1] - before[1] == 3
+    assert after[0] > before[0]
+
+
+STARTUP_STAGES = ("import", "backend", "store", "listen")
+
+
+@pytest.fixture(scope="module")
+def started():
+    """/metrics of a FiloServer that has started, and the clock around it."""
+    from benchmarks.chip import readers
+    from filodb_tpu.server import FiloServer, process_start_time
+
+    t0 = time.time()
+    srv = FiloServer({"shards": 2, "http_port": 0})
+    port = srv.start()
+    wall = time.time() - t0
+    try:
+        with urllib.request.urlopen(f"http://127.0.0.1:{port}/metrics") as r:
+            text = r.read().decode()
+    finally:
+        srv.stop()
+    return readers.parse_metrics(text), t0, wall, process_start_time()
+
+
+@pytest.mark.parametrize("stage", STARTUP_STAGES)
+def test_startup_seconds_has_its_four_stages(started, stage):
+    counters, _t0, _wall, _born = started
+    got = {dict(ls)["stage"].strip('"'): v for (n, ls), v in counters.items()
+           if n == "filodb_startup_seconds"}
+    assert sorted(got) == sorted(STARTUP_STAGES)
+    assert got[stage] >= 0
+
+
+def test_startup_stages_add_up_to_the_start_and_the_process_start_is_exact(started):
+    from benchmarks.chip import readers
+
+    counters, t0, wall, born = started
+    # /metrics prints a gauge in full where six digits would lose it
+    assert readers.total(counters, "process_start_time_seconds") == born
+    assert born <= t0
+    stages = {s: readers.total(counters, "filodb_startup_seconds", stage=s)
+              for s in STARTUP_STAGES}
+    assert stages["import"] == pytest.approx(t0 - born, abs=0.5)
+    inside = stages["backend"] + stages["store"] + stages["listen"]
+    assert inside <= wall + 1e-3
+    assert inside >= 0.9 * wall - 0.05  # but for the caller's own gap
+
+
+@pytest.mark.filterwarnings("ignore::DeprecationWarning")
+def test_the_new_spans_are_host_events_of_a_profiler_trace(tmp_path):
+    """``ingest:routed``, ``sched:run``, ``fused:groups`` and ``prewarm:key``
+    on a trace's clock; the pool's hop is the gap between ``sched:run``'s
+    start and the worker's plan span."""
+    from filodb_tpu.coordinator.scheduler import QueryScheduler
+    from filodb_tpu.query.scheduler import DispatchScheduler
+
+    pool = QueryScheduler(parallelism=2)
+    sched = DispatchScheduler(window_ms=0, prewarm_min_count=1)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        eng = _engine(scheduler=pool, dispatch_scheduler=sched)
+        eng.query_range(QUERIES["counter"], START_S, END_S, 60)
+        # the served query put its key (and its lag behind now) in the ring
+        assert len(sched.prewarm_tick(storms={})) == 1
+    finally:
+        jax.profiler.stop_trace()
+        pool.shutdown()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    spans = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if dict(e.stats).get("trace_id") is not None:
+                    spans.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    for name in ("ingest:routed", "sched:run", "fused:groups", "prewarm:key"):
+        assert name in spans, sorted(spans)
+    (run,) = spans["sched:run"]
+    plan = min(spans["FusedAggregateExec"])  # the served one: the first
+    assert run[0] <= plan[0] and plan[1] <= run[1]
+    warm = spans["prewarm:key"][0]
+    assert any(warm[0] <= a and b <= warm[1] for a, b in spans["fused:groups"])
