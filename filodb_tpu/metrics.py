@@ -122,6 +122,7 @@ HELP_TEXTS: dict[str, str] = {
     "filodb_fused_fallback": "Fused single-dispatch aggregates delegated to the reference tree, by reason.",
     "filodb_group_reduce": "Fused scalar dispatches by the form of their cross-series reduction (wide = exact int32 pieces, plain = f32 segment reduce).",
     "filodb_fused_dispatch": "Fused launches by the range body that ran (after any degradation) and the grid class of the block it ran on (regular|jitter|holes|irregular): an irregular grid runs off the mxu > jitter > masked ladder, on pallas or general.",
+    "filodb_pallas_lane_tiles": "Lane tiles (128 samples of a series tile) under the steps of the Pallas gather-scan's launches: kind=scanned the tiles the steps read, kind=resident the tiles of the rows before them; scanned / resident near 2 / (T / 128) says the narrow scan engaged, 1 that every step read whole rows.",
     "filodb_stage_cache_insert_dropped": "Staged blocks not cached because ingest effects touched their range.",
     "filodb_superblock_maintenance": "Version-stale superblock maintenance outcomes (revalidate|extend|extend_abort|restage).",
     "filodb_downsample_claims": "Distributed-downsample claim lifecycle events.",

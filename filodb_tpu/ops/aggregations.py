@@ -1047,6 +1047,11 @@ def _fused_dispatch(func: str, epilogue: tuple, block, num_groups: int,
     # a dispatch that fell off the ladder (mxu > jitter > masked) says so
     REGISTRY.counter("filodb_fused_dispatch", body=body_name,
                      grid=grid_class(block)).inc()
+    if body_name == "pallas":  # never batched: ``params`` is this launch's
+        from .pallas_kernels import book_lane_tiles
+
+        book_lane_tiles(block, params.start_ms - block.base_ms,
+                        params.step_ms, params.window_ms, j_pad)
     t0 = time.perf_counter()
     spec = FusedSpec(
         body_name, func, epilogue, num_groups,

@@ -8,10 +8,22 @@ first/last value, first raw value and the last pair's interval and
 difference — in ONE pass with the block resident in VMEM, tiled ``(BS
 series x BJ steps)`` over a grid that reuses the series block across step
 tiles (the block index map keeps ts/vals constant along the step axis, so
-Pallas skips the re-fetch DMA). The kernel is VPU-bound (a ``[BS, T]``
-mask and two operations a statistic for each of BJ steps), so it is built
-for the statistics its function's finisher reads and no other: the set is
-static, chosen while tracing (``FUNC_STATS``, ``stat_set``).
+Pallas skips the re-fetch DMA). A step costs a mask and two operations a
+statistic over every vreg it reads, and then a trip through the cross-lane
+unit for each reduce in its chain, so the kernel is built for the
+statistics its function's finisher reads and no other (the set is static,
+chosen while tracing: ``FUNC_STATS``, ``stat_set``), and a step reads only
+the lane tiles its window can touch: a ``[5m]`` window at a 10 s scrape
+holds 30 samples of a row's 768 lanes. ``_lane_tile_steer`` makes, inside
+the same jitted program, a table of the smallest and largest valid
+timestamp of each (BS series x LANES lanes) tile and from it, for every
+(series tile, step), the first of the NARROW adjacent lane tiles that hold
+the step's window; the verdicts reach the kernel as scalars in SMEM. A tile
+of the grid whose BJ steps all fit scans ``[BS, NARROW * LANES]`` slices at a
+dynamic, 128-aligned lane offset; one with a step that does not (a ``[1h]``
+window, 1 s and 60 s series in one tile, lanes out of time order) scans
+whole rows, as every block one or two lane tiles wide does with no table at
+all. The test holds for any block: only the speed rests on the data.
 
 A small jit finisher then derives the range function from these statistics
 (Prometheus extrapolation for rate/increase/delta; irate/idelta from the
@@ -34,6 +46,8 @@ from .staging import StagedBlock
 
 BS = 64   # series per tile (second-to-last block dim: multiple of 8)
 BJ = 128  # steps per tile (last block dim: hardware requires a multiple of 128)
+LANES = 128  # samples per lane tile: the unit a step's scan leaves out
+NARROW = 2  # lane tiles the narrow scan reads: a window's samples astride one boundary
 NEG = -3.0e38  # python literals: jnp scalars would be captured consts
 POS = 3.0e38
 
@@ -82,74 +96,160 @@ def stat_set(func: str, is_counter: bool = False, is_delta: bool = False) -> tup
     return stats
 
 
-def _window_agg_kernel(stats, params_ref, ts_ref, vals_ref, *refs):
+def _step_stats(want, ts, vals, raw, valid, t_j, window):
+    """The window statistics of ONE step over the lanes given: ``[BS, W]``
+    operands (the whole row, or the slice of it the window can touch) ->
+    ``[BS]`` each."""
+    IMAX = jnp.int32(2**31 - 1)
+    IMIN = jnp.int32(-(2**31) + 1)
+    m = (ts <= t_j) & (ts > t_j - window) & valid
+    new = {"count": m.astype(jnp.float32).sum(axis=1)}
+    if "sum" in want:
+        new["sum"] = jnp.where(m, vals, 0.0).sum(axis=1)
+    if "min" in want:
+        new["min"] = jnp.where(m, vals, POS).min(axis=1)
+    if "max" in want:
+        new["max"] = jnp.where(m, vals, NEG).max(axis=1)
+    # boundary selection in exact int32 time (f32 would round >2^24 ms)
+    if want & {"t_first", "v_first", "raw_first"}:
+        tmin = jnp.where(m, ts, IMAX).min(axis=1)
+        first_m = m & (ts == tmin[:, None])
+        new["t_first"] = tmin.astype(jnp.float32)
+        new["v_first"] = jnp.where(first_m, vals, 0.0).sum(axis=1)
+        if raw is not None:
+            new["raw_first"] = jnp.where(first_m, raw, 0.0).sum(axis=1)
+    if want & {"t_last", "v_last", "dt_last", "dv_last"}:
+        tmax = jnp.where(m, ts, IMIN).max(axis=1)
+        last_m = m & (ts == tmax[:, None])
+        new["t_last"] = tmax.astype(jnp.float32)
+        new["v_last"] = jnp.where(last_m, vals, 0.0).sum(axis=1)
+    if want & {"dt_last", "dv_last"}:
+        # the sample before the last one in the window; a window of
+        # fewer than two leaves trash the finisher masks (count < 2)
+        before = m & (ts < tmax[:, None])
+        tprev = jnp.where(before, ts, IMIN).max(axis=1)
+        prev_m = before & (ts == tprev[:, None])
+        new["dt_last"] = (tmax - tprev).astype(jnp.float32)
+        new["dv_last"] = new["v_last"] - jnp.where(prev_m, vals, 0.0).sum(axis=1)
+    return new
+
+
+def _window_agg_kernel(stats, narrow, params_ref, *refs):
     """One (BS series x BJ steps) tile: the statistics named in ``stats``
     (static: a Python-level choice while tracing, no runtime branch), one
-    output ref each, after the ``raw`` operand where ``raw_first`` is read."""
+    output ref each, after the ``raw`` operand where ``raw_first`` is read.
+    With ``narrow`` (static) the tile reads ``steer_ref``'s verdict first:
+    each step scans NARROW lane tiles from the one it names, or every step
+    the whole row."""
     want = frozenset(stats)
     refs = list(refs)
+    steer_ref = refs.pop(0) if narrow else None
+    ts_ref, vals_ref = refs.pop(0), refs.pop(0)
     raw_ref = refs.pop(0) if "raw_first" in want else None
     lens_ref, *out_refs = refs
     start = params_ref[0]
     step = params_ref[1]
     window = params_ref[2]
     j0 = pl.program_id(1) * BJ
-    ts = ts_ref[:]  # [BS, T] i32
-    vals = vals_ref[:]
     lens = lens_ref[:]  # [BS, 1]
-    T = ts.shape[1]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (ts.shape[0], T), 1)
-    valid = lane < lens
-    IMAX = jnp.int32(2**31 - 1)
-    IMIN = jnp.int32(-(2**31) + 1)
+    rows, T = ts_ref.shape
     # column one-hot accumulation: per step jj compute [BS] stats and add
     # stat ⊗ onehot(jj) into [BS, BJ] carries — vector-only ops (no dynamic
     # stores), so Mosaic lowers it; a BJ=128 static unroll would explode
     # compile time and a (BS, <128) output block is rejected by hardware
     col = jax.lax.broadcasted_iota(jnp.int32, (1, BJ), 1)
 
-    def body(jj, accs):
-        t_j = start + (j0 + jj) * step
-        m = (ts <= t_j) & (ts > t_j - window) & valid
-        new = {"count": m.astype(jnp.float32).sum(axis=1)}
-        if "sum" in want:
-            new["sum"] = jnp.where(m, vals, 0.0).sum(axis=1)
-        if "min" in want:
-            new["min"] = jnp.where(m, vals, POS).min(axis=1)
-        if "max" in want:
-            new["max"] = jnp.where(m, vals, NEG).max(axis=1)
-        # boundary selection in exact int32 time (f32 would round >2^24 ms)
-        if want & {"t_first", "v_first", "raw_first"}:
-            tmin = jnp.where(m, ts, IMAX).min(axis=1)
-            first_m = m & (ts == tmin[:, None])
-            new["t_first"] = tmin.astype(jnp.float32)
-            new["v_first"] = jnp.where(first_m, vals, 0.0).sum(axis=1)
-            if raw_ref is not None:
-                new["raw_first"] = jnp.where(first_m, raw_ref[:], 0.0).sum(axis=1)
-        if want & {"t_last", "v_last", "dt_last", "dv_last"}:
-            tmax = jnp.where(m, ts, IMIN).max(axis=1)
-            last_m = m & (ts == tmax[:, None])
-            new["t_last"] = tmax.astype(jnp.float32)
-            new["v_last"] = jnp.where(last_m, vals, 0.0).sum(axis=1)
-        if want & {"dt_last", "dv_last"}:
-            # the sample before the last one in the window; a window of
-            # fewer than two leaves trash the finisher masks (count < 2)
-            before = m & (ts < tmax[:, None])
-            tprev = jnp.where(before, ts, IMIN).max(axis=1)
-            prev_m = before & (ts == tprev[:, None])
-            new["dt_last"] = (tmax - tprev).astype(jnp.float32)
-            new["dv_last"] = new["v_last"] - jnp.where(prev_m, vals, 0.0).sum(axis=1)
-        hot = col == jj  # [1, BJ] bool
-        # select, don't multiply: NaN stats (stale markers, parsed 'NaN'
-        # samples) must stay confined to their own step (NaN * 0 == NaN).
-        # An entry of ``new`` that ``stats`` does not name is a [BS] cast
-        # or subtraction at most, and dead code.
-        return tuple(a + jnp.where(hot, new[k][:, None], 0.0) for a, k in zip(accs, stats))
+    def steps(read):
+        """The BJ steps, each over the lanes ``read(jj)`` gives it: (ts,
+        vals, raw, valid). What does not depend on ``jj`` is made before
+        the loop: a ``[BS, 1]`` -> lanes broadcast is a trip through the
+        cross-lane unit, as long as a step's whole reduce."""
+        def body(jj, accs):
+            t_j = start + (j0 + jj) * step
+            new = _step_stats(want, *read(jj), t_j, window)
+            hot = col == jj  # [1, BJ] bool
+            # select, don't multiply: NaN stats (stale markers, parsed 'NaN'
+            # samples) must stay confined to their own step (NaN * 0 == NaN).
+            # An entry of ``new`` that ``stats`` does not name is a [BS] cast
+            # or subtraction at most, and dead code.
+            return tuple(a + jnp.where(hot, new[k][:, None], 0.0) for a, k in zip(accs, stats))
 
-    zero = jnp.zeros((ts.shape[0], BJ), jnp.float32)
-    accs = jax.lax.fori_loop(0, BJ, body, (zero,) * len(stats))
+        zero = jnp.zeros((rows, BJ), jnp.float32)
+        return jax.lax.fori_loop(0, BJ, body, (zero,) * len(stats))
+
+    def whole_row():
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, T), 1)
+        row = (ts_ref[:], vals_ref[:], None if raw_ref is None else raw_ref[:], lane < lens)
+        return steps(lambda jj: row)
+
+    def lane_tiles():
+        width = NARROW * LANES
+        # lane0 + lane < lens  <=>  lane0 < room
+        room = lens - jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+
+        def read(jj):
+            lane0 = pl.multiple_of(steer_ref[0, jj] * LANES, LANES)
+            at = (slice(None), pl.ds(lane0, width))
+            return (ts_ref[at], vals_ref[at],
+                    None if raw_ref is None else raw_ref[at], room > lane0)
+
+        return steps(read)
+
+    if narrow:
+        accs = jax.lax.cond(steer_ref[0, 0] >= 0, lane_tiles, whole_row)
+    else:
+        accs = whole_row()
     for ref, acc in zip(out_refs, accs):
         ref[:] = acc
+
+
+def _pad_bj(num_steps: int) -> int:
+    return ((num_steps + BJ - 1) // BJ) * BJ
+
+
+def _narrow_scan(T: int) -> bool:
+    """Whether a block of padded width ``T`` has lane tiles to leave out."""
+    return T % LANES == 0 and T > NARROW * LANES
+
+
+def _pad_series(lens, ts, *rows):
+    """``lens`` and the ``[S, T]`` rows padded to whole BS tiles with empty
+    series: ``(lens, [ts, *rows])``."""
+    short = -ts.shape[0] % BS
+    if not short:
+        return lens, [ts, *rows]
+    pad = ((0, short), (0, 0))
+    return jnp.pad(lens, ((0, short),)), [
+        jnp.pad(ts, pad, constant_values=2**31 - 1), *(jnp.pad(r, pad) for r in rows)]
+
+
+def _lane_tile_steer(ts, lens, start_off, step_ms, window_ms, J: int):
+    """``[S_pad/BS, J]`` int32, for each series tile and step the FIRST of
+    the NARROW lane tiles the step's window can touch; -1 through a whole
+    (series tile, BJ steps) tile of the kernel's grid where any step of it
+    can touch more than NARROW of them (that tile reads whole rows).
+
+    Lane tile ``k`` can hold a sample of step ``j``'s window iff ``tmax[k] >
+    t_j - window`` and ``tmin[k] <= t_j``, the smallest and largest
+    timestamp among the tile's valid lanes (``lane < lens``) over the BS
+    series: true of ANY block — unsorted lanes, ragged lens, unlike scrape
+    intervals — so only the speed rests on what the data looks like."""
+    T, G, K = ts.shape[1], ts.shape[0] // BS, ts.shape[1] // LANES
+    # over the BS series first (one pass over ts, rows onto rows: no
+    # relayout), then over a tile's lanes in what is left, [G, T]
+    ts = ts.reshape(G, BS, T)
+    valid = jnp.arange(T, dtype=jnp.int32) < lens.reshape(G, BS, 1)
+    tmin = jnp.where(valid, ts, 2**31 - 1).min(axis=1)
+    tmax = jnp.where(valid, ts, -(2**31) + 1).max(axis=1)
+    tmin = tmin.reshape(G, K, LANES).min(axis=2)
+    tmax = tmax.reshape(G, K, LANES).max(axis=2)
+    t_j = (start_off + jnp.arange(J, dtype=jnp.int32) * step_ms)[None, :, None]
+    need = (tmax[:, None, :] > t_j - window_ms) & (tmin[:, None, :] <= t_j)  # [G, J, K]
+    k = jnp.arange(K, dtype=jnp.int32)
+    k_lo = jnp.where(need, k, K).min(axis=2)
+    k_hi = jnp.where(need, k, -1).max(axis=2)  # no tile needed: k_hi < k_lo
+    fits = (k_hi - k_lo < NARROW).reshape(G, J // BJ, BJ).all(axis=2)
+    return jnp.where(jnp.repeat(fits, BJ, axis=1), jnp.minimum(k_lo, K - NARROW), -1)
 
 
 @functools.partial(jax.jit, static_argnames=("num_steps", "interpret", "stats"))
@@ -158,36 +258,39 @@ def window_aggregates(ts, vals, raw, lens, start_off, step_ms, window_ms,
                       num_steps: int, interpret: bool, stats: tuple):
     """[S, T] staged block -> dict of the [S, num_steps] per-window
     statistics named in ``stats`` (a function's ``stat_set``)."""
-    S, T = ts.shape
-    S_pad = ((S + BS - 1) // BS) * BS
-    J = ((num_steps + BJ - 1) // BJ) * BJ
-    rows = [ts, vals] + ([raw] if "raw_first" in stats else [])
-    if S_pad != S:
-        pad = ((0, S_pad - S), (0, 0))
-        rows[0] = jnp.pad(ts, pad, constant_values=2**31 - 1)
-        rows[1:] = [jnp.pad(r, pad) for r in rows[1:]]
-        lens = jnp.pad(lens, ((0, S_pad - S),))
+    J = _pad_bj(num_steps)
+    lens, rows = _pad_series(lens.astype(jnp.int32), ts, vals,
+                             *([raw] if "raw_first" in stats else []))
+    S_pad, T = rows[0].shape
     from jax.experimental.pallas import tpu as pltpu
 
     params = jnp.stack([start_off, step_ms, window_ms]).astype(jnp.int32)
-    lens2 = lens[:, None].astype(jnp.int32)
+    narrow = _narrow_scan(T)
     grid = (S_pad // BS, J // BJ)
     # index maps receive the scalar-prefetch ref as a trailing arg
     row_spec = pl.BlockSpec((BS, T), lambda i, j, *_: (i, 0))
     out_spec = pl.BlockSpec((BS, BJ), lambda i, j, *_: (i, j))
     out_shape = [jax.ShapeDtypeStruct((S_pad, J), jnp.float32)] * len(stats)
+    steer, steer_spec = [], []
+    if narrow:
+        # a tile's BJ verdicts reach the kernel as scalars: an SMEM block
+        # (Mosaic wants its second-to-last dimension whole: [G, 1, J])
+        steer = [_lane_tile_steer(rows[0], lens, *params, J)[:, None, :]]
+        steer_spec = [pl.BlockSpec((None, 1, BJ), lambda i, j, *_: (i, 0, j),
+                                   memory_space=pltpu.SMEM)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,  # params land in SMEM before the pipeline
         grid=grid,
-        in_specs=[row_spec] * len(rows) + [pl.BlockSpec((BS, 1), lambda i, j, *_: (i, 0))],
+        in_specs=steer_spec + [row_spec] * len(rows)
+        + [pl.BlockSpec((BS, 1), lambda i, j, *_: (i, 0))],
         out_specs=[out_spec] * len(stats),
     )
     outs = pl.pallas_call(
-        functools.partial(_window_agg_kernel, stats),
+        functools.partial(_window_agg_kernel, stats, narrow),
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(params, *rows, lens2)
+    )(params, *steer, *rows, lens[:, None])
     return dict(zip(stats, outs))
 
 
@@ -196,6 +299,43 @@ def interpret_mode() -> bool:
     (tier-1). On an accelerator it is always compiled — a kernel Mosaic
     refuses is an error to repair, never a quiet interpreter run."""
     return jax.devices()[0].platform == "cpu"
+
+
+@functools.partial(jax.jit, static_argnames=("num_steps",))
+def _narrow_grid_tiles(ts, lens, start_off, step_ms, window_ms, num_steps: int):
+    """How many (series tile, BJ steps) tiles of ``window_aggregates``'
+    grid scan NARROW lane tiles a step: its own table, its own test."""
+    lens, (ts,) = _pad_series(lens.astype(jnp.int32), ts)
+    steer = _lane_tile_steer(ts, lens, start_off, step_ms, window_ms, _pad_bj(num_steps))
+    return (steer[:, ::BJ] >= 0).sum()
+
+
+def book_lane_tiles(block: StagedBlock, start_off, step_ms, window_ms,
+                    num_steps: int) -> None:
+    """Book one launch of the kernel over ``block``:
+    ``filodb_pallas_lane_tiles_total{kind="scanned"}`` the lane tiles its
+    steps read, ``{kind="resident"}`` the lane tiles of the rows they had
+    before them (a step that scans its whole row reads them all), so scanned
+    / resident says how far the narrow scan engaged. The count is a device
+    reduction of the steering table; the first launch of a (block, window)
+    waits for it once, a repeated panel reads the memo."""
+    from ..metrics import REGISTRY
+    from ..singleflight import memo_on
+
+    S, T = block.ts.shape
+    J, K = _pad_bj(num_steps), -(-T // LANES)
+    tiles = -(-S // BS) * (J // BJ)  # the kernel's grid
+    window = (int(start_off), int(step_ms), int(window_ms))
+
+    def narrow_tiles() -> int:
+        if not _narrow_scan(T):
+            return 0
+        return int(_narrow_grid_tiles(block.ts, block.lens, *map(np.int32, window), J))
+
+    narrow = memo_on(block, "_lane_tile_memo", (*window, J), narrow_tiles)
+    REGISTRY.counter("filodb_pallas_lane_tiles", kind="scanned").inc(
+        BJ * (narrow * NARROW + (tiles - narrow) * K))
+    REGISTRY.counter("filodb_pallas_lane_tiles", kind="resident").inc(BJ * tiles * K)
 
 
 # Widest staged block (padded samples per series) the kernel is selected
@@ -298,6 +438,7 @@ def run_pallas_range_function(func: str, block: StagedBlock, params,
     J = pad_steps(params.num_steps)
     start_off = np.int32(params.start_ms - block.base_ms)
     raw = block.raw if block.raw is not None else block.vals
+    book_lane_tiles(block, start_off, params.step_ms, params.window_ms, J)
     agg = window_aggregates(
         block.ts, block.vals, raw, block.lens,
         start_off, np.int32(params.step_ms), np.int32(params.window_ms), J,
@@ -316,6 +457,7 @@ def _register_kernel_observatory() -> None:
         "ops.pallas_kernels",
         window_aggregates=window_aggregates,
         finish=finish,
+        _narrow_grid_tiles=_narrow_grid_tiles,
     )
 
 

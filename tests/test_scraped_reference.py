@@ -238,6 +238,15 @@ def _launches(port: int) -> dict:
     return out
 
 
+def _lane_tiles(port: int) -> dict:
+    """{kind: lane tiles so far} of the Pallas body's launches, off /metrics."""
+    out = {"scanned": 0.0, "resident": 0.0}
+    for (name, labels), v in readers.parse_metrics(_get(port, "/metrics")).items():
+        if name == "filodb_pallas_lane_tiles_total":
+            out[dict(labels)["kind"].strip('"')] = v
+    return out
+
+
 def test_the_planted_cases_are_in_the_fleet(fleet):
     d, out_t = fleet.data, fleet.out_t
     cnt = (scraped_panels.counts_le(d.ts[:5], d.lens[:5], out_t)
@@ -289,6 +298,32 @@ def test_the_served_path_equals_the_reference_off_the_ladder(
         grew = {k: v - before.get(k, 0) for k, v in _launches(served).items()
                 if v != before.get(k, 0)}
         assert grew == {(body, "irregular"): 1.0}, panel["name"]
+
+
+def test_the_pallas_body_books_the_lane_tiles_its_steps_read(fleet, served, monkeypatch):
+    """``filodb_pallas_lane_tiles_total``: the fleet's superblock is more
+    than two lane tiles wide and every window of a panel touches at most
+    two, so each launch books NARROW lane tiles a step as ``scanned`` and
+    the row's as ``resident``; a repeated panel books the same again (off
+    the memo on the block); a shared-grid query, on ``mxu``, books neither."""
+    from filodb_tpu.ops import pallas_kernels as PK
+
+    monkeypatch.setenv("FILODB_PALLAS", "1")
+
+    def grown(query):
+        before = _lane_tiles(served)
+        _ask(served, fleet, query)
+        after = _lane_tiles(served)
+        return {k: after[k] - before[k] for k in after}
+
+    for panel in PANELS:
+        first = grown(panel["query"])
+        assert 0 < first["scanned"] < first["resident"], panel["name"]
+        grid_tiles, odd = divmod(first["scanned"], PK.BJ * PK.NARROW)
+        lane_tiles = first["resident"] / (grid_tiles * PK.BJ)
+        assert odd == 0 and lane_tiles == int(lane_tiles) > PK.NARROW, (panel["name"], first)
+        assert grown(panel["query"]) == first, panel["name"]
+    assert grown(f"sum(rate({REGULAR}[5m]))") == {"scanned": 0.0, "resident": 0.0}
 
 
 def test_a_late_scrape_read_at_its_slot_is_outside_the_limit(fleet, served):

@@ -538,4 +538,23 @@ def test_the_pallas_kernel_of_every_statistic_set_compiles_for_the_chip(one_chip
             arr((s,), jnp.int32), scalar, scalar, scalar,
             num_steps=PK.BJ, interpret=False, stats=stats).compile()
         assert "tpu_custom_call" in compiled.as_text()
-        assert compiled.memory_analysis().output_size_in_bytes >= len(stats) * s * PK.BJ * 4
+        mem = compiled.memory_analysis()
+        assert mem.output_size_in_bytes >= len(stats) * s * PK.BJ * 4
+        # the lane-tile table is one pass over ts: no masked copy of it
+        assert mem.temp_size_in_bytes < s * t * 4 // 8
+
+
+def test_the_count_of_narrow_grid_tiles_compiles_for_the_chip(one_chip):
+    """The counter's own program (`book_lane_tiles`: once a block and
+    window) at the same two shapes, also without a copy of ``ts``."""
+    import jax.numpy as jnp
+
+    from filodb_tpu.ops import pallas_kernels as PK
+
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    for s, t in ((131072, 768), (4096, PK.MAX_T)):
+        compiled = PK._narrow_grid_tiles.lower(
+            jax.ShapeDtypeStruct((s, t), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((s,), jnp.int32, sharding=one_chip),
+            scalar, scalar, scalar, num_steps=PK.BJ).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < s * t * 4 // 8
