@@ -124,6 +124,7 @@ HELP_TEXTS: dict[str, str] = {
     "filodb_fused_dispatch": "Fused launches by the range body that ran (after any degradation) and the grid class of the block it ran on (regular|jitter|holes|irregular): an irregular grid runs off the mxu > jitter > masked ladder, on pallas or general.",
     "filodb_pallas_lane_tiles": "Lane tiles (128 samples of a series tile) under the steps of the Pallas gather-scan's launches: kind=scanned the tiles the steps read, kind=resident the tiles of the rows before them; scanned / resident near 2 / (T / 128) says the narrow scan engaged, 1 that every step read whole rows.",
     "filodb_hist_rescale_series": "Series of base-2 exponential histograms a fused launch merged onto their group's scale, by how: rescaled = downscaled onto a coarser group scale, native = already at it.",
+    "filodb_hist_merge": "Fused launches over base-2 exponential histograms by how the group sum ran: onehot = a 0/1 membership product on the MXU (up to 128 groups, trash group included), segment = a segment_sum.",
     "filodb_ingest_scheme_refused": "Histogram rows refused at ingest because their bucket scheme is explicit against a base-2 partition or base-2 against an explicit one.",
     "filodb_ingest_scheme_moved": "Base-2 histogram partitions whose scheme a batch widened at ingest (range grown or scale lowered: the join of the two, fit to 160 buckets).",
     "filodb_stage_cache_insert_dropped": "Staged blocks not cached because ingest effects touched their range.",

@@ -556,11 +556,13 @@ def _hist_sharded_combine(sjb, epilogue: tuple, gids_l, les, qv,
 # ``base2_grid`` (each window's whole-count increase a bucket and the [J]
 # rate factor every series shares), elsewhere the per-series rates. The
 # epilogue ("hist2", kind, W) then merges every series onto its group's
-# smallest scale — a gather along the bucket axis, one column index a
-# (series, output column) — sums by group (exactly, for whole counts) and,
-# for the quantile, interpolates on each group's own bounds. Explicit-bucket
-# blocks keep ("hist", ...) and hist_shared's own grid: their program does
-# not change.
+# smallest scale and sums by group, both as exact contractions on the MXU:
+# each value cut into three bf16 pieces, the merge a product with a [B, W]
+# 0/1 selection a series (one 1 a column), the sum a product with the
+# [G, S] 0/1 membership (segment_sum past WIDE_ONEHOT_MAX_GROUPS groups),
+# both accumulated in f32 — and, for the quantile, interpolates on each
+# group's own bounds. Explicit-bucket blocks keep ("hist", ...) and
+# hist_shared's own grid: their program does not change.
 
 
 @functools.partial(jax.jit, static_argnames=("num_groups",))
@@ -614,39 +616,104 @@ def base2_group_plan(block, gids_dev, num_groups: int, scheme_dev, key):
     return memo_on(block, "_base2_plan", key, build)
 
 
+def hist_merge_form(num_groups: int) -> str:
+    """How a base-2 launch sums its groups: ``"onehot"``, a product with
+    the [G, S] 0/1 membership on the MXU, up to WIDE_ONEHOT_MAX_GROUPS
+    groups (the trash group counted, the scalar wide sum's rule); past it
+    the one-hot is the larger operand, and ``"segment"`` keeps the
+    segment_sum. Every launch counts its form: filodb_hist_merge_total."""
+    few = num_groups + 1 <= WIDE_ONEHOT_MAX_GROUPS
+    return "onehot" if few else "segment"
+
+
+def _bf16_pieces(x):
+    """``(hi, mid, lo)`` bf16 whose f32 sum is ``x`` exactly (finite, not
+    near f32's smallest normal): each the next 8 significant bits, cut by
+    clearing mantissa bits (a round trip through bf16 is a convert pair a
+    compiler may fold away), so a product with 0/1 entries accumulated in
+    f32 loses nothing."""
+    pieces = []
+    for _ in range(2):
+        bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+        top = jax.lax.bitcast_convert_type(bits & jnp.int32(~0xFFFF),
+                                           jnp.float32)
+        pieces.append(top)
+        x = x - top
+    return tuple(p.astype(jnp.bfloat16) for p in pieces + [x])
+
+
 @jax.named_scope("hist_rescale")
 def _base2_rescale(sjb, gids, shared, width: int):
-    """[S, J, B] rates at each series' own scheme -> [S, J, W] on its
+    """[S, J, B] values at each series' own scheme -> [S, J, W] on its
     group's: column 0 the zero bucket, column k the bound
     b_g^(offset_g + k) = the series' fine bound (offset_g + k) * 2^d,
     clipped to its own range (below: its zero count; above: its total),
-    and its total from the group's +Inf column on."""
+    and its total from the group's +Inf column on. One product on the MXU:
+    each value's three bf16 pieces side by side on the contracted axis, the
+    0/1 selection (one 1 a column) repeated under each, so a column sums
+    the pieces of ONE value — exact in f32 in any order. ``sjb`` holds no
+    NaN (a 0 times a NaN is a NaN)."""
     scale, offset, n, s_g, o_g, k_g = shared[:6]
+    B = sjb.shape[2]
     d = scale - s_g[gids]
     k = jnp.arange(width, dtype=jnp.int32)[None, :]
     fine = (o_g[gids][:, None] + k) * jnp.left_shift(jnp.int32(1), d)[:, None]
     idx = jnp.clip(fine - offset[:, None], 0, n[:, None])
     idx = jnp.where(k == 0, 0,
                     jnp.where(k > k_g[gids][:, None], n[:, None] + 1, idx))
-    return jnp.take_along_axis(sjb, idx[:, None, :], axis=2)
+    pick = ((jnp.arange(3 * B, dtype=jnp.int32) % B)[None, :, None]
+            == idx[:, None, :]).astype(jnp.bfloat16)  # [S, 3B, W]
+    pieces = jnp.concatenate(_bf16_pieces(sjb), axis=2)  # [S, J, 3B]
+    return jax.lax.dot_general(pieces, pick, (((2,), (1,)), ((0,), (0,))),
+                               preferred_element_type=jnp.float32)
+
+
+@jax.named_scope("group_reduce")
+def _base2_group_sum(sjw, ok, gids, num_groups: int):
+    """[G, J, W] sums of ``sjw`` [S, J, W] by group, NaN where no member has
+    a sample (``ok`` [S, J]). ``onehot``: the membership repeated under
+    each value's three bf16 pieces, stacked on the series axis; for whole
+    counts every partial sum is a whole number below 2^24 (PERF.md 2), so
+    the product is the segment_sum to the bit. No reshape around either
+    product: one costs the compiler a transposed copy of the operand."""
+    S, J, W = sjw.shape
+    if hist_merge_form(num_groups) == "segment":
+        return _segment_aggregate_jit(
+            "sum", jnp.where(ok[:, :, None], sjw, jnp.nan).reshape(S, J * W),
+            gids, num_groups + 1,
+        )[:num_groups].reshape(num_groups, J, W)
+    groups = jnp.arange(num_groups, dtype=gids.dtype)[:, None]  # no trash row
+    member3 = (jnp.concatenate([gids] * 3)[None, :] == groups
+               ).astype(jnp.bfloat16)  # [G, 3S]
+    total = jax.lax.dot_general(
+        member3, jnp.concatenate(_bf16_pieces(sjw), axis=0),
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    count = jax.lax.dot((gids[None, :] == groups).astype(jnp.bfloat16),
+                        ok.astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32)
+    return jnp.where(count[:, :, None] > 0, total, jnp.nan)
 
 
 def _base2_epilogue(grid, epilogue: tuple, gids, shared, qv, num_groups: int):
-    """The ("hist2", kind, W) epilogue: rescale, per-column segment sum
-    (the flattened [S, J*W] form of _hist_epilogue's), then the quantile
-    on each group's own bounds — or the [G, J, W] partials. ``grid`` is
-    ``hist_shared``'s ``base2_grid`` (whole-count increments, [J] factor): the
-    sum is exact and the factor, common to every series, comes after it
-    (the quantile never needs it: it is the same for every column); or a
-    rate grid with no factor (any other body). ``qv`` is the quantile as
-    two f32, high and low part."""
+    """The ("hist2", kind, W) epilogue: rescale, per-column group sum, then
+    the quantile on each group's own bounds — or the [G, J, W] partials.
+    ``grid`` is ``hist_shared``'s ``base2_grid`` (whole-count increments,
+    [J] factor): the sum is exact and the factor, common to every series,
+    comes after it (the quantile never needs it: it is the same for every
+    column); or a rate grid with no factor (any other body). ``qv`` is the
+    quantile as two f32, high and low part.
+
+    A sample is a whole row of buckets: every body writes a missing one as
+    NaN across its row (tests/test_base2_contract.py), so the zero bucket,
+    which every scheme has, says whether it is there. Before the products
+    the padded rows and every value that is not finite become 0, and
+    absence is rebuilt from that count after them."""
     _, kind, width = epilogue
     sjb, factor = grid if isinstance(grid, tuple) else (grid, None)
-    S, J = sjb.shape[:2]
-    sjw = _base2_rescale(sjb, gids, shared, width)
-    gjw = _segment_aggregate_jit(
-        "sum", sjw.reshape(S, J * width), gids, num_groups + 1
-    )[:num_groups].reshape(num_groups, J, width)
+    ok = ~jnp.isnan(sjb[:, :, 0]) & (gids < num_groups)[:, None]
+    sjb = jnp.where(ok[:, :, None] & jnp.isfinite(sjb), sjb, 0.0)
+    gjw = _base2_group_sum(_base2_rescale(sjb, gids, shared, width), ok,
+                           gids, num_groups)
     if kind != "quantile":
         return gjw if factor is None else gjw * factor[None, :, None]
     with jax.named_scope("epilogue"):
@@ -1315,11 +1382,13 @@ def fused_base2_hist_aggregate(func: str, block, gids_padded,
     then the ("hist2", ...) epilogue. Returns [G, J_pad] quantiles, or
     [G, J_pad, W] partials on the plan's group schemes. Books
     ``filodb_hist_rescale_series_total``: series merged onto a coarser
-    scale, and series already at their group's."""
+    scale, and series already at their group's; and one
+    ``filodb_hist_merge_total{form}`` (hist_merge_form)."""
     group_dev, width, _schemes, rescaled = plan
     REGISTRY.counter("filodb_hist_rescale_series", how="rescaled").inc(rescaled)
     REGISTRY.counter("filodb_hist_rescale_series", how="native").inc(
         block.n_series - rescaled)
+    REGISTRY.counter("filodb_hist_merge", form=hist_merge_form(num_groups)).inc()
     kind = "quantile" if q is not None else "sum"
     return _fused_dispatch(
         func, ("hist2", kind, width), block, num_groups, False, is_delta,

@@ -434,11 +434,17 @@ def test_a_jittered_fleet_merges_rates_the_same_on_both_paths():
 
 def test_every_request_is_one_program_and_reads_nothing_back():
     """Cold and warm, a base-2 query is one fused launch and reads no staged
-    block back to the host (the union rule read every block back)."""
+    block back to the host (the union rule read every block back); the
+    launch counts how its group sum ran, once (a few groups: the one-hot
+    product)."""
     f = _Fleet(15, n=8)
     for _ in range(2):
         d2h = _counter("filodb_stage_d2h_bytes")
         ran = _counter("filodb_fused_dispatch", body="hist_shared")
+        merged = {form: _counter("filodb_hist_merge", form=form)
+                  for form in ("onehot", "segment")}
         f.answer(f.fused, PANELS[2]["query"])
         assert _counter("filodb_stage_d2h_bytes") == d2h
         assert _counter("filodb_fused_dispatch", body="hist_shared") == ran + 1
+        assert _counter("filodb_hist_merge", form="onehot") == merged["onehot"] + 1
+        assert _counter("filodb_hist_merge", form="segment") == merged["segment"]
