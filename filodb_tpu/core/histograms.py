@@ -70,7 +70,9 @@ def base2_exp_buckets(scale: int, start_index: int, num: int) -> BucketScheme:
 # scheme is the join of every scheme its batches brought (an SDK's range
 # grows and its scale falls as new extremes arrive), fit to 160 buckets;
 # each chunk keeps the scheme it was written on, and a read puts it on the
-# partition's (memstore/partition.py).
+# partition's (memstore/partition.py). A point finer than the join (a delta
+# exporter's interval that saw a narrow range) is merged down onto it at
+# ingest, exactly: whole counts add.
 
 BASE2_MAX_BUCKETS = 160  # the OTel SDK's default MaxSize
 BASE2_WIDTH = BASE2_MAX_BUCKETS + 2  # + the zero column and the +Inf column
@@ -135,16 +137,7 @@ def base2_index(src: Base2Scheme, dst: Base2Scheme, width: int | None = None) ->
     the bound b_dst^K (below src's first bucket: its zero count; above its
     last: its total), and src's +Inf column from dst's +Inf on (the padding
     a wider array carries). ``dst.scale`` must not exceed ``src.scale``."""
-    width = dst.width if width is None else width
-    d = src.scale - dst.scale
-    if d < 0:
-        raise ValueError(f"cannot upscale {src} onto {dst}")
-    k = np.arange(width, dtype=np.int64)
-    fine = (dst.offset + k) * (1 << d)
-    idx = np.clip(fine - src.offset, 0, src.n)
-    idx[0] = 0
-    idx[k > dst.n] = src.n + 1
-    return idx
+    return Base2Rows([src.scale], [src.offset], [src.n]).index(dst, width)[0]
 
 
 def base2_remap(arr: np.ndarray, src: Base2Scheme, dst: Base2Scheme,
@@ -153,6 +146,58 @@ def base2_remap(arr: np.ndarray, src: Base2Scheme, dst: Base2Scheme,
     ``[..., width or dst.width]`` on ``dst`` (columns past ``dst.n + 1``
     repeat the total)."""
     return np.asarray(arr)[..., base2_index(src, dst, width)]
+
+
+class Base2Rows:
+    """A base-2 scheme a record, in bulk: ``scale``, ``offset``, ``n`` [N]
+    int64. ``RecordBatch.bucket_schemes``' form for a stream that sends a
+    scheme a point — an OTel exporter under DELTA temporality resets its
+    aggregator at each collection, so each point sits at the finest scale
+    its own interval fits and the scale rises and falls from point to
+    point. Indexing with rows (a slice or an index array) gives theirs."""
+
+    __slots__ = ("scale", "offset", "n")
+
+    def __init__(self, scale, offset, n):
+        self.scale, self.offset, self.n = (
+            np.asarray(a, np.int64) for a in (scale, offset, n))
+
+    def __len__(self) -> int:
+        return len(self.scale)
+
+    def __getitem__(self, rows) -> "Base2Rows":
+        return Base2Rows(self.scale[rows], self.offset[rows], self.n[rows])
+
+    def single(self) -> Base2Scheme | None:
+        """The one scheme every row is on, else None."""
+        first = (self.scale[0], self.offset[0], self.n[0])
+        if all((a == a[0]).all() for a in (self.scale, self.offset, self.n)):
+            return Base2Scheme(*(int(x) for x in first))
+        return None
+
+    def join(self) -> Base2Scheme:
+        """``fit_base2(merge_base2(rows))``: the scheme that holds every row."""
+        scale = int(self.scale.min())
+        has = self.n > 0
+        if not has.any():
+            return Base2Scheme(scale, 0, 0)
+        d = self.scale[has] - scale
+        lo = int((self.offset[has] >> d).min())
+        hi = int(((self.offset[has] + self.n[has] - 1) >> d).max())
+        return fit_base2(Base2Scheme(scale, lo, hi - lo + 1))
+
+    def index(self, dst: Base2Scheme, width: int | None = None) -> np.ndarray:
+        """[N, width or dst.width] ``base2_index`` of each row onto ``dst``,
+        which holds every row: take it along the columns of the rows'
+        counts."""
+        d = self.scale - dst.scale
+        if (d < 0).any():
+            raise ValueError(f"cannot upscale a row onto {dst}")
+        k = np.arange(dst.width if width is None else width, dtype=np.int64)[None, :]
+        fine = (dst.offset + k) * np.left_shift(1, d)[:, None]
+        idx = np.clip(fine - self.offset[:, None], 0, self.n[:, None])
+        idx[:, 0] = 0
+        return np.where(k > dst.n, (self.n + 1)[:, None], idx)
 
 
 def base2_les_rows(schemes, width: int) -> np.ndarray:

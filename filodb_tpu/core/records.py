@@ -16,6 +16,7 @@ from typing import Iterable, Mapping, Sequence
 
 import numpy as np
 
+from .histograms import Base2Rows
 from .schemas import Schema, canonical_partkey, partkey_hash, shard_for
 
 
@@ -32,12 +33,14 @@ class RecordBatch:
     values: dict[str, np.ndarray]
     tags: Sequence[Mapping[str, str]]
     bucket_les: np.ndarray | None = None  # histogram schemas only
-    # a base-2 exponential scheme per RECORD (core.histograms.Base2Scheme),
-    # like ``tags``: each series carries its own scale and offset, which may
-    # change from one record to the next (a series' run is cut there). When
+    # a base-2 exponential scheme per RECORD, like ``tags``: each series
+    # carries its own scale and offset, which may change from one record to
+    # the next. A list of core.histograms.Base2Scheme (a series' run is cut
+    # where it changes), or core.histograms.Base2Rows (a scheme a point, in
+    # bulk: a series' rows stay one run, each with its own scheme). When
     # set, a series' ``bucket_les`` is its scheme's (``Base2Scheme.les``),
     # and histogram columns past a record's ``width`` are padding
-    bucket_schemes: Sequence | None = None
+    bucket_schemes: Sequence | Base2Rows | None = None
 
     def __len__(self) -> int:
         return len(self.timestamps)
@@ -63,6 +66,7 @@ class RecordBatch:
         n = len(tags)
         # a run of records on one scheme repeats its object, as tags do
         ids = (None if self.bucket_schemes is None
+               or isinstance(self.bucket_schemes, Base2Rows)
                else np.fromiter(map(id, self.bucket_schemes), np.int64, n))
         i = 0
         while i < n:
@@ -95,7 +99,7 @@ class RecordBatch:
                 ix = np.concatenate(
                     [np.arange(lo, hi) for lo, hi in runs]
                 )
-            for sub, scheme in self._scheme_runs(ix, runs, ids):
+            for sub, scheme, rows in self._scheme_runs(ix, runs, ids):
                 out.append(
                     SeriesBatch(
                         schema=self.schema,
@@ -105,30 +109,38 @@ class RecordBatch:
                         bucket_les=(self.bucket_les if scheme is None
                                     else scheme.les()),
                         bucket_scheme=scheme,
+                        bucket_rows=rows,
                     )
                 )
         return out
 
     def _scheme_runs(self, ix, runs, ids) -> list:
-        """A series' rows ``ix`` (its ``runs``) cut where their base-2
-        scheme changes (an SDK's range grows and its scale falls from one
-        export to the next): ``[(rows, scheme)]`` in row order, ``ix``
-        itself when one scheme holds (explicit schemes: None). ``ids`` are
-        the schemes' object ids: only where they differ are two compared."""
+        """A series' rows ``ix`` (its ``runs``) by base-2 scheme:
+        ``[(rows, scheme, None)]`` in row order, cut where the scheme
+        changes (an SDK's range grows and its scale falls from one export
+        to the next), ``ix`` itself when one scheme holds (explicit schemes:
+        None); ``ids`` are the schemes' object ids: only where they differ
+        are two compared. From ``Base2Rows``: ``[(ix, join, rows)]``, one
+        run with each row's own scheme, or ``[(ix, scheme, None)]`` where
+        every row has the same."""
         sch = self.bucket_schemes
         if sch is None:
-            return [(ix, None)]
+            return [(ix, None, None)]
+        if isinstance(sch, Base2Rows):
+            rows = sch[ix]
+            one = rows.single()
+            return [(ix, rows.join(), rows) if one is None else (ix, one, None)]
         rows = (np.arange(ix.start, ix.stop) if isinstance(ix, slice)
                 else np.asarray(ix))
         at = np.nonzero(ids[rows[1:]] != ids[rows[:-1]])[0] + 1
         cuts = [0] + [int(j) for j in at if sch[rows[j]] != sch[rows[j - 1]]]
         if len(cuts) == 1:
-            return [(ix, sch[runs[0][0]])]
+            return [(ix, sch[runs[0][0]], None)]
         cuts.append(len(rows))
         if isinstance(ix, slice):  # contiguous: views, as one run gets
-            return [(slice(ix.start + a, ix.start + b), sch[rows[a]])
+            return [(slice(ix.start + a, ix.start + b), sch[rows[a]], None)
                     for a, b in zip(cuts, cuts[1:])]
-        return [(rows[a:b], sch[rows[a]]) for a, b in zip(cuts, cuts[1:])]
+        return [(rows[a:b], sch[rows[a]], None) for a, b in zip(cuts, cuts[1:])]
 
     def shard_split(self, spread: int, num_shards: int, options=None) -> dict[int, "RecordBatch"]:
         """Partition a batch by destination shard (gateway shardingPipeline
@@ -157,6 +169,8 @@ class RecordBatch:
                 [self.tags[i] for i in ix],
                 self.bucket_les,
                 None if self.bucket_schemes is None
+                else self.bucket_schemes[ix]
+                if isinstance(self.bucket_schemes, Base2Rows)
                 else [self.bucket_schemes[i] for i in ix],
             )
         return out
@@ -172,6 +186,9 @@ class SeriesBatch:
     values: dict[str, np.ndarray]
     bucket_les: np.ndarray | None = None
     bucket_scheme: object | None = None  # core.histograms.Base2Scheme
+    # each row's own scheme (core.histograms.Base2Rows) where they differ;
+    # ``bucket_scheme`` is then their join
+    bucket_rows: Base2Rows | None = None
 
     @property
     def partkey(self) -> bytes:

@@ -207,31 +207,42 @@ class TimeSeriesPartition:
         self._buf = ColumnArrays(buf, self.bucket_scheme)
         self._buf_len = 0
 
-    def take_scheme(self, scheme, values: Mapping[str, np.ndarray]):
+    def take_scheme(self, scheme, values: Mapping[str, np.ndarray], rows=None):
         """A batch's ``values``, on base-2 ``scheme``, put on the
         partition's scheme for ``ingest``. A batch that reaches past it (an
         SDK's range grows and its scale falls as new extremes arrive)
         widens the partition to the join of the two, fit to 160 buckets:
         the open buffer is sealed on the old scheme, which a read puts on
-        the new one; both moves are exact for cumulative counts. Columns
-        past ``scheme.width`` are a batch's padding. Returns ``(values,
-        moved)``, values None for a batch the partition cannot hold (an
-        explicit scheme against a base-2 one)."""
+        the new one; both moves are exact for cumulative counts. A row
+        finer than the partition's scheme is merged down onto it, exactly:
+        whole counts add. ``rows`` (core.histograms.Base2Rows), when given,
+        is each row's own scheme and ``scheme`` their join: a stream that
+        sends a scheme a point goes on in one pass. Columns past a row's
+        width are a batch's padding. Returns ``(values, moved, merged)``,
+        values None for a batch the partition cannot hold (an explicit
+        scheme against a base-2 one), merged the rows finer than the
+        partition's scheme."""
         own = self.bucket_scheme
         if scheme is None or own is None:
-            return (values if scheme is own else None), False
-        values = {k: v[:, :scheme.width] if v.ndim == 2 else v
-                  for k, v in values.items()}
+            return (values if scheme is own else None), False, 0
         joined = fit_base2(merge_base2([own, scheme]))
         moved = joined != own
         if moved:
             self.switch_buffers()
             self.bucket_les = joined.les()
             self.bucket_scheme = joined  # before a row is written on it
+        if rows is not None:
+            idx = rows.index(joined)
+            values = {k: np.take_along_axis(v, idx, axis=1) if v.ndim == 2 else v
+                      for k, v in values.items()}
+            return values, moved, int((rows.scale > joined.scale).sum())
+        values = {k: v[:, :scheme.width] if v.ndim == 2 else v
+                  for k, v in values.items()}
         if scheme != joined:
             values = {k: base2_remap(v, scheme, joined) if v.ndim == 2 else v
                       for k, v in values.items()}
-        return values, moved
+        n = len(next(iter(values.values())))
+        return values, moved, n if scheme.scale > joined.scale else 0
 
     def ingest(self, timestamps: np.ndarray, values: Mapping[str, np.ndarray]) -> int:
         """Append a time-ordered sample run; seals full chunks as it goes.

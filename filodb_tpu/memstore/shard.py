@@ -504,7 +504,8 @@ class TimeSeriesShard:
             self.index.update_end_time(pid, 2**62)
             self._ended.discard(pid)
         part = self.partitions[pid]
-        values, moved = part.take_scheme(sb.bucket_scheme, sb.values)
+        values, moved, merged = part.take_scheme(sb.bucket_scheme, sb.values,
+                                                 sb.bucket_rows)
         if values is None:
             # an explicit scheme against a base-2 one: its counts must not
             # be stored under the partition's bounds
@@ -521,6 +522,10 @@ class TimeSeriesShard:
 
             REGISTRY.counter("filodb_ingest_scheme_moved").inc()
             self._scheme_moves += 1
+        if merged:
+            from ..metrics import REGISTRY
+
+            REGISTRY.counter("filodb_ingest_scheme_merged").inc(merged)
         # enforce time order within the run
         ts = sb.timestamps
         if len(ts) > 1 and not (np.diff(ts) >= 0).all():

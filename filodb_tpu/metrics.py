@@ -127,6 +127,8 @@ HELP_TEXTS: dict[str, str] = {
     "filodb_hist_merge": "Fused launches over base-2 exponential histograms by how the group sum ran: onehot = a 0/1 membership product on the MXU (up to 128 groups, trash group included), segment = a segment_sum.",
     "filodb_ingest_scheme_refused": "Histogram rows refused at ingest because their bucket scheme is explicit against a base-2 partition or base-2 against an explicit one.",
     "filodb_ingest_scheme_moved": "Base-2 histogram partitions whose scheme a batch widened at ingest (range grown or scale lowered: the join of the two, fit to 160 buckets).",
+    "filodb_ingest_scheme_merged": "Base-2 histogram rows whose own scheme was finer than their partition's, merged down onto it exactly at ingest (a delta exporter's point at the finest scale its interval fits).",
+    "filodb_hist_window": "Fused launches over base-2 exponential histograms by how the range body read each window: edges = the two samples at its edges (a cumulative column's rate family, last), sums = every sample in it (a delta column's rate / increase, sum_over_time).",
     "filodb_stage_cache_insert_dropped": "Staged blocks not cached because ingest effects touched their range.",
     "filodb_superblock_maintenance": "Version-stale superblock maintenance outcomes (revalidate|extend|extend_abort|restage).",
     "filodb_downsample_claims": "Distributed-downsample claim lifecycle events.",

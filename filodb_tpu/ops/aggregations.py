@@ -31,6 +31,7 @@ from .hist_kernels import (
     _hist_range_jitter,
     _hist_range_shared,
     hist_range_kernel,
+    hist_window_form,
     histogram_quantile,
     histogram_quantile_rows,
     quantile_parts,
@@ -697,11 +698,12 @@ def _base2_group_sum(sjw, ok, gids, num_groups: int):
 def _base2_epilogue(grid, epilogue: tuple, gids, shared, qv, num_groups: int):
     """The ("hist2", kind, W) epilogue: rescale, per-column group sum, then
     the quantile on each group's own bounds — or the [G, J, W] partials.
-    ``grid`` is ``hist_shared``'s ``base2_grid`` (whole-count increments,
-    [J] factor): the sum is exact and the factor, common to every series,
-    comes after it (the quantile never needs it: it is the same for every
-    column); or a rate grid with no factor (any other body). ``qv`` is the
-    quantile as two f32, high and low part.
+    ``grid`` is ``hist_shared``'s ``base2_grid`` (whole counts — a
+    cumulative column's window increases or a delta column's window sums —
+    and a [J] factor): the sum is exact and the factor, common to every
+    series, comes after it (the quantile never needs it: it is the same for
+    every column); or a rate grid with no factor (any other body). ``qv``
+    is the quantile as two f32, high and low part.
 
     A sample is a whole row of buckets: every body writes a missing one as
     NaN across its row (tests/test_base2_contract.py), so the zero bucket,
@@ -1382,13 +1384,15 @@ def fused_base2_hist_aggregate(func: str, block, gids_padded,
     then the ("hist2", ...) epilogue. Returns [G, J_pad] quantiles, or
     [G, J_pad, W] partials on the plan's group schemes. Books
     ``filodb_hist_rescale_series_total``: series merged onto a coarser
-    scale, and series already at their group's; and one
-    ``filodb_hist_merge_total{form}`` (hist_merge_form)."""
+    scale, and series already at their group's; one
+    ``filodb_hist_merge_total{form}`` (hist_merge_form) and one
+    ``filodb_hist_window_total{form}`` (hist_kernels.hist_window_form)."""
     group_dev, width, _schemes, rescaled = plan
     REGISTRY.counter("filodb_hist_rescale_series", how="rescaled").inc(rescaled)
     REGISTRY.counter("filodb_hist_rescale_series", how="native").inc(
         block.n_series - rescaled)
     REGISTRY.counter("filodb_hist_merge", form=hist_merge_form(num_groups)).inc()
+    REGISTRY.counter("filodb_hist_window", form=hist_window_form(func, is_delta)).inc()
     kind = "quantile" if q is not None else "sum"
     return _fused_dispatch(
         func, ("hist2", kind, width), block, num_groups, False, is_delta,

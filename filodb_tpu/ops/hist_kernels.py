@@ -19,6 +19,17 @@ from .kernels import RangeParams, _bounds, pad_steps
 from .staging import StagedBlock
 
 
+def hist_window_form(func: str, is_delta: bool) -> str:
+    """How a hist range body reads a window: ``"sums"``, every sample in it
+    (sum_over_time, and rate / increase of a delta column, whose points
+    each hold only their own interval), or ``"edges"``, the samples at its
+    edges (a cumulative column's rate family, last). The one test every
+    body makes; each base-2 launch books it (filodb_hist_window_total)."""
+    if func == "sum_over_time" or (is_delta and func in ("rate", "increase")):
+        return "sums"
+    return "edges"
+
+
 def _gather3(arr, idx):
     """arr [S, T, B], idx [S, J] -> [S, J, B]."""
     T = arr.shape[1]
@@ -45,7 +56,7 @@ def hist_range_kernel(
     has = count > 0
     if func in ("last", "last_over_time"):
         return jnp.where(has, _gather3(vals, hi - 1), jnp.nan)
-    if func == "sum_over_time" or (is_delta and func in ("rate", "increase")):
+    if hist_window_form(func, is_delta) == "sums":
         cs = jnp.cumsum(vals, axis=1)
         cs = jnp.concatenate([jnp.zeros_like(cs[:, :1]), cs], axis=1)
         s = _gather3(cs, hi) - _gather3(cs, lo)
@@ -250,11 +261,16 @@ def _hist_range_shared(func, vals, lo, hi, t_first, t_last, out_t, window,
 
     if func in ("last", "last_over_time"):
         return jnp.where(has, gidx(hi - 1), jnp.nan)
-    if func == "sum_over_time" or (is_delta and func in ("rate", "increase")):
-        cs = jnp.cumsum(vals, axis=1)
-        cs = jnp.concatenate([jnp.zeros_like(cs[:, :1]), cs], axis=1)
-        s = (jnp.take(cs, jnp.clip(hi, 0, T), axis=1)
-             - jnp.take(cs, jnp.clip(lo, 0, T), axis=1))
+    if hist_window_form(func, is_delta) == "sums":
+        # each window's sum as ONE product with its [J, T] 0/1 membership,
+        # at HIGHEST: every bit of an f32 count kept (whole counts below
+        # 2^24 sum exactly). A running sum over T costs the chip ~15x the
+        # bytes, and its TPU lowering names its ops out of this scope.
+        t = jnp.arange(T, dtype=lo.dtype)[None, :]
+        member = ((t >= lo[:, None]) & (t < hi[:, None])).astype(f32)
+        s = jnp.einsum("jt,stb->sjb", member, vals,
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=f32)
         if func == "rate":
             s = s / (window.astype(f32) * 1e-3)
         return jnp.where(has, s, jnp.nan)
@@ -284,18 +300,27 @@ def _shared_rate_factor(t_first, t_last, cnt, out_t, window, f32):
 @jax.named_scope("range_fn")
 def _hist_base2_shared(func, vals, lo, hi, t_first, t_last, out_t, window,
                        is_delta: bool):
-    """The shared-grid hist body for base-2 exponential histograms:
-    ``(increments [S, J, B], factor [J])`` for the rate family of a
-    cumulative column — each window's count increase per bucket (whole
-    numbers, exact in f32) and the extrapolation factor that makes it the
-    rate, which is the same for every series on a shared grid — so the
-    epilogue sums whole counts exactly and applies the factor once, after
-    the sum: sum(rate) == rate of the summed increase there. Any other
-    function: ``(_hist_range_shared's grid, None)``."""
+    """The shared-grid hist body for base-2 exponential histograms: the rate
+    family as ``(whole counts [S, J, B], factor [J])``, so the epilogue sums
+    whole counts exactly and applies the factor once, after the sum —
+    sum(rate) == rate of the summed counts, because the factor is the same
+    for every series on a shared grid. A cumulative column's counts are
+    each window's increase a bucket and its factor the extrapolation
+    factor; a delta column's (and sum_over_time's) are each window's sum
+    (below 2^24 a bucket: exact in f32, one product) and its factor 1 /
+    window for ``rate``, else 1. Any other function: ``(_hist_range_shared's
+    grid, None)``."""
+    f32 = vals.dtype
+    if hist_window_form(func, is_delta) == "sums":
+        sums = _hist_range_shared("sum_over_time", vals, lo, hi, t_first,
+                                  t_last, out_t, window, is_delta)
+        factor = jnp.ones(lo.shape, f32)
+        if func == "rate":
+            factor = factor / (window.astype(f32) * 1e-3)
+        return sums, factor
     if is_delta or func not in ("rate", "increase", "delta"):
         return _hist_range_shared(func, vals, lo, hi, t_first, t_last, out_t,
                                   window, is_delta), None
-    f32 = vals.dtype
     T = vals.shape[1]
     cnt = (hi - lo).astype(f32)
     dlt = (jnp.take(vals, jnp.clip(hi - 1, 0, T - 1), axis=1)
@@ -342,7 +367,7 @@ def _hist_range_jitter(func, vals, dev, hwa, window, is_delta: bool):
     if func in ("last", "last_over_time"):
         vL0, vKlo, vKhi = tk(vals, idx[1]), tk(vals, idx[3]), tk(vals, idx[4])
         return jnp.where(has3, w3(ih3, vKhi, c03, vL0, vKlo), nan)
-    if func == "sum_over_time" or (is_delta and func in ("rate", "increase")):
+    if hist_window_form(func, is_delta) == "sums":
         cs = jnp.cumsum(vals, axis=1)
         cs = jnp.concatenate([jnp.zeros_like(cs[:, :1]), cs], axis=1)
         s = (jnp.take(cs, jnp.clip(chi, 0, T), axis=1)
