@@ -617,6 +617,36 @@ def base2_group_plan(block, gids_dev, num_groups: int, scheme_dev, key):
     return memo_on(block, "_base2_plan", key, build)
 
 
+# a block whose every value is a whole number below this in magnitude reads
+# a cumulative window's increase as one +-1 product, bit for bit the gathers
+EDGE_PRODUCT_BOUND = float(1 << 23)
+
+
+@jax.jit
+def _whole_below_bound(vals):
+    """True where every value of ``vals`` is a whole number below
+    EDGE_PRODUCT_BOUND in magnitude (so none is a NaN or an Inf)."""
+    return jnp.all((jnp.abs(vals) < EDGE_PRODUCT_BOUND)
+                   & (vals == jnp.round(vals)))
+
+
+def hist_edge_form(block, func: str, is_delta: bool) -> str | None:
+    """How a base-2 launch reads a cumulative window's increase (the rate
+    family of a cumulative column; None for any other launch): ``"product"``,
+    one +-1 product on the MXU (hist_kernels._hist_base2_shared), where
+    the shared-grid body runs and every value of the block is a whole
+    number below EDGE_PRODUCT_BOUND — one device reduction a block,
+    memoised on it (a superblock that moves is a new block); ``"gather"``,
+    the samples at each window's edges, elsewhere. A static of the launch;
+    every such launch counts its form: filodb_hist_edges_total."""
+    if is_delta or func not in ("rate", "increase", "delta"):
+        return None
+    if _fused_body(True, block, func, is_delta, None)[0] != "hist_shared":
+        return "gather"
+    return memo_on(block, "_edge_form", "form", lambda: (
+        "product" if bool(_whole_below_bound(block.vals)) else "gather"))
+
+
 def hist_merge_form(num_groups: int) -> str:
     """How a base-2 launch sums its groups: ``"onehot"``, a product with
     the [G, S] 0/1 membership on the MXU, up to WIDE_ONEHOT_MAX_GROUPS
@@ -856,8 +886,8 @@ def _hist_shared_grid(func, rows, win, is_delta):
     return _hist_range_shared(func, *rows, *win, is_delta)
 
 
-def _hist_base2_grid(func, rows, win, is_delta):
-    return _hist_base2_shared(func, *rows, *win, is_delta)
+def _hist_base2_grid(func, rows, win, is_delta, edges="gather"):
+    return _hist_base2_shared(func, *rows, *win, is_delta, edges)
 
 
 def _hist_jitter_grid(func, rows, win, is_delta):
@@ -1176,7 +1206,7 @@ def _batched_stacks(block, lanes, j_pad: int, body_name: str, mesh):
 def _fused_dispatch(func: str, epilogue: tuple, block, num_groups: int,
                     is_counter: bool, is_delta: bool, name: str, mesh=None,
                     *, gids=None, qv=None, params=None, lanes=None,
-                    j_pad=None, les=None):
+                    j_pad=None, les=None, edges=None):
     """The ONE host-side dispatch of every fused entry point: body
     selection (_fused_body), the degrade-and-count rules, the reduction's
     form, the window operands, then one launch of _fused_program_jit with
@@ -1193,7 +1223,9 @@ def _fused_dispatch(func: str, epilogue: tuple, block, num_groups: int,
     is an optimization, never a correctness risk).
 
     With ``mesh`` (a 1-D device mesh matching the block's series-sharded
-    placement) the same program dispatches ONCE across every device."""
+    placement) the same program dispatches ONCE across every device.
+    ``edges`` (hist_edge_form) is a base-2 launch's last static of the
+    shared-grid body's ``base2_grid``."""
     hist = epilogue[0] in ("hist", "hist2")
     body_name, reason = _fused_body(hist, block, func, is_delta, mesh)
     body = FUSED_BODIES[body_name]
@@ -1244,11 +1276,12 @@ def _fused_dispatch(func: str, epilogue: tuple, block, num_groups: int,
 
         book_lane_tiles(block, params.start_ms - block.base_ms,
                         params.step_ms, params.window_ms, j_pad)
+    statics = body.statics(block, j_pad, is_counter, is_delta)
+    if edges is not None and body.base2_grid is not None:
+        statics += (edges,)
     t0 = time.perf_counter()
-    spec = FusedSpec(
-        body_name, func, epilogue, num_groups,
-        body.statics(block, j_pad, is_counter, is_delta), mesh, u_map,
-    )
+    spec = FusedSpec(body_name, func, epilogue, num_groups, statics, mesh,
+                     u_map)
     before = _fused_program_jit._cache_size()
     out = _fused_program_jit(
         spec, body.rows(block), windows, gids,
@@ -1385,20 +1418,25 @@ def fused_base2_hist_aggregate(func: str, block, gids_padded,
     [G, J_pad, W] partials on the plan's group schemes. Books
     ``filodb_hist_rescale_series_total``: series merged onto a coarser
     scale, and series already at their group's; one
-    ``filodb_hist_merge_total{form}`` (hist_merge_form) and one
-    ``filodb_hist_window_total{form}`` (hist_kernels.hist_window_form)."""
+    ``filodb_hist_merge_total{form}`` (hist_merge_form), one
+    ``filodb_hist_window_total{form}`` (hist_kernels.hist_window_form) and,
+    for a cumulative column's rate family, one
+    ``filodb_hist_edges_total{form}`` (hist_edge_form)."""
     group_dev, width, _schemes, rescaled = plan
     REGISTRY.counter("filodb_hist_rescale_series", how="rescaled").inc(rescaled)
     REGISTRY.counter("filodb_hist_rescale_series", how="native").inc(
         block.n_series - rescaled)
     REGISTRY.counter("filodb_hist_merge", form=hist_merge_form(num_groups)).inc()
     REGISTRY.counter("filodb_hist_window", form=hist_window_form(func, is_delta)).inc()
+    edges = hist_edge_form(block, func, is_delta)
+    if edges is not None:
+        REGISTRY.counter("filodb_hist_edges", form=edges).inc()
     kind = "quantile" if q is not None else "sum"
     return _fused_dispatch(
         func, ("hist2", kind, width), block, num_groups, False, is_delta,
         f"fused_hist_{'quantile_' if q is not None else ''}sum_{func}", None,
         gids=gids_padded, qv=quantile_parts(q if q is not None else 0.0),
-        params=params, les=tuple(scheme_dev) + tuple(group_dev),
+        params=params, les=tuple(scheme_dev) + tuple(group_dev), edges=edges,
     )
 
 
@@ -1676,6 +1714,7 @@ def _register_kernel_observatory() -> None:
         _segment_aggregate_jit=_segment_aggregate_jit,
         _fused_program_jit=_fused_program_jit,
         _base2_group_scheme=_base2_group_scheme,
+        _whole_below_bound=_whole_below_bound,
         topk_mask=topk_mask,
         segment_quantile=segment_quantile,
     )

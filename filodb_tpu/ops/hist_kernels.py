@@ -262,15 +262,12 @@ def _hist_range_shared(func, vals, lo, hi, t_first, t_last, out_t, window,
     if func in ("last", "last_over_time"):
         return jnp.where(has, gidx(hi - 1), jnp.nan)
     if hist_window_form(func, is_delta) == "sums":
-        # each window's sum as ONE product with its [J, T] 0/1 membership,
-        # at HIGHEST: every bit of an f32 count kept (whole counts below
-        # 2^24 sum exactly). A running sum over T costs the chip ~15x the
-        # bytes, and its TPU lowering names its ops out of this scope.
+        # each window's sum as ONE product with its [J, T] 0/1 membership
+        # (a running sum over T costs the chip ~15x the bytes, and its TPU
+        # lowering names its ops out of this scope)
         t = jnp.arange(T, dtype=lo.dtype)[None, :]
         member = ((t >= lo[:, None]) & (t < hi[:, None])).astype(f32)
-        s = jnp.einsum("jt,stb->sjb", member, vals,
-                       precision=jax.lax.Precision.HIGHEST,
-                       preferred_element_type=f32)
+        s = _window_product(member, vals)
         if func == "rate":
             s = s / (window.astype(f32) * 1e-3)
         return jnp.where(has, s, jnp.nan)
@@ -286,6 +283,16 @@ def _hist_range_shared(func, vals, lo, hi, t_first, t_last, out_t, window,
     raise ValueError(f"unknown histogram range function {func}")
 
 
+def _window_product(weights, vals):
+    """[S, J, B] = ``weights`` [J, T] x ``vals`` [S, T, B] on the MXU at
+    HIGHEST, accumulated in f32: every bit of an f32 count is kept, so on
+    whole counts whose partial sums stay below 2^24 a 0/1 or +-1 product is
+    exact. It reads the parameter in its own layout: no gather along T."""
+    return jnp.einsum("jt,stb->sjb", weights, vals,
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=vals.dtype)
+
+
 def _shared_rate_factor(t_first, t_last, cnt, out_t, window, f32):
     """[J] ``_rate_factor`` on a shared grid: one factor a step for every
     series (``_hist_range_shared`` and the base-2 body)."""
@@ -299,7 +306,7 @@ def _shared_rate_factor(t_first, t_last, cnt, out_t, window, f32):
 
 @jax.named_scope("range_fn")
 def _hist_base2_shared(func, vals, lo, hi, t_first, t_last, out_t, window,
-                       is_delta: bool):
+                       is_delta: bool, edges: str = "gather"):
     """The shared-grid hist body for base-2 exponential histograms: the rate
     family as ``(whole counts [S, J, B], factor [J])``, so the epilogue sums
     whole counts exactly and applies the factor once, after the sum —
@@ -309,7 +316,16 @@ def _hist_base2_shared(func, vals, lo, hi, t_first, t_last, out_t, window,
     factor; a delta column's (and sum_over_time's) are each window's sum
     (below 2^24 a bucket: exact in f32, one product) and its factor 1 /
     window for ``rate``, else 1. Any other function: ``(_hist_range_shared's
-    grid, None)``."""
+    grid, None)``.
+
+    ``edges`` (static; ops/aggregations.hist_edge_form) is how a cumulative
+    window's increase is read: ``"gather"``, the samples at its edges taken
+    along T; ``"product"``, ONE product of the block with the [J, T] edge
+    matrix (+1 at the last sample, -1 at the first), the same bits where
+    every value of the block is a whole number below 2^23 (a partial sum of
+    two values' bf16 pieces then stays a whole number below 2^24) — and
+    only there: a NaN or an Inf anywhere in a row would reach every window
+    of its series."""
     f32 = vals.dtype
     if hist_window_form(func, is_delta) == "sums":
         sums = _hist_range_shared("sum_over_time", vals, lo, hi, t_first,
@@ -323,8 +339,15 @@ def _hist_base2_shared(func, vals, lo, hi, t_first, t_last, out_t, window,
                                   window, is_delta), None
     T = vals.shape[1]
     cnt = (hi - lo).astype(f32)
-    dlt = (jnp.take(vals, jnp.clip(hi - 1, 0, T - 1), axis=1)
-           - jnp.take(vals, jnp.clip(lo, 0, T - 1), axis=1))
+    first, last = jnp.clip(lo, 0, T - 1), jnp.clip(hi - 1, 0, T - 1)
+    if edges == "product":
+        # under two samples a row may cancel to 0: that window is NaN below
+        t = jnp.arange(T, dtype=lo.dtype)[None, :]
+        edge = ((t == last[:, None]).astype(f32)
+                - (t == first[:, None]).astype(f32))
+        dlt = _window_product(edge, vals)
+    else:
+        dlt = jnp.take(vals, last, axis=1) - jnp.take(vals, first, axis=1)
     factor = _shared_rate_factor(t_first, t_last, cnt, out_t, window, f32)
     if func == "rate":
         factor = factor / (window.astype(f32) * 1e-3)
