@@ -130,6 +130,7 @@ HELP_TEXTS: dict[str, str] = {
     "filodb_ingest_scheme_merged": "Base-2 histogram rows whose own scheme was finer than their partition's, merged down onto it exactly at ingest (a delta exporter's point at the finest scale its interval fits).",
     "filodb_hist_window": "Fused launches over base-2 exponential histograms by how the range body read each window: edges = the two samples at its edges (a cumulative column's rate family, last), sums = every sample in it (a delta column's rate / increase, sum_over_time).",
     "filodb_hist_edges": "Fused launches over base-2 exponential histograms of a cumulative column's rate family by how the range body read each window's increase: product = one +-1 product of the [J, T] edge matrix with the block on the MXU (every value of the block a whole number below 2^23), gather = the samples at the window's edges taken along T.",
+    "filodb_hist_epilogue": "Fused launches over base-2 exponential histograms by how the merge onto each group's scheme and the group sum ran: pallas = one Pallas kernel with its intermediates in VMEM (an accelerator, or FILODB_PALLAS=1; the onehot sum; a VMEM plan that fits), xla = the two 0/1 products of bf16 pieces in HBM.",
     "filodb_stage_cache_insert_dropped": "Staged blocks not cached because ingest effects touched their range.",
     "filodb_superblock_maintenance": "Version-stale superblock maintenance outcomes (revalidate|extend|extend_abort|restage).",
     "filodb_downsample_claims": "Distributed-downsample claim lifecycle events.",

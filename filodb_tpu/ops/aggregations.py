@@ -30,6 +30,8 @@ from .hist_kernels import (
     _hist_base2_shared,
     _hist_range_jitter,
     _hist_range_shared,
+    base2_select_rule,
+    bf16_pieces,
     hist_range_kernel,
     hist_window_form,
     histogram_quantile,
@@ -657,44 +659,51 @@ def hist_merge_form(num_groups: int) -> str:
     return "onehot" if few else "segment"
 
 
-def _bf16_pieces(x):
-    """``(hi, mid, lo)`` bf16 whose f32 sum is ``x`` exactly (finite, not
-    near f32's smallest normal): each the next 8 significant bits, cut by
-    clearing mantissa bits (a round trip through bf16 is a convert pair a
-    compiler may fold away), so a product with 0/1 entries accumulated in
-    f32 loses nothing."""
-    pieces = []
-    for _ in range(2):
-        bits = jax.lax.bitcast_convert_type(x, jnp.int32)
-        top = jax.lax.bitcast_convert_type(bits & jnp.int32(~0xFFFF),
-                                           jnp.float32)
-        pieces.append(top)
-        x = x - top
-    return tuple(p.astype(jnp.bfloat16) for p in pieces + [x])
+def hist_epilogue_form(block, num_groups: int, j_pad: int, width: int) -> str:
+    """How a base-2 launch merges onto each group's scheme and sums by
+    group: ``"pallas"``, ONE kernel whose intermediates stay in VMEM
+    (pallas_kernels.base2_merge_sum), where the one Pallas policy selects
+    kernels on this platform (pallas_kernels.pallas_platform), the sum is
+    ``onehot`` (hist_merge_form) and the kernel's VMEM plan fits the static
+    (J, B, W, G) (pallas_kernels.base2_epilogue_tile); ``"xla"``, the two
+    products of _base2_rescale and _base2_group_sum, elsewhere. A static of
+    the launch; every base-2 launch counts its form:
+    filodb_hist_epilogue_total."""
+    from .pallas_kernels import base2_epilogue_tile, pallas_platform
+
+    S, _T, B = block.vals.shape
+    if (hist_merge_form(num_groups) == "onehot" and pallas_platform()
+            and base2_epilogue_tile(S, j_pad, B, width, num_groups) is not None):
+        return "pallas"
+    return "xla"
+
+
+def _base2_select_scalars(gids, shared):
+    """[4, S] int32: each series' d (its scale above its group's), base
+    (offset_g * 2^d - offset), K_g and n, the operands of
+    hist_kernels.base2_select_rule, the one rule of both forms of the merge
+    (_base2_rescale, pallas_kernels.base2_merge_sum)."""
+    scale, offset, n, s_g, o_g, k_g = shared[:6]
+    d = scale - s_g[gids]
+    return jnp.stack([d, jnp.left_shift(o_g[gids], d) - offset, k_g[gids], n])
 
 
 @jax.named_scope("hist_rescale")
 def _base2_rescale(sjb, gids, shared, width: int):
     """[S, J, B] values at each series' own scheme -> [S, J, W] on its
-    group's: column 0 the zero bucket, column k the bound
-    b_g^(offset_g + k) = the series' fine bound (offset_g + k) * 2^d,
-    clipped to its own range (below: its zero count; above: its total),
-    and its total from the group's +Inf column on. One product on the MXU:
+    group's (hist_kernels.base2_select_rule picks each column's bucket).
+    One product on the MXU:
     each value's three bf16 pieces side by side on the contracted axis, the
     0/1 selection (one 1 a column) repeated under each, so a column sums
     the pieces of ONE value — exact in f32 in any order. ``sjb`` holds no
     NaN (a 0 times a NaN is a NaN)."""
-    scale, offset, n, s_g, o_g, k_g = shared[:6]
     B = sjb.shape[2]
-    d = scale - s_g[gids]
-    k = jnp.arange(width, dtype=jnp.int32)[None, :]
-    fine = (o_g[gids][:, None] + k) * jnp.left_shift(jnp.int32(1), d)[:, None]
-    idx = jnp.clip(fine - offset[:, None], 0, n[:, None])
-    idx = jnp.where(k == 0, 0,
-                    jnp.where(k > k_g[gids][:, None], n[:, None] + 1, idx))
+    d, base, k_g, n = _base2_select_scalars(gids, shared)[:, :, None]
+    idx = base2_select_rule(jnp.arange(width, dtype=jnp.int32)[None, :],
+                            d, base, k_g, n)  # [S, W]
     pick = ((jnp.arange(3 * B, dtype=jnp.int32) % B)[None, :, None]
             == idx[:, None, :]).astype(jnp.bfloat16)  # [S, 3B, W]
-    pieces = jnp.concatenate(_bf16_pieces(sjb), axis=2)  # [S, J, 3B]
+    pieces = jnp.concatenate(bf16_pieces(sjb), axis=2)  # [S, J, 3B]
     return jax.lax.dot_general(pieces, pick, (((2,), (1,)), ((0,), (0,))),
                                preferred_element_type=jnp.float32)
 
@@ -717,7 +726,7 @@ def _base2_group_sum(sjw, ok, gids, num_groups: int):
     member3 = (jnp.concatenate([gids] * 3)[None, :] == groups
                ).astype(jnp.bfloat16)  # [G, 3S]
     total = jax.lax.dot_general(
-        member3, jnp.concatenate(_bf16_pieces(sjw), axis=0),
+        member3, jnp.concatenate(bf16_pieces(sjw), axis=0),
         (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     count = jax.lax.dot((gids[None, :] == groups).astype(jnp.bfloat16),
                         ok.astype(jnp.bfloat16),
@@ -726,8 +735,12 @@ def _base2_group_sum(sjw, ok, gids, num_groups: int):
 
 
 def _base2_epilogue(grid, epilogue: tuple, gids, shared, qv, num_groups: int):
-    """The ("hist2", kind, W) epilogue: rescale, per-column group sum, then
-    the quantile on each group's own bounds — or the [G, J, W] partials.
+    """The ("hist2", kind, W[, form]) epilogue: rescale, per-column group
+    sum, then the quantile on each group's own bounds — or the [G, J, W]
+    partials. The merge and the sum run as ``form`` (hist_epilogue_form)
+    says: ``"pallas"``, one kernel that keeps every intermediate in VMEM
+    (pallas_kernels.base2_merge_sum); ``"xla"`` (the default), the two
+    products below.
     ``grid`` is ``hist_shared``'s ``base2_grid`` (whole counts — a
     cumulative column's window increases or a delta column's window sums —
     and a [J] factor): the sum is exact and the factor, common to every
@@ -740,12 +753,22 @@ def _base2_epilogue(grid, epilogue: tuple, gids, shared, qv, num_groups: int):
     which every scheme has, says whether it is there. Before the products
     the padded rows and every value that is not finite become 0, and
     absence is rebuilt from that count after them."""
-    _, kind, width = epilogue
+    _, kind, width = epilogue[:3]
     sjb, factor = grid if isinstance(grid, tuple) else (grid, None)
-    ok = ~jnp.isnan(sjb[:, :, 0]) & (gids < num_groups)[:, None]
-    sjb = jnp.where(ok[:, :, None] & jnp.isfinite(sjb), sjb, 0.0)
-    gjw = _base2_group_sum(_base2_rescale(sjb, gids, shared, width), ok,
-                           gids, num_groups)
+    if epilogue[3:] == ("pallas",):
+        from .pallas_kernels import (
+            base2_epilogue_tile, base2_merge_sum, interpret_mode,
+        )
+
+        S, J, B = sjb.shape
+        gjw = base2_merge_sum(
+            sjb, gids, _base2_select_scalars(gids, shared), num_groups, width,
+            base2_epilogue_tile(S, J, B, width, num_groups), interpret_mode())
+    else:
+        ok = ~jnp.isnan(sjb[:, :, 0]) & (gids < num_groups)[:, None]
+        sjb = jnp.where(ok[:, :, None] & jnp.isfinite(sjb), sjb, 0.0)
+        gjw = _base2_group_sum(_base2_rescale(sjb, gids, shared, width), ok,
+                               gids, num_groups)
     if kind != "quantile":
         return gjw if factor is None else gjw * factor[None, :, None]
     with jax.named_scope("epilogue"):
@@ -1419,7 +1442,8 @@ def fused_base2_hist_aggregate(func: str, block, gids_padded,
     ``filodb_hist_rescale_series_total``: series merged onto a coarser
     scale, and series already at their group's; one
     ``filodb_hist_merge_total{form}`` (hist_merge_form), one
-    ``filodb_hist_window_total{form}`` (hist_kernels.hist_window_form) and,
+    ``filodb_hist_window_total{form}`` (hist_kernels.hist_window_form),
+    one ``filodb_hist_epilogue_total{form}`` (hist_epilogue_form) and,
     for a cumulative column's rate family, one
     ``filodb_hist_edges_total{form}`` (hist_edge_form)."""
     group_dev, width, _schemes, rescaled = plan
@@ -1427,13 +1451,15 @@ def fused_base2_hist_aggregate(func: str, block, gids_padded,
     REGISTRY.counter("filodb_hist_rescale_series", how="native").inc(
         block.n_series - rescaled)
     REGISTRY.counter("filodb_hist_merge", form=hist_merge_form(num_groups)).inc()
+    form = hist_epilogue_form(block, num_groups, pad_steps(params.num_steps), width)
+    REGISTRY.counter("filodb_hist_epilogue", form=form).inc()
     REGISTRY.counter("filodb_hist_window", form=hist_window_form(func, is_delta)).inc()
     edges = hist_edge_form(block, func, is_delta)
     if edges is not None:
         REGISTRY.counter("filodb_hist_edges", form=edges).inc()
     kind = "quantile" if q is not None else "sum"
     return _fused_dispatch(
-        func, ("hist2", kind, width), block, num_groups, False, is_delta,
+        func, ("hist2", kind, width, form), block, num_groups, False, is_delta,
         f"fused_hist_{'quantile_' if q is not None else ''}sum_{func}", None,
         gids=gids_padded, qv=quantile_parts(q if q is not None else 0.0),
         params=params, les=tuple(scheme_dev) + tuple(group_dev), edges=edges,

@@ -293,6 +293,36 @@ def _window_product(weights, vals):
                       preferred_element_type=vals.dtype)
 
 
+def base2_select_rule(k, d, base, k_g, n):
+    """The bucket of its own scheme a base-2 series reads at column ``k`` of
+    its group's scheme: column 0 the zero bucket; column k the bound
+    b_g^(offset_g + k) = the series' fine bound (offset_g + k) * 2^d,
+    clipped to its own range (below: its zero count; above: its total);
+    past the group's K_g its total (the +Inf column on). ``d`` = scale -
+    scale_g, ``base`` = offset_g * 2^d - offset; int32 operands that
+    broadcast together (aggregations._base2_rescale over [S, W],
+    pallas_kernels.base2_merge_sum a series at a time)."""
+    idx = jnp.clip(jnp.left_shift(k, d) + base, 0, n)
+    return jnp.where(k == 0, 0, jnp.where(k > k_g, n + 1, idx))
+
+
+def bf16_pieces(x):
+    """``(hi, mid, lo)`` bf16 whose f32 sum is ``x`` exactly (finite, not
+    near f32's smallest normal): each the next 8 significant bits, cut by
+    clearing mantissa bits (a round trip through bf16 is a convert pair a
+    compiler may fold away), so a product with 0/1 entries accumulated in
+    f32 loses nothing. The base-2 epilogue's both forms cut with it
+    (aggregations._base2_rescale, pallas_kernels.base2_merge_sum)."""
+    pieces = []
+    for _ in range(2):
+        bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+        top = jax.lax.bitcast_convert_type(bits & jnp.int32(~0xFFFF),
+                                           jnp.float32)
+        pieces.append(top)
+        x = x - top
+    return tuple(p.astype(jnp.bfloat16) for p in pieces + [x])
+
+
 def _shared_rate_factor(t_first, t_last, cnt, out_t, window, f32):
     """[J] ``_rate_factor`` on a shared grid: one factor a step for every
     series (``_hist_range_shared`` and the base-2 body)."""

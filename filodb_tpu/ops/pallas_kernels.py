@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from .hist_kernels import base2_select_rule, bf16_pieces
 from .staging import StagedBlock
 
 BS = 64   # series per tile (second-to-last block dim: multiple of 8)
@@ -349,20 +350,160 @@ def book_lane_tiles(block: StagedBlock, start_off, step_ms, window_ms,
 MAX_T = 4096
 
 
-def pallas_enabled(t_pad: int) -> bool:
-    """The ONE Pallas selection policy for a block of padded width
-    ``t_pad``, shared by the legacy range-function dispatch
-    (kernels._dispatch_range_function) and the fused variant ladder
-    (aggregations._pallas_variant): never past MAX_T; FILODB_PALLAS "0"
-    disables outright; "auto" (default) selects the one-pass VMEM kernel on
-    real accelerators only; "1" forces it everywhere — interpret mode on
-    CPU, which is for tests."""
+def pallas_platform() -> bool:
+    """The ONE Pallas policy's platform half: FILODB_PALLAS "0" disables
+    every kernel outright; "auto" (default) selects them on real
+    accelerators only; "1" forces them everywhere — interpret mode on CPU,
+    which is for tests. pallas_enabled (the window kernel) and
+    aggregations.hist_epilogue_form (base2_merge_sum) add what their
+    kernel's shapes need."""
     import os
 
     mode = os.environ.get("FILODB_PALLAS", "auto")
-    if mode == "0" or t_pad > MAX_T:
+    if mode == "0":
         return False
     return jax.devices()[0].platform not in ("cpu",) or mode == "1"
+
+
+def pallas_enabled(t_pad: int) -> bool:
+    """The window kernel's selection for a block of padded width ``t_pad``,
+    shared by the legacy range-function dispatch
+    (kernels._dispatch_range_function) and the fused variant ladder
+    (aggregations._pallas_variant): never past MAX_T, then pallas_platform."""
+    return t_pad <= MAX_T and pallas_platform()
+
+
+# -- the base-2 epilogue's merge and group sum, resident in VMEM -------------
+#
+# aggregations._base2_epilogue's XLA form cuts the [S, J, B] whole-count grid
+# into three bf16 pieces, concatenates them in HBM ([S, J, 3B]), takes the
+# batched selection product to [S, J, W], cuts THAT into pieces stacked on
+# the series axis ([3S, J, W]) and takes the membership product: ~3 GB of
+# temporaries a program against a 0.34 GB input (PERF.md 5). base2_merge_sum
+# reads each (step tile x series tile) slab of the grid once and keeps the
+# rest in VMEM. It reads the grid as [B, S, J], the layout the shared-grid
+# body's range product writes on a TPU (a bitcast, no copy), takes each
+# series' [B, TJ] out of the slab with one sublane-strided load, makes its
+# [W, B] 0/1 selection by an iota compare (hist_kernels.base2_select_rule,
+# from five scalars a series in SMEM), multiplies its three bf16 pieces on
+# the MXU, and adds the [W, TJ] result in f32 into its group's rows of a
+# [G, W, TJ] sum resident across the series axis. The sample count rides the
+# row past W.
+
+B2_SERIES = 16  # series a grid step: two sublane tiles of the [B, S, J] grid
+B2_VMEM_LIMIT = 64 << 20  # the scoped VMEM the kernel asks of Mosaic (v5e: 128 MiB)
+B2_VMEM_PLAN = 40 << 20  # what its buffers and one series' temporaries may take
+
+
+def _round(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def base2_epilogue_tile(S: int, J: int, B: int, W: int, num_groups: int):
+    """Steps a grid tile of ``base2_merge_sum`` over an [S, J, B] grid to W
+    columns and ``num_groups`` groups: LANES (Mosaic's strided load reads
+    rows of exactly 128 lanes); None where J or S is not a whole number of
+    tiles or the VMEM plan — the slab and the resident sums
+    double-buffered, one series' pieces, selection and products — passes
+    B2_VMEM_PLAN (the XLA form runs). Reckoned from the static shapes
+    alone."""
+    wp = _round(W + 1, 8)
+    need = (2 * B * B2_SERIES * LANES * 4 + 2 * num_groups * wp * LANES * 4
+            + _round(B, 8) * LANES * (2 * 4 + 3 * 2)
+            + wp * _round(B, LANES) * (4 + 2) + 4 * wp * LANES * 4)
+    if J % LANES or S % B2_SERIES or need > B2_VMEM_PLAN:
+        return None
+    return LANES
+
+
+def _base2_merge_kernel(num_groups: int, width: int, tab_ref, x_ref, out_ref):
+    """One (TJ steps x B2_SERIES series) tile. ``x_ref`` [B, B2_SERIES, TJ];
+    ``tab_ref`` [5, B2_SERIES] a series' group, d, base, K_g and n;
+    ``out_ref`` [G, Wp, TJ], the same block along the series axis (the last
+    of the grid): zeroed at the first tile, each real series' merged
+    [W, TJ] added at its group's rows and its count at row ``width``, NaN
+    where a group's count is 0 after the last. A series of n + 2 <= 128
+    buckets reads and multiplies its first 128 alone: its selection has no
+    1 past them, so the rest adds nothing."""
+    i = pl.program_id(1)
+    B, rows, tj = x_ref.shape
+    flat = x_ref.reshape(B * rows, tj)
+
+    @pl.when(i == 0)
+    def _():
+        out_ref[...] = jnp.zeros(out_ref.shape, jnp.float32)
+
+    w = jax.lax.broadcasted_iota(jnp.int32, (width, 1), 0)
+
+    def series(s, carry):
+        g = tab_ref[0, s]
+        n = tab_ref[4, s]
+
+        def add(buckets: int):
+            x = flat[pl.ds(s, buckets, stride=rows), :]  # [buckets, TJ]: this series
+            ok = ~jnp.isnan(x[0:1, :])  # a sample is a whole row of buckets
+            x = jnp.where(ok & jnp.isfinite(x), x, 0.0)
+            idx = base2_select_rule(w, tab_ref[1, s], tab_ref[2, s], tab_ref[3, s], n)
+            pick = (jax.lax.broadcasted_iota(jnp.int32, (width, buckets), 1) == idx
+                    ).astype(jnp.bfloat16)
+            hi, mid, lo = (jnp.dot(pick, p, preferred_element_type=jnp.float32)
+                           for p in bf16_pieces(x))
+            out_ref[g, :width] += hi + mid + lo
+            out_ref[g, width:width + 1] += ok.astype(jnp.float32)
+
+        real = g < num_groups  # padded and trash-group rows add nothing
+        if B <= LANES:
+            pl.when(real)(lambda: add(B))
+        else:
+            pl.when(real & (n + 2 <= LANES))(lambda: add(LANES))
+            pl.when(real & (n + 2 > LANES))(lambda: add(B))
+        return carry
+
+    jax.lax.fori_loop(0, rows, series, 0)
+
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _():
+        acc = out_ref[...]
+        out_ref[...] = jnp.where(acc[:, width:width + 1, :] > 0, acc, jnp.nan)
+
+
+@functools.partial(jax.jit, static_argnames=("num_groups", "width", "tile",
+                                             "interpret"))
+@jax.named_scope("group_reduce")
+def base2_merge_sum(sjb, gids, select, num_groups: int, width: int, tile: int,
+                    interpret: bool):
+    """[G, J, W] sums by group of the [S, J, B] grid ``sjb`` merged onto
+    each group's scheme, NaN where no member has a sample: column w of
+    series s reads its bucket base2_select_rule(w, *select[:, s]) (the
+    [4, S] int32 d, base, K_g, n of aggregations._base2_select_scalars);
+    rows of ``gids`` >= num_groups are padding. The grid's non-finite
+    values and a row whose zero bucket is NaN read 0, as in the XLA form,
+    and every value is cut into bf16_pieces under a 0/1 selection: each
+    column of a product is ONE value exactly, and on whole counts whose
+    partial sums stay below 2^24 the f32 sum over series is exact in any
+    order — bit for bit the XLA form. ``tile`` is base2_epilogue_tile's."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, J, B = sjb.shape
+    wp = _round(width + 1, 8)
+    tab = jnp.concatenate([gids.astype(jnp.int32)[None], select])  # [5, S]
+    tab = tab.reshape(5, S // B2_SERIES, B2_SERIES).transpose(1, 0, 2)
+    out = pl.pallas_call(
+        functools.partial(_base2_merge_kernel, num_groups, width),
+        grid=(J // tile, S // B2_SERIES),
+        in_specs=[
+            pl.BlockSpec((None, 5, B2_SERIES), lambda j, i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((B, B2_SERIES, tile), lambda j, i: (0, i, j)),
+        ],
+        out_specs=pl.BlockSpec((num_groups, wp, tile), lambda j, i: (0, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((num_groups, wp, J), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=B2_VMEM_LIMIT),
+        interpret=interpret,
+    )(tab, jnp.transpose(sjb, (2, 0, 1)))
+    return jnp.transpose(out[:, :width], (0, 2, 1))
 
 
 @functools.partial(jax.jit, static_argnames=("func", "is_counter", "is_delta"))
@@ -458,6 +599,7 @@ def _register_kernel_observatory() -> None:
         window_aggregates=window_aggregates,
         finish=finish,
         _narrow_grid_tiles=_narrow_grid_tiles,
+        base2_merge_sum=base2_merge_sum,
     )
 
 

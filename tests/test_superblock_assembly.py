@@ -43,6 +43,7 @@ from filodb_tpu.core.records import SeriesBatch
 from filodb_tpu.core.schemas import Dataset, METRIC_TAG, PROM_HISTOGRAM
 from filodb_tpu.memstore.memstore import TimeSeriesMemStore
 from filodb_tpu.metrics import REGISTRY
+from filodb_tpu.ops import aggregations as AGG
 from filodb_tpu.ops import staging as ST
 from filodb_tpu.parallel.mesh import make_mesh
 from filodb_tpu.testkit import counter_batch, histogram_batch
@@ -558,3 +559,67 @@ def test_the_count_of_narrow_grid_tiles_compiles_for_the_chip(one_chip):
             jax.ShapeDtypeStruct((s,), jnp.int32, sharding=one_chip),
             scalar, scalar, scalar, num_steps=PK.BJ).compile()
         assert compiled.memory_analysis().temp_size_in_bytes < s * t * 4 // 8
+
+
+# the base-2 cells' epilogue shapes: (W, G) of each grouping of expo.repeat
+# and expo_delta.repeat, and the most groups the kernel takes
+BASE2_EPILOGUES = ((176, 40), (120, 1), (168, 40), (112, 1), (176, 127))
+
+
+def test_the_base2_epilogue_kernel_compiles_for_the_chip(one_chip):
+    """Mosaic takes base2_merge_sum at the cells' shape (S4096 x J128 x
+    B162) for every width and group count they launch, and the kernel reads
+    the grid in place: no temporary."""
+    import jax.numpy as jnp
+
+    from filodb_tpu.ops import pallas_kernels as PK
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    for width, groups in BASE2_EPILOGUES:
+        tile = PK.base2_epilogue_tile(4096, 128, 162, width, groups)
+        assert tile == PK.LANES
+        compiled = PK.base2_merge_sum.lower(
+            arr((4096, 128, 162), jnp.float32), arr((4096,), jnp.int32),
+            arr((4, 4096), jnp.int32), num_groups=groups, width=width,
+            tile=tile, interpret=False).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("width,groups,delta", [(176, 40, False), (168, 40, True)],
+                         ids=["expo", "expo_delta"])
+def test_the_base2_program_writes_no_piece_to_hbm(one_chip, monkeypatch, width,
+                                                  groups, delta):
+    """The whole base-2 program of each cell with the kernel: the range
+    product's [S, J, B] grid goes to the kernel with no copy, and no bf16
+    piece of it, no [S, J, 3B] or [3S, J, W] concatenation, is an array of
+    the program; its temporaries are about the grid alone (340 MB)."""
+    import re
+
+    import jax.numpy as jnp
+
+    from filodb_tpu.ops import pallas_kernels as PK
+
+    monkeypatch.setattr(PK, "interpret_mode", lambda: False)  # compiled for the chip
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S, T, B, J, G = 4096, 768, 162, 128, groups
+    i32 = jnp.int32
+    spec = AGG.FusedSpec("hist_shared", "rate", ("hist2", "quantile", width, "pallas"),
+                         G, (delta,) if delta else (False, "product"))
+    compiled = AGG._fused_program_jit.lower(
+        spec, (arr((S, T, B), jnp.float32),),
+        tuple(arr((J,), i32) for _ in range(5)) + (jax.ShapeDtypeStruct((), i32),),
+        arr((S,), i32),
+        tuple(arr((S,), i32) for _ in range(3)) + tuple(arr((G + 1,), i32) for _ in range(3))
+        + (arr((G, width), jnp.float32),),
+        arr((2,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"bf16\[(4096|12288),", text)
+    assert not re.search(r"= f32\[(4096,128,162|162,4096,128)\]\{[^}]*\} copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9
