@@ -1,8 +1,8 @@
-# filodb-tpu build/test/bench shortcuts
+# filodb-tpu build/test shortcuts
 
 NATIVE_DIR := filodb_tpu/native
 
-.PHONY: all native test test-alerting test-chaos test-index test-ingest-chaos test-jitter test-multichip test-observability test-replica test-rollup test-scheduler test-standing attest bench bench-smoke microbench serve clean
+.PHONY: all native test test-alerting test-chaos test-index test-ingest-chaos test-jitter test-multichip test-observability test-replica test-rollup test-scheduler test-standing serve clean
 
 all: native
 
@@ -13,9 +13,7 @@ all: native
 native:
 	python -c "from filodb_tpu import native; [print(k, '->', v) for k, v in native.tiers().items()]"
 
-# default test run; pair with `make bench-smoke` before sending a perf-
-# sensitive change (the smoke gate catches losing the fused single-dispatch
-# path or a staging-cache regression that unit tests can't see)
+# default test run
 test: native
 	python -m pytest tests/ -q
 
@@ -72,8 +70,7 @@ test-standing: native
 # index"): randomized property equivalence of the posting-bitmap index vs
 # the retained set-based oracle (eq/in/literal-alt/prefix/general-regex/
 # negative/empty-matcher x interval overlap x limit), incremental
-# add/update_end_time/remove parity, concurrent lookup-vs-ingest soak,
-# and zero ledger drift for the opt-in device postings tier
+# add/update_end_time/remove parity and a concurrent lookup-vs-ingest soak
 test-index: native
 	python -m pytest tests/test_index_bitmap.py -q -m index
 
@@ -116,28 +113,6 @@ test-observability: native test-alerting
 	python tools/check_spans.py
 	python tools/check_metrics.py
 	python -m pytest tests/ -q -m "observability or chaos" --continue-on-collection-errors
-
-bench: native
-	python bench.py
-
-# perf regression gate (doc/perf.md): 2k series, 3 runs, CPU backend;
-# fails if p50 regresses >25% vs benchmarks/bench_smoke_floor.json —
-# plus the attestation machinery smoke (one tiny workload through the
-# bench -> kernel-snapshot -> verdict -> digest pipeline)
-bench-smoke: native
-	python tools/bench_smoke.py
-	python tools/attest.py --smoke
-
-# one-command hardware attestation (doc/operations.md "Attestation"):
-# bench-smoke floors + MULTICHIP dryrun + per-workload kernel-observatory
-# snapshots, bundled into one signed-off ATTEST_<backend>.json proving
-# what compiled, dispatched and fell back. Runs on the CPU backend today
-# and unchanged on hardware (workers label their backend honestly).
-attest: native
-	python tools/attest.py
-
-microbench: native
-	python -m benchmarks.run
 
 serve:
 	python -m filodb_tpu.cli serve --config conf/timeseries-dev.json

@@ -8,7 +8,7 @@ defaults (8 shards, spread 3, memory-only), bulk-loads a deployment-sized
 history through the ingest API the HTTP handlers call
 (``TimeSeriesMemStore.ingest_routed``), then drives it over HTTP and checks
 every answer against a plain numpy f64 oracle written here. Data comes from
-``--seed``; nothing is imported from bench.py or tests/.
+``--seed``; nothing is imported from tests/.
 
 It FAILS (non-zero exit, no timing, no result line) when jax finds no TPU:
 the platform is pinned to ``tpu`` before jax is imported, so a failed init
@@ -47,7 +47,7 @@ N_STEPS = 114  # query grid: the last ~1.9 h of the history, 60 s steps
 WIDE_SAMPLES = 5200
 WIDE_STEP_S = 600
 WIDE_STEPS = 76
-RTOL = 5e-3  # what bench.py's oracles use; max rel err is printed to tighten
+RTOL = 5e-3  # max rel err is printed to tighten
 # avg(avg_over_time) sums 1e9-sized values over every series: held to the
 # limit of the benchmark cell counters.repeat (its avg_avg_over_time panel;
 # tests/chip_benchmark/test_counters_cell.py keeps the two equal). RTOL let

@@ -905,8 +905,7 @@ class PromApiHandler(BaseHTTPRequestHandler):
     def _index_debug(self):
         """Part-key index introspection (doc/perf.md "Vectorized part-key
         index"): per-label cardinality + postings footprint per shard, the
-        rolled-up label dictionary, and the hot device-staged posting
-        bitmaps when the opt-in HBM tier is on."""
+        rolled-up label dictionary."""
         from ..memstore.cardinality import label_top_values
 
         p = self._params()
@@ -915,7 +914,7 @@ class PromApiHandler(BaseHTTPRequestHandler):
         shards = []
         labels_rollup: dict[str, dict] = {}
         drill: dict[str, int] = {}
-        total_bytes = device_bytes = 0
+        total_bytes = 0
         for sh in self.engine.memstore.shards(ds):
             st = sh.index_stats()
             if drill_label:
@@ -930,16 +929,12 @@ class PromApiHandler(BaseHTTPRequestHandler):
                 slot["values"] += rec["values"]
                 slot["postings_bytes"] += rec["postings_bytes"]
             total_bytes += st.get("postings_bytes", 0)
-            dev = st.get("device")
-            if dev:
-                device_bytes += dev.get("staged_bytes", 0)
             shards.append({
                 "shard": sh.shard_num,
                 "part_keys": st.get("num_part_keys", 0),
                 "postings_bytes": st.get("postings_bytes", 0),
                 "dictionary_size": st.get("dictionary_size", 0),
                 "lookups": st.get("lookups", 0),
-                "device": dev,
             })
         return self._send(200, J.success({
             "dataset": ds,
@@ -952,7 +947,6 @@ class PromApiHandler(BaseHTTPRequestHandler):
                 key=lambda kv: -kv[1]["postings_bytes"],
             )),
             "postings_bytes": total_bytes,
-            "device_staged_bytes": device_bytes,
             # ?label= drill-down: top values of that label by series count
             "label_values": (sorted(
                 ({"value": v, "series": n} for v, n in drill.items()),
@@ -1376,7 +1370,6 @@ def register_shard_stats_collector(engine: QueryEngine) -> None:
             return
         for sh in memstore.shards(ds):
             ist = sh.index_stats()
-            dev = ist.get("device") or {}
             for name, v in (
                 ("filodb_shard_partitions", sh.num_partitions),
                 ("filodb_shard_rows_ingested", sh.stats.rows_ingested),
@@ -1385,7 +1378,6 @@ def register_shard_stats_collector(engine: QueryEngine) -> None:
                 ("filodb_shard_chunks_flushed", sh.stats.chunks_flushed),
                 ("filodb_index_postings_bytes", ist.get("postings_bytes", 0)),
                 ("filodb_index_dictionary_size", ist.get("dictionary_size", 0)),
-                ("filodb_index_device_staged_bytes", dev.get("staged_bytes", 0)),
             ):
                 REGISTRY.gauge(name, dataset=ds, shard=str(sh.shard_num)).set(float(v))
 

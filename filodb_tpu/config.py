@@ -21,12 +21,6 @@ DEFAULTS: dict = {
     # "python" = vectorized posting-bitmap index (default), "native" = C++
     # posting lists, "set" = the retained set-arithmetic oracle
     "index_backend": "python",
-    # opt-in HBM tier for hot posting bitmaps (doc/perf.md "Vectorized
-    # part-key index": all-equality selectors over staged bitmaps resolve
-    # as one tiny jit intersection; ledger kind index_postings)
-    "index_device_postings": False,
-    "index_device_min_hits": 16,
-    "index_device_max_bytes": 64 << 20,
     # flush / persistence
     "flush_interval_s": 3600,
     "store_root": None,  # None = memory-only (NullColumnStore)
@@ -310,8 +304,8 @@ def force_virtual_devices(n: int) -> None:
     devices — must run BEFORE the first jax backend init. A smaller
     pre-existing count (e.g. inherited from a test harness) is replaced; a
     larger one is kept. The ONE definition of the flag-forcing defense
-    shared by the MULTICHIP dryrun (__graft_entry__) and bench.py's
-    fused_mesh workload."""
+    shared by the MULTICHIP dryrun (__graft_entry__) and the sketch
+    property tests."""
     import re
 
     flags = os.environ.get("XLA_FLAGS", "")

@@ -126,7 +126,7 @@ class DeviceLedger:
     the drift check (``verify``) behind ``/debug/resources``."""
 
     KINDS = ("staged_block", "superblock", "compile_cache",
-             "standing_state", "index_postings", "rollup")
+             "standing_state", "rollup")
 
     def __init__(self):
         self._lock = threading.Lock()

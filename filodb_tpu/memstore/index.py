@@ -30,12 +30,6 @@ property-test oracle and the ``index_backend="set"`` escape hatch):
   match cache keyed by pattern and invalidated by the label's dictionary /
   postings versions.
 
-An opt-in device tier (memstore/index_device.py) stages the hottest posting
-bitmaps to HBM — chosen from observed selector traffic, Storyboard-style —
-and resolves all-equality selectors with one tiny jit intersection program,
-ledger-accounted under the ``index_postings`` kind. Default OFF: the warm
-fused query path stays exactly ONE kernel dispatch.
-
 The C++ fast path (native/index.cpp) still plugs in behind the same class
 (memstore/index_native.py) when built.
 """
@@ -215,13 +209,6 @@ class PartKeyIndex:
         # (label, pattern) -> (dict_version, matched values tuple,
         #                      post_version, merged posting view | None)
         self._regex_cache: OrderedDict = OrderedDict()
-        # observed equality-selector traffic per (label, value): the device
-        # tier's hot-postings chooser input (Storyboard: let the workload
-        # pick what gets precomputed/staged). Bounded: coldest half pruned
-        # when it overflows.
-        self.traffic: dict[tuple[str, str], int] = {}
-        self.TRAFFIC_MAX = 4096
-        self.device_tier = None  # DevicePostingsTier when opted in
         self.lookups = 0
         # postings_stats amortization: per-label aggregates cached by
         # (dict_version, post_version), whole snapshot TTL'd — the metrics
@@ -412,20 +399,12 @@ class PartKeyIndex:
                     # cheapest, most selective classes first: an empty AND
                     # short-circuits before any regex pass runs
                     classed.sort(key=lambda fc: _CLASS_RANK[fc[1]])
-                tier = self.device_tier
-                if tier is not None:
-                    self._record_traffic(classed)
-                    dev = tier.try_intersect(classed)
-                    if dev is not None:
-                        res = ("d", dev)
-                if res is None:
-                    for f, _c in classed:
-                        p = self._posting_for_filter(f)
-                        res = p if res is None else P.p_and(res, p, nw)
-                        if P.p_is_empty(res):
-                            _observe_lookup(op_class,
-                                            time.perf_counter() - t0)
-                            return np.empty(0, dtype=np.int32)
+                for f, _c in classed:
+                    p = self._posting_for_filter(f)
+                    res = p if res is None else P.p_and(res, p, nw)
+                    if P.p_is_empty(res):
+                        _observe_lookup(op_class, time.perf_counter() - t0)
+                        return np.empty(0, dtype=np.int32)
             ids = P.p_to_ids(res) if res is not None else P.dense_to_ids(self._all)
             if len(ids) and (start_ts > 0 or end_ts < END_SENTINEL):
                 # vectorized [start, end] overlap; skipped for the
@@ -441,19 +420,6 @@ class PartKeyIndex:
             out = np.asarray(ids, dtype=np.int32)
         _observe_lookup(op_class, time.perf_counter() - t0)
         return out
-
-    def _record_traffic(self, classed) -> None:
-        tr = self.traffic
-        for f, c in classed:
-            # {k=""} equality also matches series MISSING the tag (the
-            # missing-tag rule below) — a staged posting bitmap alone can't
-            # answer it, so it must never become a device-tier candidate
-            if c == "eq" and f.value != "":
-                key = (f.column, f.value)
-                tr[key] = tr.get(key, 0) + 1
-        if len(tr) > self.TRAFFIC_MAX:
-            keep = sorted(tr.items(), key=lambda kv: -kv[1])[: self.TRAFFIC_MAX // 2]
-            self.traffic = dict(keep)
 
     def label_names(self, filters: Sequence[ColumnFilter], start_ts: int,
                     end_ts: int) -> list[str]:
@@ -566,7 +532,6 @@ class PartKeyIndex:
                 del cache[dead]
             total_bytes += (self._all.nbytes + self._start.nbytes
                             + self._end.nbytes)
-            tier = self.device_tier
             out = {
                 "num_part_keys": len(self._tags),
                 "capacity_bits": self._nbits,
@@ -574,7 +539,6 @@ class PartKeyIndex:
                 "postings_bytes": total_bytes,
                 "dictionary_size": total_values,
                 "lookups": self.lookups,
-                "device": tier.snapshot() if tier is not None else None,
             }
             self._stats_snapshot = (now, out)
             return out

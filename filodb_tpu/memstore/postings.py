@@ -21,9 +21,7 @@ touches a device.
 
 Bit order contract: words are little-endian ``uint64`` viewed as bytes for
 pack/unpack, so part id ``i`` lives at word ``i >> 6``, bit ``i & 63`` —
-the same layout ``ops/postings_kernels.intersect_words`` consumes after a
-``view(uint32)`` reinterpretation (bitwise AND is invariant under the word
-split).
+so bitwise AND / OR / ANDNOT over two bitmaps is one numpy op per word.
 """
 
 from __future__ import annotations

@@ -58,13 +58,6 @@ class StoreConfig:
     # when unbuilt) | "set" (the original set-arithmetic index, retained as
     # the property-test oracle / escape hatch)
     index_backend: str = "python"
-    # opt-in HBM tier for hot posting bitmaps (memstore/index_device.py):
-    # all-equality selectors whose matchers are staged resolve as one tiny
-    # jit intersection program. Default OFF — with it off the index never
-    # touches a device and the warm fused query stays ONE kernel dispatch.
-    index_device_postings: bool = False
-    index_device_min_hits: int = 16
-    index_device_max_bytes: int = 64 << 20
     # staging-cache byte budget per shard (HBM/working-set guard; reference
     # analog: BlockManager reclaim under memory pressure)
     stage_cache_bytes: int = 2 << 30
@@ -235,27 +228,6 @@ class TimeSeriesShard:
             return SetBasedPartKeyIndex()
         if idx is None:
             idx = PartKeyIndex()
-        if self.config.index_device_postings:
-            if type(idx) is not PartKeyIndex:
-                # the native backend answers all-equality selectors in C++
-                # and never reaches the bitmap tier hook — attaching a tier
-                # there would be a silent no-op holding a ledger account
-                import logging
-
-                logging.getLogger("filodb_tpu.memstore").warning(
-                    "index_device_postings ignored: backend %r resolves "
-                    "equality selectors outside the bitmap path (use "
-                    "index_backend=\"python\")", self.config.index_backend,
-                )
-            else:
-                from .index_device import DevicePostingsTier
-
-                idx.device_tier = DevicePostingsTier(
-                    idx,
-                    min_hits=self.config.index_device_min_hits,
-                    max_bytes=self.config.index_device_max_bytes,
-                    name=f"{self.dataset}/shard-{self.shard_num}/index",
-                )
         return idx
 
     def index_stats(self) -> dict:
@@ -264,7 +236,7 @@ class TimeSeriesShard:
         if hasattr(self.index, "postings_stats"):
             return self.index.postings_stats()
         return {"num_part_keys": len(self.index), "labels": {},
-                "postings_bytes": 0, "dictionary_size": 0, "device": None}
+                "postings_bytes": 0, "dictionary_size": 0}
 
     # -- ingest ------------------------------------------------------------
 

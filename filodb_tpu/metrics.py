@@ -151,7 +151,7 @@ HELP_TEXTS: dict[str, str] = {
     "filodb_tenant_query_seconds": "Wall-clock query seconds per tenant.",
     "filodb_tenant_kernel_seconds": "Device kernel-dispatch seconds per tenant.",
     "filodb_tenant_bytes_staged": "Bytes staged to device per tenant.",
-    "filodb_device_bytes": "Live device bytes per ledger kind (staged_block|superblock|compile_cache|standing_state|index_postings|rollup).",
+    "filodb_device_bytes": "Live device bytes per ledger kind (staged_block|superblock|compile_cache|standing_state|rollup).",
     "filodb_device_alloc": "Ledger debits (entries pinned) per kind.",
     "filodb_device_alloc_bytes": "Bytes debited to the device ledger per kind.",
     "filodb_device_free": "Ledger credits per kind and reason (evict|invalidate|replace|drop).",
@@ -195,7 +195,6 @@ HELP_TEXTS: dict[str, str] = {
     "filodb_compile_cache_hits": "Compile-cache hits by tier (in_process = warm jit cache, persistent = compile deserialized from the on-disk XLA cache).",
     "filodb_compile_cache_misses": "Compile-cache misses by tier (in_process = a compile happened, persistent = a fresh trace wrote a new on-disk entry).",
     "filodb_index_postings_bytes": "Host posting-bitmap footprint of the part-key index, per shard.",
-    "filodb_index_device_staged_bytes": "Posting bitmaps staged to device (HBM) by the index's opt-in hot tier, per shard.",
     "filodb_index_dictionary_size": "Distinct (label, value) dictionary entries in the part-key index, per shard.",
     "filodb_rollup_entries": "Registered rollup entries (selector x resolution summary blocks) per dataset.",
     "filodb_rollup_maintenance": "Rollup maintainer outcomes (add|build|fold|rebuild|retire|error).",
@@ -288,8 +287,8 @@ class Registry:
     def counter_samples(self, *families: str) -> dict[str, float]:
         """Rendered ``family{labels} -> value`` for the named counter
         families — the public snapshot surface for consumers outside this
-        module (the bench kernel-snapshot dump, attestation) so they never
-        couple to the private storage layout."""
+        module (the test suites) so they never couple to the private
+        storage layout."""
         out: dict[str, float] = {}
         with self._lock:
             for (name, labels), m in self._metrics.items():

@@ -25,9 +25,7 @@
 // neighbour passes. Fallback rate is ~0.6% on f32-widened data, ~0 on f64.
 //
 // Reference analog: prometheus/.../query/PrometheusModel.scala:256 (the JVM
-// circe render). Throughput numbers of record: BENCH_LOCAL.json metrics
-// prom_render_native_2M_random / _2M_integral / prom_render_python_100k_random
-// (benchmarks/run.py bench_render measures all three).
+// circe render).
 //
 // Build: g++ -O3 -march=native -std=c++17 -shared -fPIC promrender.cpp \
 //        -o libfilodbrender.so

@@ -360,7 +360,7 @@ class ExecutableRegistry:
             return {k: dict(v) for k, v in self._storms.items()}
 
     def snapshot(self, limit: int | None = None) -> dict:
-        """The /debug/kernels (and attestation-artifact) rendering:
+        """The /debug/kernels rendering:
         per-executable table sorted by dispatches, storm annotations,
         registered-wrapper cache sizes and the detector config."""
         with self._lock:
@@ -381,7 +381,7 @@ class ExecutableRegistry:
         }
 
     def totals(self) -> dict:
-        """Aggregate proof line for attestation: compiles/dispatches and
+        """Aggregate proof line: compiles/dispatches and
         the fused/batched/mesh families that actually served traffic."""
         with self._lock:
             recs = list(self._records.values())

@@ -9,8 +9,8 @@ then never again. Persisting them to disk makes that true ACROSS process
 restarts too: a rolling deploy or crash-restart skips straight to warm
 dispatch latencies instead of re-paying multi-second XLA compiles.
 
-Placement — ONE rule, shared by the server, ``bench.py`` and
-``chip_smoke.py`` (``cache_dir``): ``JAX_COMPILATION_CACHE_DIR`` when the
+Placement — ONE rule, shared by the server and ``chip_smoke.py``
+(``cache_dir``): ``JAX_COMPILATION_CACHE_DIR`` when the
 environment sets it, else ``<checkout>/.jax-compile-cache``. The directory
 is part of jax's cache key, so it is never derived from anything that moves
 between runs (a data dir, ``$HOME``, a pid, the clock). The top-level

@@ -6,7 +6,6 @@ shape serves in-process, mesh-sharded, and (later) remote execution).
 
 from __future__ import annotations
 
-import os
 import time
 from dataclasses import dataclass, field
 from typing import Any, Optional, Sequence
@@ -1299,12 +1298,6 @@ def _key_mode(hint, stage_mode: str) -> str:
     return stage_mode
 
 
-# incremental superblock extension under live ingest (escape hatch: set
-# FILODB_SUPERBLOCK_EXTEND=0 to restore invalidate-and-rebuild). It decides
-# nothing at build: a superblock's mirrors are made when an extension first
-# writes them (ST.materialize_mirrors), so without extensions there are none
-_SUPERBLOCK_EXTEND = os.environ.get("FILODB_SUPERBLOCK_EXTEND", "1") != "0"
-
 # aggregation ops the fused single-dispatch path computes exactly as one
 # on-device segment reduce (ops/aggregations.fused_range_aggregate)
 FUSED_AGG_OPS = frozenset({"sum", "count", "avg", "min", "max"})
@@ -1611,7 +1604,7 @@ class FusedAggregateExec(ExecPlan):
                 ctx.stats.bump(cache_hits=1)
                 return self._serve_hit(ctx, entry)
             return None
-        if not _SUPERBLOCK_EXTEND or entry.stage_mode is None:
+        if entry.stage_mode is None:
             record_superblock_event("restage")
             cache.note(sb_key, "restage")
             return None

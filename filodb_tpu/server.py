@@ -79,9 +79,6 @@ class FiloServer:
             groups_per_shard=int(cfg["groups_per_shard"]),
             max_partitions=int(cfg["max_partitions_per_shard"]),
             index_backend=cfg["index_backend"],
-            index_device_postings=bool(cfg["index_device_postings"]),
-            index_device_min_hits=int(cfg["index_device_min_hits"]),
-            index_device_max_bytes=int(cfg["index_device_max_bytes"]),
         )
         # multi-host: join the JAX distributed runtime (no-op single-process)
         # and own only this process's shard slice (reference v2 cluster:

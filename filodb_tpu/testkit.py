@@ -24,8 +24,8 @@ from .core.schemas import GAUGE, METRIC_TAG, PROM_COUNTER, PROM_HISTOGRAM, Schem
 def kernel_dispatch_total() -> int:
     """Total ``filodb_kernel_dispatch_seconds`` observations so far — the
     ONE definition of the O(1)-dispatch assertion's counter, shared by the
-    fused/fused-mesh test suites, bench.py's fused_mesh workload, and the
-    MULTICHIP dryrun (a warm fused query must move this by exactly 1)."""
+    fused/fused-mesh test suites and the MULTICHIP dryrun (a warm fused
+    query must move this by exactly 1)."""
     from .metrics import REGISTRY
 
     total = 0

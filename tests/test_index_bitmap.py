@@ -5,8 +5,8 @@ must return IDENTICAL part-id sets to the retained set-arithmetic oracle
 (SetBasedPartKeyIndex) — exact equality, not tolerance — across randomized
 filter combinations (eq / in / literal-alternation / prefix regex / general
 regex / negative / empty-matcher), interval overlap, and limits; stay
-equal under incremental add / update_end_time / remove; survive concurrent
-lookup-vs-ingest; and keep the opt-in device tier's ledger drift at zero.
+equal under incremental add / update_end_time / remove; and survive
+concurrent lookup-vs-ingest.
 """
 
 from __future__ import annotations
@@ -308,99 +308,3 @@ class TestConcurrentSoak:
         for f in filters_pool:
             assert_same_lookup(bm, oracle, f, 0, BIG)
 
-
-class TestDeviceTierLedger:
-    def _hot_pair(self):
-        from filodb_tpu.memstore.index_device import DevicePostingsTier
-
-        bm = PartKeyIndex()
-        oracle = SetBasedPartKeyIndex()
-        for pid in range(3000):
-            tags = {"_ws_": "demo", "_ns_": f"ns{pid % 4}",
-                    "host": f"h{pid % 100}"}
-            bm.add_partkey(pid, tags, 0)
-            oracle.add_partkey(pid, tags, 0)
-        tier = DevicePostingsTier(bm, min_hits=2, name="test-tier")
-        bm.device_tier = tier
-        return bm, oracle, tier
-
-    def _drift(self):
-        from filodb_tpu.ledger import LEDGER
-
-        slot = LEDGER.verify()["kinds"].get("index_postings")
-        return slot["drift"] if slot else 0
-
-    def test_device_intersection_matches_and_drift_zero(self):
-        bm, oracle, tier = self._hot_pair()
-        f = [equals("_ws_", "demo"), equals("_ns_", "ns1")]
-        for _ in range(3):  # build traffic
-            bm.part_ids_from_filters(f, 0, BIG)
-        assert tier.maintain() > 0
-        assert self._drift() == 0
-        before = tier.stats["intersections"]
-        assert_same_lookup(bm, oracle, f, 0, BIG)
-        assert tier.stats["intersections"] > before, \
-            "device path must actually resolve the staged selector"
-        # interval + limit still vectorize on top of the device result
-        assert_same_lookup(bm, oracle, f, 0, BIG, limit=5)
-
-    def test_postings_change_invalidates_staged_copy(self):
-        bm, oracle, tier = self._hot_pair()
-        f = [equals("_ns_", "ns2")]
-        for _ in range(3):
-            bm.part_ids_from_filters(f, 0, BIG)
-        assert tier.maintain() > 0
-        # a new series under the staged label must force the host path and
-        # drop the stale device copy — with zero ledger drift throughout
-        bm.add_partkey(9000, {"_ws_": "demo", "_ns_": "ns2", "host": "hX"}, 0)
-        oracle.add_partkey(9000, {"_ws_": "demo", "_ns_": "ns2",
-                                  "host": "hX"}, 0)
-        assert_same_lookup(bm, oracle, f, 0, BIG)
-        assert self._drift() == 0
-        assert tier.maintain() > 0  # restage picks the fresh postings
-        assert_same_lookup(bm, oracle, f, 0, BIG)
-        assert self._drift() == 0
-        tier.clear()
-        assert self._drift() == 0
-        assert tier.ledger.bytes == 0
-
-    def test_empty_value_equality_never_uses_device_path(self):
-        """{k=\"\"} equality also matches series MISSING the tag — a staged
-        posting bitmap alone cannot answer it, so the tier must neither
-        count it as traffic nor resolve it, even if a bitmap for the empty
-        value exists."""
-        from filodb_tpu.memstore.index_device import DevicePostingsTier
-
-        bm = PartKeyIndex()
-        oracle = SetBasedPartKeyIndex()
-        for pid in range(200):
-            tags = {"m": "x"}
-            if pid % 2:
-                tags["a"] = ""  # explicitly tagged with the EMPTY value
-            # even pids lack the tag entirely
-            bm.add_partkey(pid, tags, 0)
-            oracle.add_partkey(pid, tags, 0)
-        tier = DevicePostingsTier(bm, min_hits=1, name="empty-val-tier")
-        bm.device_tier = tier
-        f = [equals("a", "")]
-        for _ in range(5):
-            assert_same_lookup(bm, oracle, f, 0, BIG)  # all 200 ids
-        assert ("a", "") not in bm.traffic
-        assert tier.maintain() == 0
-        # belt and braces: force-stage the empty-value bitmap anyway — the
-        # lookup must still refuse the device path and stay correct
-        bm.traffic[("a", "")] = 100
-        tier.maintain()
-        before = tier.stats["intersections"]
-        assert_same_lookup(bm, oracle, f, 0, BIG)
-        assert tier.stats["intersections"] == before
-
-    def test_shard_opt_in_wiring(self):
-        from filodb_tpu.memstore.shard import StoreConfig, TimeSeriesShard
-
-        sh = TimeSeriesShard("d", 0, StoreConfig(index_device_postings=True))
-        assert sh.index.device_tier is not None
-        st = sh.index_stats()
-        assert st["device"] is not None
-        sh2 = TimeSeriesShard("d", 1, StoreConfig())
-        assert sh2.index.device_tier is None

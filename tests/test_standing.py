@@ -5,8 +5,7 @@ The load-bearing property: a standing query's delta-maintained ``[G, J]``
 partials are BIT-EQUAL to a full re-evaluation of the same grid over the
 same (aligned) superblock — across regular, jittered and holey scrape
 grids, across live-edge appends riding the in-place superblock extension
-path, across forced restages (``FILODB_SUPERBLOCK_EXTEND=0`` covered by
-the ingest-chaos suite; here the extension path is live), and under
+path, across forced restages, and under
 concurrent ingest. Plus the serving contract: a warm refresh with provably
 disjoint ingest performs ZERO kernel dispatches, a live-edge refresh
 dispatches exactly ONCE for only the touched step suffix, one refresh

@@ -431,8 +431,7 @@ def jit_registration_violations() -> list[str]:
     ``KERNELS.register_jits(...)`` call in the same module (kwarg name ==
     wrapper name). A kernel added without registration would dispatch
     outside the observatory: its compiles and device costs would be
-    invisible to /debug/kernels, the recompile-storm detector and the
-    attestation artifact."""
+    invisible to /debug/kernels and the recompile-storm detector."""
     out: list[str] = []
     for path in sorted(OPS.glob("*.py")):
         tree = ast.parse(path.read_text(), filename=str(path))
